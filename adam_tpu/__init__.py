@@ -49,19 +49,21 @@ if not os.environ.get("ADAM_TPU_NO_X64"):
     jax.config.update("jax_enable_x64", True)
 
 # Persistent XLA compilation cache: CLI-style invocations pay first-compile
-# once per (kernel, shape) across *processes*, not per run — the analog of
-# the JVM's warmed JIT staying resident in the Spark executor. Opt out with
-# ADAM_TPU_NO_COMPILE_CACHE=1; override location with ADAM_TPU_COMPILE_CACHE.
-if not os.environ.get("ADAM_TPU_NO_COMPILE_CACHE"):
-    _cache_dir = os.environ.get("ADAM_TPU_COMPILE_CACHE") or os.path.join(
-        os.path.expanduser("~"), ".cache", "adam_tpu", "xla"
-    )
-    try:
-        os.makedirs(_cache_dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", _cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-    except Exception:  # unwritable FS — run without the cache
-        pass
+# once per (kernel, shape) across processes, not per run.  Where
+# JAX_COMPILATION_CACHE_DIR is set, JAX reads it and it alone places the
+# cache; otherwise the cache sits at the fixed <checkout>/.jax_cache (the
+# path is part of the cache key, so it must not move between runs).
+# ADAM_TPU_NO_COMPILE_CACHE=1 turns the cache off (the tests do).
+if os.environ.get("ADAM_TPU_NO_COMPILE_CACHE"):
+    jax.config.update("jax_enable_compilation_cache", False)
+else:
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update(
+            "jax_compilation_cache_dir",
+            os.path.join(os.path.dirname(os.path.dirname(
+                os.path.abspath(__file__))), ".jax_cache"),
+        )
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
 
 __version__ = "0.1.0"
 

@@ -66,7 +66,7 @@ class CalculateDepth(Command):
             # so payload columns (sequence/qual/attrs) are never read
             proj = ["contig", "start", "end", "flags"]
         if args.stream:
-            # out-of-core path (VERDICT r4 missing #1): header first for
+            # out-of-core path: header first for
             # the dictionary, then windows through the bin spill
             header = context.load_header(args.adam)
             gt = GenotypeDataset.load(

@@ -355,7 +355,7 @@ class Top(Command):
     """Live dashboard over a streamed run's ``--progress`` heartbeat
     file (utils/top.py): the interactive half of the observability
     layer — tails the NDJSON stream, renders a refreshing one-screen
-    view (progress bar, reads/s, tunnel bytes, HBM, per-device
+    view (progress bar, reads/s, transfer bytes, HBM, per-device
     in-flight depth, retry/evict counters, ETA), and exits cleanly on
     the final ``done=true`` line.  Read-only: attach/detach freely
     while the run is live."""

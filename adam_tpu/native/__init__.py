@@ -96,16 +96,12 @@ def _compiler_fingerprint() -> str:
 
 
 def _cache_dir() -> str:
-    """Build-cache directory: ``ADAM_TPU_NATIVE_CACHE`` override, else a
-    per-user cache dir (XDG) — never inside the package tree, so opaque
-    host-specific binaries cannot end up in version control."""
-    env = os.environ.get("ADAM_TPU_NATIVE_CACHE")
-    if env:
-        return env
-    base = os.environ.get("XDG_CACHE_HOME") or os.path.join(
-        os.path.expanduser("~"), ".cache"
+    """Build-cache directory: ``ADAM_TPU_NATIVE_CACHE`` override, else
+    ``_build/`` beside the sources (git-ignored), so a checkout reads
+    and writes nothing outside itself."""
+    return os.environ.get("ADAM_TPU_NATIVE_CACHE") or os.path.join(
+        _DIR, "_build"
     )
-    return os.path.join(base, "adam_tpu", "native")
 
 
 def _build_so() -> Optional[str]:

@@ -56,16 +56,7 @@ def pack_rows_body(mat, lens, size: int):
     shapes — the *fetch* is what shrinks (``packed[:total]``), not the
     device allocation, which aliases the matrix footprint it replaces.
     Padding positions scatter to index ``size`` and drop.
-
-    Backend-selected at trace time (``ops/kernel_backend``): the XLA
-    scatter below is the bit-parity reference; ``pallas`` swaps in
-    :func:`pack_rows_pallas`.  Every jit that can hold this body keys
-    its cache on the backend, so flipping the env retraces.
     """
-    from adam_tpu.ops.kernel_backend import kernel_backend
-
-    if kernel_backend() == "pallas":
-        return pack_rows_pallas(mat, lens, size)
     n, w = mat.shape
     lens = lens.astype(jnp.int64)
     offsets = jnp.cumsum(lens) - lens  # exclusive row starts
@@ -79,88 +70,12 @@ def pack_rows_body(mat, lens, size: int):
     )
 
 
-def _pack_block_kernel(mat_ref, lens_ref, offs_ref, out_ref):
-    """One pallas grid step: scatter one row block's prefixes into the
-    flat VMEM payload (revisited across steps; zeroed at step 0 so
-    bucket-tail padding matches the XLA path's ``jnp.zeros`` base)."""
-    import jax as _jax
-    from jax.experimental import pallas as pl
-
-    @pl.when(pl.program_id(0) == 0)
-    def _zero():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    br, w = mat_ref.shape
-
-    def row_body(r, carry):
-        ln = lens_ref[r, 0]
-        off = offs_ref[r, 0]
-
-        def col_body(j, carry):
-            @pl.when(j < ln)
-            def _store():
-                out_ref[off + j] = mat_ref[r, j]
-
-            return carry
-
-        return _jax.lax.fori_loop(0, w, col_body, carry)
-
-    _jax.lax.fori_loop(0, br, row_body, 0)
-
-
-def pack_rows_pallas(mat, lens, size: int):
-    """Pallas twin of the XLA row-prefix pack scatter: the grid
-    pipeline double-buffers each row block's DMA while the previous
-    block scatters into the flat payload held in VMEM.  Row offsets
-    (exclusive cumsum) stay an XLA prefix-sum — only the memory-bound
-    scatter loop is hand-scheduled.  Bitwise identical to the XLA
-    body: same values at the same offsets, zeros elsewhere."""
-    from jax.experimental import pallas as pl
-
-    from adam_tpu.ops.kernel_backend import pallas_interpret
-    from adam_tpu.ops.pallas_observe import _block_rows
-
-    n, w = mat.shape
-    if n == 0 or w == 0 or size == 0:
-        return jnp.zeros(size, mat.dtype)
-    lens32 = lens.astype(jnp.int32).reshape(n, 1)
-    offs32 = (jnp.cumsum(lens.astype(jnp.int32))
-              - lens.astype(jnp.int32)).reshape(n, 1)
-    br = _block_rows(n)
-    return pl.pallas_call(
-        _pack_block_kernel,
-        out_shape=jax.ShapeDtypeStruct((size,), mat.dtype),
-        grid=(n // br,),
-        in_specs=[
-            pl.BlockSpec((br, w), lambda i: (i, 0)),
-            pl.BlockSpec((br, 1), lambda i: (i, 0)),
-            pl.BlockSpec((br, 1), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((size,), lambda i: (0,)),
-        interpret=pallas_interpret(),
-    )(mat, lens32, offs32)
-
-
-#: Per-backend jits for the standalone pack entry — the body branches
-#: on the backend at trace time, so a single module-level ``jax.jit``
-#: would pin whichever backend traced first.
-_PACK_JITS: dict = {}
-
-
+@partial(jax.jit, static_argnames=("size",))
 def pack_rows_kernel(mat, lens, size: int):
     """Jit entry point over :func:`pack_rows_body` (standalone packing
     of an already-resident matrix; the apply path fuses the body into
-    its own kernel instead — one dispatch, no intermediate).  Resolves
-    the active kernel backend and jits per backend."""
-    from adam_tpu.ops.kernel_backend import kernel_backend
-
-    be = kernel_backend()
-    fn = _PACK_JITS.get(be)
-    if fn is None:
-        fn = _PACK_JITS.setdefault(
-            be, partial(jax.jit, static_argnames=("size",))(pack_rows_body)
-        )
-    return fn(mat, lens, size)
+    its own kernel instead — one dispatch, no intermediate)."""
+    return pack_rows_body(mat, lens, size)
 
 
 def pack_rows_np(mat: np.ndarray, lens: np.ndarray) -> np.ndarray:

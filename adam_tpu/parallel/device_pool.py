@@ -224,14 +224,9 @@ def donation_ok(device=None) -> bool:
     ONE donation decision shared by every dispatch site AND the prewarm
     entry builders, so the two can never pick different variants (which
     would cold-compile the dispatched twin inside a window)."""
-    try:
-        if device is not None:
-            return getattr(device, "platform", "cpu") != "cpu"
-        import jax
+    from adam_tpu.parallel.mesh import on_tpu
 
-        return jax.devices()[0].platform != "cpu"
-    except Exception:
-        return False
+    return on_tpu(device)
 
 
 def resident_windows_enabled(default: bool = True) -> bool:
@@ -595,13 +590,9 @@ class DevicePool:
             # inside the window it rejoins on.  Replayed windows
             # re-prewarm on survivors via the same process-wide cache
             # (already-warm triples dedupe to no-ops).
-            # the dedupe key carries the kernel backend (like the
-            # compile ledger): an XLA-warmed shape says nothing about
-            # the pallas executable of the same shape
-            backend = compile_ledger.active_backend()
             for key, fn in entries:
                 for dev in self.survivors():
-                    cache_key = (key, _device_key(dev), backend)
+                    cache_key = (key, _device_key(dev))
                     if cache_key not in _PREWARMED and cache_key not in claimed:
                         claimed.add(cache_key)
                         todo.append((key, fn, dev, cache_key))
@@ -1210,9 +1201,8 @@ _PROBE_LOCK = threading.Lock()
 
 def probe_device_tflops(device) -> float:
     """One small timed f32 matmul on ``device`` -> TFLOP/s (cached per
-    process).  A deliberately light sibling of bench.py's 4096³ probe:
-    it only needs RELATIVE skew between time-sliced chips to pace the
-    sweep scheduler, not an absolute ceiling."""
+    process).  Deliberately light: it only needs RELATIVE skew between
+    chips to pace the sweep scheduler, not an absolute ceiling."""
     key = _device_key(device)
     with _PROBE_LOCK:
         got = _PROBE_TFLOPS.get(key)
@@ -1225,10 +1215,10 @@ def probe_device_tflops(device) -> float:
         n = 1024
         a = jax.device_put(jnp.ones((n, n), jnp.float32), device)
         jax.block_until_ready(a @ a)  # compile + first run
-        # best-of-3: a single timed rep caught mid-stall on a
-        # time-sliced chip would mislabel the device for the whole
-        # process (the cache below is permanent) and skew the sweep
-        # schedule WORSE than plain round-robin
+        # best-of-3: a single timed rep caught mid-stall would mislabel
+        # the device for the whole process (the cache below is
+        # permanent) and skew the sweep schedule WORSE than plain
+        # round-robin
         best_dt = float("inf")
         for _ in range(3):
             t0 = time.monotonic()
@@ -1248,12 +1238,11 @@ def probe_device_tflops(device) -> float:
 def sweep_weights(devices) -> list[float]:
     """Relative throughput weight per device for the sweep scheduler.
 
-    Order: ``ADAM_TPU_SWEEP_TFLOPS`` (comma-separated floats — feed a
-    bench artifact's ``per_device_probe_tflops`` straight in; entry k
+    Order: ``ADAM_TPU_SWEEP_TFLOPS`` (comma-separated floats; entry k
     weights device **id** k, so eviction-shrunk device lists still pace
     the right chips; ids past the list fall back to the mean, malformed
     values degrade to equal weights), then a one-time in-process matmul
-    probe on accelerator devices, then equal weights (virtual-CPU test
+    probe on TPU devices, then equal weights (virtual-CPU test
     meshes are symmetric by construction — probing them measures
     scheduler noise).
     """
@@ -1284,7 +1273,9 @@ def sweep_weights(devices) -> list[float]:
             "floats; using equal weights", raw,
         )
         return [1.0] * n
-    if any(getattr(d, "platform", "cpu") != "cpu" for d in devices):
+    from adam_tpu.parallel.mesh import on_tpu
+
+    if any(on_tpu(d) for d in devices):
         probed = [probe_device_tflops(d) for d in devices]
         if all(v > 0 for v in probed):
             return probed

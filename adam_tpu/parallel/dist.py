@@ -24,12 +24,13 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from adam_tpu.formats.batch import ReadBatch
 from adam_tpu.ops import flagstat as fs
 from adam_tpu.ops import kmer as kmer_ops
-from adam_tpu.parallel.mesh import SHARD_AXIS, genome_mesh, shard_map
+from adam_tpu.parallel.mesh import SHARD_AXIS, genome_mesh
 from adam_tpu.utils.transfer import device_fetch
 
 

@@ -14,7 +14,6 @@ neighbors adjacent so halo exchanges ride ICI.
 
 from __future__ import annotations
 
-import functools
 from typing import Optional, Sequence
 
 import jax
@@ -28,26 +27,13 @@ SHARD_AXIS = "shard"
 #: shard their read-row axis over it, observe histograms psum across it.
 BATCH_AXIS = "batch"
 
-# jax moved shard_map from jax.experimental (check_rep) to the top level
-# (check_vma) — accept both spellings so the collectives run on every
-# toolchain the container ships.
-try:  # jax >= 0.6
-    from jax import shard_map as _shard_map_impl
-
-    _CHECK_KW = "check_vma"
-except ImportError:  # pragma: no cover - toolchain-dependent
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-    _CHECK_KW = "check_rep"
-
-
-def shard_map(f=None, **kw):
-    """Version-portable ``jax.shard_map`` (keyword-style, decorator-friendly)."""
-    if "check_vma" in kw and _CHECK_KW != "check_vma":
-        kw[_CHECK_KW] = kw.pop("check_vma")
-    if f is None:
-        return functools.partial(shard_map, **kw)
-    return _shard_map_impl(f, **kw)
+def on_tpu(device=None) -> bool:
+    """Whether ``device`` (the default device when None) is a TPU — the
+    one platform question the pipeline asks: it decides the BQSR
+    backend default, buffer donation and the sweep's per-device
+    probes."""
+    d = device if device is not None else jax.devices()[0]
+    return getattr(d, "platform", None) == "tpu"
 
 
 def genome_mesh(devices: Optional[Sequence] = None) -> Mesh:

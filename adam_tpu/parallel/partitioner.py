@@ -258,9 +258,10 @@ def _mesh_specs(n_args: int):
 
 def _mesh_observe_jit_builder():
     import jax
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
-    from adam_tpu.parallel.mesh import BATCH_AXIS, shard_map
+    from adam_tpu.parallel.mesh import BATCH_AXIS
 
     @partial(jax.jit, static_argnames=("n_rg", "lmax", "mesh"))
     def run(bases, quals, lengths, flags, rg, res_ok, is_mm, rd_ok,
@@ -291,9 +292,10 @@ def _mesh_observe_jit_builder():
 
 def _mesh_apply_jit_builder(donate: bool):
     import jax
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
-    from adam_tpu.parallel.mesh import BATCH_AXIS, shard_map
+    from adam_tpu.parallel.mesh import BATCH_AXIS
 
     def run(bases, quals, lengths, flags, rg, has_qual, valid, table,
             lmax, mesh):
@@ -321,9 +323,10 @@ def _mesh_apply_jit_builder(donate: bool):
 
 def _mesh_apply_pack_jit_builder(donate: bool):
     import jax
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
-    from adam_tpu.parallel.mesh import BATCH_AXIS, shard_map
+    from adam_tpu.parallel.mesh import BATCH_AXIS
 
     def run(bases, quals, lengths, flags, rg, has_qual, valid, table,
             lmax, mesh):
@@ -356,9 +359,10 @@ def _mesh_apply_pack_jit_builder(donate: bool):
 
 def _mesh_observe_packed_jit_builder(donate: bool):
     import jax
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
-    from adam_tpu.parallel.mesh import BATCH_AXIS, shard_map
+    from adam_tpu.parallel.mesh import BATCH_AXIS
 
     def run(bases, quals, lengths, flags, rg, res_pk, mm_pk, rd_ok,
             n_rg, lmax, mesh):
@@ -392,9 +396,10 @@ def _mesh_observe_packed_jit_builder(donate: bool):
 
 def _mesh_apply_pack2_jit_builder(donate: bool):
     import jax
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
-    from adam_tpu.parallel.mesh import BATCH_AXIS, shard_map
+    from adam_tpu.parallel.mesh import BATCH_AXIS
 
     def run(bases, quals, lengths, flags, rg, has_qual, valid, table,
             lmax, mesh):
@@ -428,9 +433,10 @@ def _mesh_apply_pack2_jit_builder(donate: bool):
 
 def _mesh_markdup_jit_builder():
     import jax
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
-    from adam_tpu.parallel.mesh import BATCH_AXIS, shard_map
+    from adam_tpu.parallel.mesh import BATCH_AXIS
 
     @partial(jax.jit, static_argnames=("mesh",))
     def run(start, end, flags, ops, lens, n_ops, quals, lengths, mesh):
@@ -451,9 +457,10 @@ def _mesh_markdup_jit_builder():
 
 def _mesh_fused_bc_jit_builder(donate: bool):
     import jax
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
-    from adam_tpu.parallel.mesh import BATCH_AXIS, shard_map
+    from adam_tpu.parallel.mesh import BATCH_AXIS
 
     def run(bases, quals, lengths, flags, rg, res_pk, mm_pk, rd_ok,
             has_qual, valid, table, n_rg, lmax, mesh):
@@ -504,13 +511,8 @@ _MESH_JITS_LOCK = threading.Lock()
 def _mesh_jit(kind: str, donate: bool = False):
     """Lazily-built module-level mesh jits (one executable cache each,
     shared by prewarm and dispatch — the device_pool get_columns_jit
-    discipline).  Keyed by the kernel backend alongside (kind, donate):
-    the shard bodies branch Pallas/XLA at trace time
-    (``ops/kernel_backend``), so a backend flip must reach a fresh
-    jit."""
-    from adam_tpu.ops.kernel_backend import kernel_backend
-
-    key = (kind, donate, kernel_backend())
+    discipline), keyed by (kind, donate)."""
+    key = (kind, donate)
     fn = _MESH_JITS.get(key)
     if fn is None:
         with _MESH_JITS_LOCK:
@@ -715,12 +717,11 @@ class MeshPartitioner:
 
     # ---- pass C: apply with the device-resident table ------------------
     def apply_supports_donation(self) -> bool:
-        # buffer donation is a no-op (with a warning) on some CPU
-        # runtimes: keep the virtual-device test legs quiet and donate
-        # where it pays — on real accelerators
-        return all(
-            getattr(d, "platform", "cpu") != "cpu" for d in self.devices
-        )
+        # buffer donation is a no-op (with a warning) on CPU runtimes:
+        # keep the virtual-device test legs quiet and donate on the chip
+        from adam_tpu.parallel.mesh import on_tpu
+
+        return all(on_tpu(d) for d in self.devices)
 
     def apply_window(self, arrays: tuple, table_dev, gl: int):
         """Dispatch one window's recalibration gather across the mesh
@@ -846,12 +847,8 @@ class MeshPartitioner:
         tr = tracer if tracer is not None else tele.TRACE
         todo = []
         with dp._PREWARM_LOCK:
-            # backend in the dedupe key, like the pool prewarm and the
-            # compile ledger: an XLA-warmed shape says nothing about
-            # the pallas executable of the same shape
-            backend = compile_ledger.active_backend()
             for key, fn in entries:
-                cache_key = (key, self.ledger_key(), backend)
+                cache_key = (key, self.ledger_key())
                 if cache_key not in dp._PREWARMED:
                     dp._PREWARMED.add(cache_key)
                     todo.append((key, fn, cache_key))

@@ -543,17 +543,6 @@ def _transform_streamed_impl(
 
     use_packed = use_device and packed_columns_enabled()
     stats["packed_columns"] = use_packed
-    # kernel backend (ADAM_TPU_KERNEL_BACKEND, ops/kernel_backend): the
-    # Pallas/XLA selector every per-residue body reads at trace time.
-    # Gauged once — the backend is a process-wide decision, and the
-    # analyzer/bench artifacts attribute kernel walls against it.
-    from adam_tpu.ops.kernel_backend import kernel_backend
-
-    stats["kernel_backend"] = kernel_backend()
-    tr.gauge(
-        tele.G_KERNEL_BACKEND,
-        1 if stats["kernel_backend"] == "pallas" else 0,
-    )
     # device-resident windows (ADAM_TPU_RESIDENT, default on for the
     # device backend; docs/PERF.md "Device-resident windows"): each
     # window's bases/quals/lengths/flags/rg land on device ONCE at

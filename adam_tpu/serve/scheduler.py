@@ -166,7 +166,7 @@ class JobScheduler:
         self._pool = None
         self._pool_built = False
         # service-wide heartbeat (<run-root>/heartbeat.ndjson): samples
-        # the global TRACE — tunnel bytes, retry/fault counters, HBM —
+        # the global TRACE — transfer bytes, retry/fault counters, HBM —
         # the pool-totals row `adam-tpu top <run-root>` renders next to
         # the per-job (job-scoped) streams
         self._service_hb = None

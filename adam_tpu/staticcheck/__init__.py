@@ -3,7 +3,7 @@ conventions the streamed TPU pipeline's correctness rests on.
 
 Eight PRs of device code left five *conventions* that no compiler
 enforces: every device->host fetch routes through
-``utils/transfer.device_fetch`` (or the PR 7 tunnel-byte ledger
+``utils/transfer.device_fetch`` (or the PR 7 transfer-byte ledger
 under-counts), every jit dispatch is ``compile_ledger.track``-wrapped
 against a prewarm entry (or ``device.compile.in_window`` lies), every
 durability-bearing publish goes through ``utils/durability`` (or a

@@ -30,10 +30,9 @@ Three checks:
   construction.
 * **pallas containment** — a ``pl.pallas_call`` anywhere in the
   package must sit inside a ``*_body``/``*_kernel``/``*_pallas``
-  function: those are the surfaces the kernel-backend selector
-  (``ops/kernel_backend``) branches on at trace time, so a stray
-  pallas site elsewhere would dodge both the backend toggle and the
-  ledger keys."""
+  function: those are the dispatchable surfaces the compile ledger
+  and the chip compile tests (``tests/test_chip_compile.py``) reach,
+  so a stray pallas site elsewhere would dodge both."""
 
 from __future__ import annotations
 
@@ -74,8 +73,7 @@ MESH_WINDOW_METHODS = frozenset(
 #: Function-name suffixes a ``pl.pallas_call`` site may live under:
 #: the jit-able math (``*_body``), a dispatchable binding
 #: (``*_kernel``) or the Pallas port itself (``*_pallas``).  Anywhere
-#: else the call escapes the backend selector (ops/kernel_backend) and
-#: the ledger/prewarm machinery that keys on it.
+#: else the call escapes the ledger/prewarm machinery.
 PALLAS_HOST_SUFFIXES = ("_body", "_kernel", "_pallas")
 
 
@@ -180,8 +178,7 @@ class DispatchLedgerRule(Rule):
                 self.name, node,
                 f"pallas_call in '{where}' — Pallas call sites must "
                 "live inside a *_body/*_kernel/*_pallas function so the "
-                "kernel-backend selector and the compile ledger key on "
-                "them (ops/kernel_backend.py)",
+                "compile ledger keys on them",
             )
 
     @staticmethod

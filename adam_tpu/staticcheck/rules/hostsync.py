@@ -89,7 +89,7 @@ class HostSyncRule(Rule):
                "transfer.device_fetch")
     contract = (
         "Every device->host fetch in pipelines/, parallel/ and ops/ "
-        "routes through utils/transfer.device_fetch so the tunnel-byte "
+        "routes through utils/transfer.device_fetch so the transfer-byte "
         "ledger, fetch watchdog, retry and fault point stay complete "
         "by construction (docs/PERF.md 'Device ledger measurements', "
         "docs/ROBUSTNESS.md)."

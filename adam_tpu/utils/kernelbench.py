@@ -1,28 +1,20 @@
 """Per-kernel microbench harness (docs/PERF.md "Megakernel tier").
 
-The end-to-end bench (``bench.py``) measures the pipeline; nothing
-measured the *kernels* — so a Pallas port or a fusion could regress one
-inner loop and the signal would drown in ingest/write noise.  This
-module times each registered device kernel in isolation, per kernel
-backend (``ADAM_TPU_KERNEL_BACKEND``, ``ops/kernel_backend``) and per
-grid bucket, with the classic simple-timeit shape: one untimed warmup
-dispatch (compile), then ``iters`` timed dispatches each blocked to
-completion.
+The end-to-end bench (``bench.py``) measures the pipeline; this module
+times each registered device kernel in isolation per grid bucket, so a
+fusion cannot regress one inner loop invisibly inside the e2e number.
+The shape is the classic simple-timeit: one untimed warmup dispatch
+(compile), then ``iters`` timed dispatches each blocked to completion.
 
 The result is a stable-schema JSON document (:data:`SCHEMA`):
 
-``{"schema": ..., "jax_backend": "cpu"|"tpu"|..., "rows": [
-    {"kernel", "backend", "mode", "g", "gl", "iters",
-     "mean_s", "best_s"}, ...]}``
+``{"schema": ..., "jax_backend": "cpu"|"tpu", "rows": [
+    {"kernel", "g", "gl", "iters", "mean_s", "best_s"}, ...]}``
 
-``mode`` is ``"compiled"`` or ``"interpret"`` — Pallas rows run in
-interpret mode off-TPU (bit-parity, uselessly slow: a correctness rail,
-not a perf number; the smoke harness asserts the schema either way and
-``bench.py`` embeds the document under the secondary line's
-``"kernels"`` key so ``scripts/bench-diff`` can gate
-``kernels.<kernel>.<backend>.g<g>x<gl>.mean_s`` on real hardware).
-
-``scripts/kernel-bench`` is the CLI wrapper.
+``jax_backend`` names where the rows ran: only ``tpu`` rows are device
+times.  ``bench.py`` embeds the document under the secondary line's
+``"kernels"`` key, which ``scripts/bench-diff`` flattens to
+``kernels.<kernel>.g<g>x<gl>.*``; ``scripts/kernel-bench`` is the CLI.
 """
 
 from __future__ import annotations
@@ -33,12 +25,11 @@ import numpy as np
 
 SCHEMA = "adam_tpu.kernelbench/1"
 
-#: Default grid buckets (rows, lanes) — small enough for the CPU
-#: interpret rail, pow2-quantized like the streamed windows' grids.
+#: Default grid buckets (rows, lanes), pow2-quantized like the
+#: streamed windows' grids.
 DEFAULT_GRIDS = ((256, 128),)
 
 KERNELS = ("observe", "pack", "apply", "fused_bc")
-BACKENDS = ("xla", "pallas")
 
 
 def _synth(g: int, gl: int, n_rg: int, seed: int = 7) -> dict:
@@ -128,40 +119,21 @@ def _timeit(thunk, iters: int) -> tuple:
 
 
 def run_kernelbench(
-    grids=DEFAULT_GRIDS, iters: int = 5, n_rg: int = 3,
-    kernels=KERNELS, backends=BACKENDS,
+    grids=DEFAULT_GRIDS, iters: int = 5, n_rg: int = 3, kernels=KERNELS,
 ) -> dict:
-    """Run the registered kernels across ``backends`` x ``grids`` ->
-    the :data:`SCHEMA` document.  A backend/kernel that fails to build
-    or dispatch contributes an ``"error"`` row instead of killing the
-    sweep (the bench artifact must survive a broken port — that IS the
-    signal)."""
+    """Run the registered kernels across ``grids`` -> the
+    :data:`SCHEMA` document.  A kernel that fails to build or dispatch
+    raises: a benchmark that skips a broken kernel reports nothing."""
     import jax
 
-    from adam_tpu.ops.kernel_backend import backend_scope, pallas_interpret
-
     rows = []
-    for bk in backends:
-        mode = (
-            "interpret" if bk == "pallas" and pallas_interpret()
-            else "compiled"
-        )
-        with backend_scope(bk):
-            for kernel in kernels:
-                for g, gl in grids:
-                    row = {
-                        "kernel": kernel, "backend": bk, "mode": mode,
-                        "g": int(g), "gl": int(gl), "iters": int(iters),
-                    }
-                    try:
-                        mean_s, best_s = _timeit(
-                            _build(kernel, g, gl, n_rg), iters
-                        )
-                        row["mean_s"] = mean_s
-                        row["best_s"] = best_s
-                    except Exception as e:  # keep the sweep alive
-                        row["error"] = f"{type(e).__name__}: {e}"
-                    rows.append(row)
+    for kernel in kernels:
+        for g, gl in grids:
+            mean_s, best_s = _timeit(_build(kernel, g, gl, n_rg), iters)
+            rows.append({
+                "kernel": kernel, "g": int(g), "gl": int(gl),
+                "iters": int(iters), "mean_s": mean_s, "best_s": best_s,
+            })
     return {
         "schema": SCHEMA,
         "jax_backend": jax.default_backend(),

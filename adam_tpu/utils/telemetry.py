@@ -111,12 +111,10 @@ SPAN_BQSR_APPLY_FETCH = _span("bqsr.apply.fetch")
 SPAN_BQSR_APPLY_HOST = _span("bqsr.apply.host")
 SPAN_MD_COLUMNS = _span("markdup.columns.dispatch")
 # the megakernel tier (PR 18): one fused B→C dispatch per window when
-# the recalibration table is known up front; the gauges record the
-# tier decision (streamed.fused_bc 1/0) and the resolved kernel
-# backend (kernel.backend 0=xla 1=pallas) once per run
+# the recalibration table is known up front; the gauge records the
+# tier decision (streamed.fused_bc 1/0) once per run
 SPAN_FUSED_BC = _span("bqsr.fused_bc")
 G_FUSED_BC = _metric("streamed.fused_bc")
-G_KERNEL_BACKEND = _metric("kernel.backend")
 C_FUSED_DISPATCHED = _metric("device.windows.fused")
 
 # ---- device pool (parallel/device_pool.py): multi-chip round-robin
@@ -336,7 +334,7 @@ G_SCHED_ACTIVE = _metric("sched.jobs.active")
 # sparse to coalesce)
 G_BATCH_JOBS = _metric("sched.batch.jobs")
 
-# ---- device ledger: tunnel byte accounting (utils/transfer.py +
+# ---- device ledger: transfer byte accounting (utils/transfer.py +
 # parallel/device_pool.py).  Counters carry the run totals; the
 # per-direction throughput histograms (bytes/second, the shared fixed
 # log-spaced buckets) answer whether the link itself — not the host —
@@ -543,7 +541,7 @@ def merge_histograms(a: dict, b: dict) -> dict:
 # --------------------------------------------------------------------------
 # Thread-local pipeline-pass scope: the streamed pipeline enters
 # pass_scope("a"/"observe"/"apply"/"sweep") around each pass's dispatch/
-# fetch sites, so the transfer ledger can attribute tunnel bytes per
+# fetch sites, so the transfer ledger can attribute transfer bytes per
 # pass without threading a label through the bqsr/markdup/transfer
 # APIs (the same shape as device_pool's replay_scope).
 _PASS_TLS = threading.local()
@@ -910,8 +908,8 @@ class Tracer:
                     tagg[1] += dur
             if dev is not None:
                 # per-device aggregate: the snapshot's device_spans
-                # section (chip occupancy + skew; time-sliced chips are
-                # NOT symmetric, so per-device walls must be separable)
+                # section (chip occupancy + skew: per-device walls must
+                # be separable)
                 per = self._dev_spans.setdefault(name, {})
                 dagg = per.get(dev)
                 if dagg is None:
@@ -1492,7 +1490,7 @@ class Tracer:
             # top-level keys): transfers/compiles/HBM are aggregates,
             # not spans, so a trace alone could never reproduce them —
             # and the analyzer must render the same report sections
-            # from either artifact kind.  Counters too: the tunnel byte
+            # from either artifact kind.  Counters too: the transfer byte
             # totals and compile hit/miss counts live there.
             "transfers": xfer,
             "compiles": compiles,
@@ -1771,7 +1769,7 @@ def merge_snapshots(snaps: list) -> dict:
 # Live progress heartbeat
 # --------------------------------------------------------------------------
 #: NDJSON schema tag every heartbeat line carries.  /2 added the
-#: device-ledger fields (tunnel bytes + HBM); /3 appended the
+#: device-ledger fields (transfer bytes + HBM); /3 appended the
 #: ``partitioner`` execution-mode field; /4 appended the cross-job
 #: batching fields (``batch_fill`` + ``batched_jobs``); /5 appended
 #: ``device_health`` (the per-device scoreboard states,
@@ -2213,7 +2211,7 @@ class Heartbeat:
                 round(reads / elapsed, 1) if elapsed > 0 else 0.0
             ),
             "bytes_written": counters.get(C_BYTES_WRITTEN, 0),
-            # tunnel byte accounting (the transfer ledger's run totals)
+            # transfer byte accounting (the transfer ledger's run totals)
             "h2d_bytes": counters.get(C_H2D_BYTES, 0),
             "d2h_bytes": counters.get(C_D2H_BYTES, 0),
             # HBM footprint per device ({} + null on backends without

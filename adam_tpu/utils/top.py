@@ -271,7 +271,7 @@ def render_multi_frame(jobs: dict, root: str = "",
     """One aggregated dashboard frame (pure function).
 
     ``jobs`` maps job name -> its newest heartbeat line; ``pool`` is
-    the service stream's newest line (process-global counters: tunnel
+    the service stream's newest line (process-global counters: transfer
     bytes, retries, faults), ``states`` maps job name -> the JOB.json
     state string when known.  Job-scoped numbers SUM across jobs;
     nothing global rides in a paced job's stream, so the totals cannot

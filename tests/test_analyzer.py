@@ -278,7 +278,7 @@ def test_resumed_run_snapshot_report_renders_ledger_sections():
     # HBM peaks
     assert report["hbm"]["0"]["peak_bytes"] == 2 << 30
     text = analyzer.render_report(report)
-    assert "Tunnel transfers" in text
+    assert "Host<->device transfers" in text
     assert "WARNING: shapes cold-compiled INSIDE a timed window" in text
     assert "bqsr.observe[1024x128x3]" in text
     assert "HBM footprint" in text

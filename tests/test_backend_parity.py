@@ -60,9 +60,9 @@ def test_backend_topology_default(monkeypatch):
     """Without a chip (the CPU test harness) the default must be a host
     backend; with one, device."""
     monkeypatch.delenv("ADAM_TPU_BQSR_BACKEND", raising=False)
-    monkeypatch.setattr(bq, "_CHIP_PRESENT", False)
+    monkeypatch.setattr(bq, "on_tpu", lambda device=None: False)
     assert bq.bqsr_backend() in ("native", "numpy")
-    monkeypatch.setattr(bq, "_CHIP_PRESENT", True)
+    monkeypatch.setattr(bq, "on_tpu", lambda device=None: True)
     assert bq.bqsr_backend() == "device"
 
 

@@ -277,41 +277,6 @@ def test_sw_score_only_parity():
     np.testing.assert_array_equal(ref, got_pl)
 
 
-def test_sw_score_i16_integral_weights_parity():
-    """The narrow i16 score kernel (integral weight sets) matches the
-    f32 scan scores exactly — integer scores are exact in both types —
-    including padded variable lengths and N codes; and the router sends
-    integral weights through it while rejecting fractional ones."""
-    import pytest
-
-    rng = np.random.default_rng(13)
-    B, lx, ly = 40, 63, 70
-    xc = rng.integers(0, 5, (B, lx)).astype(np.int32)
-    yc = rng.integers(0, 5, (B, ly)).astype(np.int32)
-    xl = rng.integers(4, lx + 1, B).astype(np.int32)
-    yl = rng.integers(4, ly + 1, B).astype(np.int32)
-    args = (2.0, -1.0, -1.0, -1.0)
-    ref = np.asarray(sw.sw_best_scores(xc, xl, yc, yl, *args,
-                                       backend="scan"))
-    got = np.asarray(
-        sw._sw_score_pallas(
-            jnp.asarray(xc), jnp.asarray(xl), jnp.asarray(yc),
-            jnp.asarray(yl), lx, ly, *args, interpret=True,
-            dtype_name="i16",
-        )
-    )
-    np.testing.assert_array_equal(ref, got)
-    with pytest.raises(ValueError):
-        sw._sw_score_pallas(
-            jnp.asarray(xc), jnp.asarray(xl), jnp.asarray(yc),
-            jnp.asarray(yl), lx, ly, 1.0, -0.333, -0.5, -0.5,
-            interpret=True, dtype_name="i16",
-        )
-    with pytest.raises(ValueError):
-        sw.sw_best_scores(xc, xl, yc, yl, 1.0, -0.333, -0.5, -0.5,
-                          backend="pallas_i16")
-
-
 def test_sw_score_long_reads_multi_tile():
     """Long-read shapes: lx past one 128-lane tile (L=256 sublane
     state, 9-step delete chains) agrees across backends, N codes

@@ -755,8 +755,8 @@ def test_hbm_ledger_tracks_peak_and_key_stability():
         assert ks["histograms"][name]["count"] == 0
 
 
-def test_heartbeat_v2_carries_tunnel_and_hbm_fields(tmp_path):
-    """The /2 schema fields: tunnel byte totals from the counters, HBM
+def test_heartbeat_v2_carries_transfer_and_hbm_fields(tmp_path):
+    """The /2 schema fields: transfer byte totals from the counters, HBM
     as {} + null on backends without memory stats (the explicit
     unsupported marker, distinct from zeros)."""
     tr = tele.Tracer(recording=True)

@@ -60,7 +60,7 @@ def test_render_frame_contents():
     assert "RUNNING" in text
     assert "2/4" in text and "resumed 1" in text and "parts 1" in text
     assert "5,000" in text
-    assert "10.0MiB" in text and "5.0MiB" in text  # tunnel totals
+    assert "10.0MiB" in text and "5.0MiB" in text  # transfer totals
     assert "1.0GiB" in text and "peak 2.0GiB" in text
     assert "retries 3" in text and "faults 1" in text
     done = top_mod.render_frame(_line(done=True, ok=True))

@@ -196,3 +196,29 @@ class TestReviewRegressions:
             zip(rt.variants.contig_idx.tolist(), rt.variants.start.tolist())
         )
         assert key2 == sorted(key2)
+
+
+@pytest.mark.parametrize("from_env", [True, False], ids=["env", "checkout"])
+def test_compile_cache_placement(tmp_path, from_env):
+    """``import adam_tpu`` leaves a set JAX_COMPILATION_CACHE_DIR alone
+    and otherwise puts the persistent cache at <checkout>/.jax_cache."""
+    import os
+    import pathlib
+    import subprocess
+    import sys
+
+    repo = pathlib.Path(__file__).resolve().parent.parent
+    env = {k: v for k, v in os.environ.items()
+           if k not in ("ADAM_TPU_NO_COMPILE_CACHE",
+                        "JAX_COMPILATION_CACHE_DIR")}
+    env["JAX_PLATFORMS"] = "cpu"
+    want = str(repo / ".jax_cache")
+    if from_env:
+        want = env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "jcc")
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "import jax, adam_tpu; print(jax.config.jax_compilation_cache_dir)"],
+        env=env, cwd=str(repo), capture_output=True, text=True, timeout=120,
+    )
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.strip().splitlines()[-1] == want
