@@ -174,11 +174,12 @@ def main(argv=None) -> int:
         format="%(asctime)s %(name)s %(levelname)s: %(message)s",
     )
     # any observability sink switches recording on: the timer table, the
-    # JSON snapshot, the Chrome trace and the analyzer report all read
-    # the same run (--progress self-manages via the heartbeat instead)
+    # JSON snapshot, the Chrome trace, the analyzer report and the
+    # profiler trace (whose host plane carries the recorded spans) all
+    # read the same run (--progress self-manages via the heartbeat)
     want_metrics = bool(
         args.print_metrics or args.metrics_json or args.trace_out
-        or getattr(args, "report", None)
+        or getattr(args, "report", None) or args.xprof_dir
     )
     ins.TIMERS.recording = want_metrics
     tele.TRACE.recording = want_metrics

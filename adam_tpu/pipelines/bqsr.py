@@ -468,225 +468,42 @@ def observe_inputs(ds: AlignmentDataset, known_snps=None) -> tuple:
     return b, read_ok, residue_ok, is_mm, len(ds.read_groups) + 1
 
 
+def count_observe_residues(b, read_ok) -> None:
+    """``bqsr.observe.residues``: the summed length of the reads the
+    observe filter admits — O(N), no pass over [N, L]."""
+    if _tele.TRACE.recording:
+        _tele.TRACE.count(
+            _tele.C_OBSERVE_RESIDUES,
+            int(np.asarray(b.lengths)[np.asarray(read_ok)].sum()),
+        )
+
+
+def count_apply_residues(b) -> None:
+    """``bqsr.apply.residues``: the summed length of the reads whose
+    qualities pass C rewrites (valid, with qualities)."""
+    if _tele.TRACE.recording:
+        keep = np.asarray(b.valid) & np.asarray(b.has_qual)
+        _tele.TRACE.count(
+            _tele.C_APPLY_RESIDUES, int(np.asarray(b.lengths)[keep].sum())
+        )
+
+
 def _observe_impl(
     ds: AlignmentDataset, known_snps: Optional[SnpTable], backend: str,
     device=None, mesh=None, resident=None,
 ):
-    b = ds.batch.to_numpy()
-    lmax = b.lmax
-    from adam_tpu import native
-    from adam_tpu.formats.strings import StringColumn
-
-    n = b.n_rows
-    md_col = StringColumn.of(ds.sidecar.md)
-    use_native = (
-        backend == "native" and native.available() and len(md_col) >= n
-    )
-    if use_native:
-        # the native walk parses each read's MD inline — no host-side
-        # [N, L] mismatch mask, no vectorized MD tokenize pass
-        is_mm = None
-        has_md = md_col.valid[:n] & np.asarray(b.valid)
-    else:
-        is_mm, _, has_md = batch_md_arrays(
-            ds.batch, ds.sidecar, need_ref_codes=False
+    # the host half (masks, padding, bit-packing) is one span; the
+    # dispatch it hands back runs after it
+    with _tele.TRACE.span(
+        _tele.SPAN_BQSR_OBSERVE_PREP, reads=int(ds.batch.n_rows)
+    ):
+        b, read_ok, run = _observe_prepare(
+            ds, known_snps, backend, device, mesh, resident
         )
+    total, mism = run()
+    from adam_tpu.formats.batch import grid_cols
 
-    read_ok = observe_read_mask(b, has_md)
-
-    # one extra bin for RG-less reads (the reference's null readGroup)
-    n_rg = len(ds.read_groups) + 1
-    from adam_tpu.formats.batch import grid_cols, grid_rows, pad_rows_np
-
-    g = grid_rows(b.n_rows)
-    gl = grid_cols(lmax)
-    snp_active = known_snps is not None and len(known_snps)
-    residue_ok = None
-    snp_keys = None
-    if snp_active and use_native:
-        # known-SNP masking runs inside the native kernel's cigar walk
-        # (sorted site-key binary search per residue) — the [N, L] i64
-        # position matrix (~3 GB at WGS batch sizes) never materializes
-        snp_keys = known_snps.site_keys(ds.seq_dict.names)
-
-    def _python_residue_mask():
-        # device/numpy backends: residue filter built host-side (the
-        # module-level helper, shared with the cross-job coalescer)
-        return observe_residue_mask(ds, b, known_snps)
-
-    nat = None
-    if use_native:
-        nat = native.bqsr_observe(
-            b.bases, b.quals, b.lengths, b.flags, b.read_group_idx,
-            b.cigar_ops, b.cigar_lens, b.cigar_n,
-            None, is_mm, read_ok, n_rg, gl,
-            contig_idx=b.contig_idx, start=b.start, snp_keys=snp_keys,
-            md_buf=md_col.buf, md_off=md_col.offsets[: n + 1],
-        )
-    if nat is not None:
-        total, mism = nat  # host arrays: downstream table math stays host
-    else:
-        if is_mm is None:
-            is_mm, _, _hm = batch_md_arrays(
-                ds.batch, ds.sidecar, need_ref_codes=False
-            )
-        if residue_ok is None:
-            residue_ok = _python_residue_mask()
-        if backend == "numpy":
-            total, mism = observe_kernel_np(
-                b.bases, b.quals, b.lengths, b.flags, b.read_group_idx,
-                residue_ok, is_mm, read_ok, n_rg, lmax,
-            )
-            # center the [-lmax, lmax] cycle slots inside the grid-width
-            # table so every backend returns the same [.., 2*gl+1, ..]
-            # shape (merge_observations pads by gl, not lmax)
-            if gl != lmax:
-                shape = (n_rg, N_QUAL, 2 * gl + 1, N_DINUC)
-                t2 = np.zeros(shape, np.int64)
-                m2 = np.zeros(shape, np.int64)
-                off = gl - lmax
-                t2[:, :, off : off + 2 * lmax + 1, :] = total
-                m2[:, :, off : off + 2 * lmax + 1, :] = mism
-                total, mism = t2, m2
-        elif mesh is not None:
-            from adam_tpu.utils import compile_ledger, faults
-            from adam_tpu.utils import retry as _retry
-
-            gm = mesh.rows_for(g)
-            rw = resident
-            if rw is not None and not (
-                rw.alive and rw.device == "mesh"
-                and rw.g == gm and rw.gl == gl
-            ):
-                rw = None
-            if rw is not None:
-                from adam_tpu.ops.colpack import pack_mask_bits
-
-                res_pk = pack_mask_bits(
-                    pad_rows_np(residue_ok, gm, False, cols=gl)
-                )
-                mm_pk = pack_mask_bits(
-                    pad_rows_np(is_mm, gm, False, cols=gl)
-                )
-                rd_pad = pad_rows_np(read_ok, gm, False)
-
-                def dispatch_mesh_resident():
-                    # per-attempt placement of the small per-pass
-                    # inputs keeps the retry idempotent even when the
-                    # donating variant consumed a prior attempt's masks
-                    faults.point("device.dispatch")
-                    return mesh.observe_window_resident(
-                        rw, res_pk, mm_pk, rd_pad, n_rg, gl
-                    )
-
-                with compile_ledger.track(
-                    ("mesh.observe_packed", gm, gl, n_rg),
-                    mesh.ledger_key(),
-                ):
-                    total, mism = _retry.retry_call(
-                        dispatch_mesh_resident,
-                        site="bqsr.observe.dispatch",
-                    )
-                rg_names = ds.read_groups.names + ["null"]
-                return total, mism, rg_names, gl
-
-            def dispatch_mesh():
-                # the sharded placement + collective dispatch re-run as
-                # one idempotent unit, exactly like the pool path
-                faults.point("device.dispatch")
-                return mesh.observe_window((
-                    # adam-tpu: noqa[residency] reason=non-resident fallback: residency off, a dead handle, or a replay re-ships from the host ingest copy
-                    pad_rows_np(b.bases, gm, schema.BASE_PAD, cols=gl),
-                    # adam-tpu: noqa[residency] reason=non-resident fallback: residency off, a dead handle, or a replay re-ships from the host ingest copy
-                    pad_rows_np(b.quals, gm, schema.QUAL_PAD, cols=gl),
-                    pad_rows_np(b.lengths, gm, 0),
-                    pad_rows_np(b.flags, gm, schema.FLAG_UNMAPPED),
-                    pad_rows_np(b.read_group_idx, gm, -1),
-                    pad_rows_np(residue_ok, gm, False, cols=gl),
-                    pad_rows_np(is_mm, gm, False, cols=gl),
-                    pad_rows_np(read_ok, gm, False),
-                ), n_rg, gl)
-
-            # ledger key == the mesh prewarm entry key: an in-window
-            # miss here is a mesh prewarm coverage gap
-            with compile_ledger.track(
-                ("mesh.observe", gm, gl, n_rg), mesh.ledger_key()
-            ):
-                total, mism = _retry.retry_call(
-                    dispatch_mesh, site="bqsr.observe.dispatch"
-                )
-        else:
-            from adam_tpu.parallel.device_pool import donation_ok, putter
-            from adam_tpu.utils import compile_ledger, faults
-            from adam_tpu.utils import retry as _retry
-
-            _put = putter(device)
-            rw = resident
-            if rw is not None and not (
-                rw.alive and rw.device is device
-                and rw.g == g and rw.gl == gl
-            ):
-                rw = None
-            if rw is not None:
-                from adam_tpu.ops.colpack import pack_mask_bits
-
-                res_pk = pack_mask_bits(
-                    pad_rows_np(residue_ok, g, False, cols=gl)
-                )
-                mm_pk = pack_mask_bits(
-                    pad_rows_np(is_mm, g, False, cols=gl)
-                )
-                rd_pad = pad_rows_np(read_ok, g, False)
-
-                def dispatch_resident():
-                    # ingest-once H2D: the five resident arrays stay
-                    # put; only the bit-packed masks + read filter ship
-                    # (fresh placements per attempt, so the donating
-                    # variant's consumed masks never re-enter a retry)
-                    faults.point("device.dispatch", device=device)
-                    return jit_variant(
-                        "observe_packed", donation_ok(device)
-                    )(
-                        *rw.args(), _put(res_pk), _put(mm_pk),
-                        _put(rd_pad), n_rg, gl,
-                    )
-
-                # ledger key == observe_packed_prewarm_entry's key
-                with compile_ledger.track(
-                    ("bqsr.observe_packed", g, gl, n_rg), device
-                ):
-                    total, mism = _retry.retry_call(
-                        dispatch_resident, site="bqsr.observe.dispatch"
-                    )
-                rg_names = ds.read_groups.names + ["null"]
-                return total, mism, rg_names, gl
-
-            def dispatch():
-                # ship + scatter-add as one retryable unit: re-running
-                # the commit and the jit dispatch is idempotent
-                faults.point("device.dispatch", device=device)
-                return observe_kernel(
-                    # adam-tpu: noqa[residency] reason=non-resident fallback: residency off, a dead handle, or a replay re-ships from the host ingest copy
-                    _put(pad_rows_np(b.bases, g, schema.BASE_PAD, cols=gl)),
-                    # adam-tpu: noqa[residency] reason=non-resident fallback: residency off, a dead handle, or a replay re-ships from the host ingest copy
-                    _put(pad_rows_np(b.quals, g, schema.QUAL_PAD, cols=gl)),
-                    _put(pad_rows_np(b.lengths, g, 0)),
-                    _put(pad_rows_np(b.flags, g, schema.FLAG_UNMAPPED)),
-                    _put(pad_rows_np(b.read_group_idx, g, -1)),
-                    _put(pad_rows_np(residue_ok, g, False, cols=gl)),
-                    _put(pad_rows_np(is_mm, g, False, cols=gl)),
-                    _put(pad_rows_np(read_ok, g, False)),
-                    n_rg, gl,
-                )
-
-            # ledger key == the prewarm entry key ("bqsr.observe"):
-            # an in-window miss here is a prewarm coverage gap
-            with compile_ledger.track(
-                ("bqsr.observe", g, gl, n_rg), device
-            ):
-                total, mism = _retry.retry_call(
-                    dispatch, site="bqsr.observe.dispatch"
-                )
+    gl = grid_cols(b.lmax)
     rg_names = ds.read_groups.names + ["null"]
     # visit accounting (BaseQualityRecalibration.scala:99-123's logging)
     # — host-resident histograms only: summing a device-backend result
@@ -705,6 +522,246 @@ def _observe_impl(
             int(read_ok.sum() * b.lmax) - n_visited,
         )
     return total, mism, rg_names, gl
+
+
+def _observe_prepare(
+    ds: AlignmentDataset, known_snps: Optional[SnpTable], backend: str,
+    device, mesh, resident,
+):
+    """Build one observe call's inputs -> ``(b, read_ok, run)``;
+    ``run()`` dispatches the backend's kernel -> ``(total, mism)``."""
+    b = ds.batch.to_numpy()
+    from adam_tpu import native
+    from adam_tpu.formats.batch import grid_cols, grid_rows
+    from adam_tpu.formats.strings import StringColumn
+
+    n = b.n_rows
+    md_col = StringColumn.of(ds.sidecar.md)
+    use_native = (
+        backend == "native" and native.available() and len(md_col) >= n
+    )
+    if use_native:
+        # the native walk parses each read's MD inline — no host-side
+        # [N, L] mismatch mask, no vectorized MD tokenize pass
+        is_mm = None
+        has_md = md_col.valid[:n] & np.asarray(b.valid)
+    else:
+        is_mm, _, has_md = batch_md_arrays(
+            ds.batch, ds.sidecar, need_ref_codes=False
+        )
+
+    read_ok = observe_read_mask(b, has_md)
+    count_observe_residues(b, read_ok)
+
+    # one extra bin for RG-less reads (the reference's null readGroup)
+    n_rg = len(ds.read_groups) + 1
+    g = grid_rows(b.n_rows)
+    gl = grid_cols(b.lmax)
+
+    def kernel_dispatch(is_mm):
+        # device/numpy backends: residue filter built host-side (the
+        # module-level helper, shared with the cross-job coalescer)
+        residue_ok = observe_residue_mask(ds, b, known_snps)
+        return _observe_kernel_dispatch(
+            b, residue_ok, is_mm, read_ok, n_rg, g, gl, backend, device,
+            mesh, resident,
+        )
+
+    if not use_native:
+        return b, read_ok, kernel_dispatch(is_mm)
+    snp_keys = None
+    if known_snps is not None and len(known_snps):
+        # known-SNP masking runs inside the native kernel's cigar walk
+        # (sorted site-key binary search per residue) — the [N, L] i64
+        # position matrix (~3 GB at WGS batch sizes) never materializes
+        snp_keys = known_snps.site_keys(ds.seq_dict.names)
+
+    def dispatch_native():
+        nat = native.bqsr_observe(
+            b.bases, b.quals, b.lengths, b.flags, b.read_group_idx,
+            b.cigar_ops, b.cigar_lens, b.cigar_n,
+            None, is_mm, read_ok, n_rg, gl,
+            contig_idx=b.contig_idx, start=b.start, snp_keys=snp_keys,
+            md_buf=md_col.buf, md_off=md_col.offsets[: n + 1],
+        )
+        if nat is not None:
+            return nat  # host arrays: downstream table math stays host
+        mm, _, _hm = batch_md_arrays(
+            ds.batch, ds.sidecar, need_ref_codes=False
+        )
+        return kernel_dispatch(mm)()
+
+    return b, read_ok, dispatch_native
+
+
+def _observe_kernel_dispatch(
+    b, residue_ok, is_mm, read_ok, n_rg: int, g: int, gl: int,
+    backend: str, device, mesh, resident,
+):
+    """Pad and bit-pack the observe masks for ``backend`` -> a zero-arg
+    ``run`` that dispatches -> ``(total, mism)``.  Each device ``run``
+    keeps its own ``compile_ledger.track`` with the key literal, which
+    the dispatch-ledger rule cross-checks against the prewarm registry."""
+    lmax = b.lmax
+    if backend == "numpy":
+        def run_numpy():
+            total, mism = observe_kernel_np(
+                b.bases, b.quals, b.lengths, b.flags, b.read_group_idx,
+                residue_ok, is_mm, read_ok, n_rg, lmax,
+            )
+            # center the [-lmax, lmax] cycle slots inside the grid-width
+            # table so every backend returns the same [.., 2*gl+1, ..]
+            # shape (merge_observations pads by gl, not lmax)
+            if gl != lmax:
+                shape = (n_rg, N_QUAL, 2 * gl + 1, N_DINUC)
+                t2 = np.zeros(shape, np.int64)
+                m2 = np.zeros(shape, np.int64)
+                off = gl - lmax
+                t2[:, :, off : off + 2 * lmax + 1, :] = total
+                m2[:, :, off : off + 2 * lmax + 1, :] = mism
+                total, mism = t2, m2
+            return total, mism
+
+        return run_numpy
+    from adam_tpu.formats.batch import pad_rows_np
+    from adam_tpu.utils import compile_ledger, faults
+    from adam_tpu.utils import retry as _retry
+
+    site = "bqsr.observe.dispatch"
+
+    if mesh is not None:
+        gm = mesh.rows_for(g)
+        rw = resident
+        if rw is not None and not (
+            rw.alive and rw.device == "mesh"
+            and rw.g == gm and rw.gl == gl
+        ):
+            rw = None
+        if rw is not None:
+            from adam_tpu.ops.colpack import pack_mask_bits
+
+            res_pk = pack_mask_bits(
+                pad_rows_np(residue_ok, gm, False, cols=gl)
+            )
+            mm_pk = pack_mask_bits(
+                pad_rows_np(is_mm, gm, False, cols=gl)
+            )
+            rd_pad = pad_rows_np(read_ok, gm, False)
+
+            def dispatch_mesh_resident():
+                # per-attempt placement of the small per-pass
+                # inputs keeps the retry idempotent even when the
+                # donating variant consumed a prior attempt's masks
+                faults.point("device.dispatch")
+                return mesh.observe_window_resident(
+                    rw, res_pk, mm_pk, rd_pad, n_rg, gl
+                )
+
+            def run_mesh_resident():
+                with compile_ledger.track(
+                    ("mesh.observe_packed", gm, gl, n_rg), mesh.ledger_key()
+                ):
+                    return _retry.retry_call(dispatch_mesh_resident, site=site)
+
+            return run_mesh_resident
+
+        args = (
+            # adam-tpu: noqa[residency] reason=non-resident fallback: residency off, a dead handle, or a replay re-ships from the host ingest copy
+            pad_rows_np(b.bases, gm, schema.BASE_PAD, cols=gl),
+            # adam-tpu: noqa[residency] reason=non-resident fallback: residency off, a dead handle, or a replay re-ships from the host ingest copy
+            pad_rows_np(b.quals, gm, schema.QUAL_PAD, cols=gl),
+            pad_rows_np(b.lengths, gm, 0),
+            pad_rows_np(b.flags, gm, schema.FLAG_UNMAPPED),
+            pad_rows_np(b.read_group_idx, gm, -1),
+            pad_rows_np(residue_ok, gm, False, cols=gl),
+            pad_rows_np(is_mm, gm, False, cols=gl),
+            pad_rows_np(read_ok, gm, False),
+        )
+
+        def dispatch_mesh():
+            # the sharded placement + collective dispatch re-run as
+            # one idempotent unit, exactly like the pool path
+            faults.point("device.dispatch")
+            return mesh.observe_window(args, n_rg, gl)
+
+        def run_mesh():
+            # ledger key == the mesh prewarm entry key: an in-window
+            # miss here is a mesh prewarm coverage gap
+            with compile_ledger.track(
+                ("mesh.observe", gm, gl, n_rg), mesh.ledger_key()
+            ):
+                return _retry.retry_call(dispatch_mesh, site=site)
+
+        return run_mesh
+
+    from adam_tpu.parallel.device_pool import donation_ok, putter
+
+    _put = putter(device)
+    rw = resident
+    if rw is not None and not (
+        rw.alive and rw.device is device
+        and rw.g == g and rw.gl == gl
+    ):
+        rw = None
+    if rw is not None:
+        from adam_tpu.ops.colpack import pack_mask_bits
+
+        res_pk = pack_mask_bits(
+            pad_rows_np(residue_ok, g, False, cols=gl)
+        )
+        mm_pk = pack_mask_bits(
+            pad_rows_np(is_mm, g, False, cols=gl)
+        )
+        rd_pad = pad_rows_np(read_ok, g, False)
+
+        def dispatch_resident():
+            # ingest-once H2D: the five resident arrays stay
+            # put; only the bit-packed masks + read filter ship
+            # (fresh placements per attempt, so the donating
+            # variant's consumed masks never re-enter a retry)
+            faults.point("device.dispatch", device=device)
+            return jit_variant(
+                "observe_packed", donation_ok(device)
+            )(
+                *rw.args(), _put(res_pk), _put(mm_pk),
+                _put(rd_pad), n_rg, gl,
+            )
+
+        def run_resident():
+            # ledger key == observe_packed_prewarm_entry's key
+            with compile_ledger.track(
+                ("bqsr.observe_packed", g, gl, n_rg), device
+            ):
+                return _retry.retry_call(dispatch_resident, site=site)
+
+        return run_resident
+
+    padded = (
+        # adam-tpu: noqa[residency] reason=non-resident fallback: residency off, a dead handle, or a replay re-ships from the host ingest copy
+        pad_rows_np(b.bases, g, schema.BASE_PAD, cols=gl),
+        # adam-tpu: noqa[residency] reason=non-resident fallback: residency off, a dead handle, or a replay re-ships from the host ingest copy
+        pad_rows_np(b.quals, g, schema.QUAL_PAD, cols=gl),
+        pad_rows_np(b.lengths, g, 0),
+        pad_rows_np(b.flags, g, schema.FLAG_UNMAPPED),
+        pad_rows_np(b.read_group_idx, g, -1),
+        pad_rows_np(residue_ok, g, False, cols=gl),
+        pad_rows_np(is_mm, g, False, cols=gl),
+        pad_rows_np(read_ok, g, False),
+    )
+
+    def dispatch():
+        # ship + scatter-add as one retryable unit: re-running
+        # the commit and the jit dispatch is idempotent
+        faults.point("device.dispatch", device=device)
+        return observe_kernel(*[_put(a) for a in padded], n_rg, gl)
+
+    def run():
+        # ledger key == the prewarm entry key ("bqsr.observe"):
+        # an in-window miss here is a prewarm coverage gap
+        with compile_ledger.track(("bqsr.observe", g, gl, n_rg), device):
+            return _retry.retry_call(dispatch, site=site)
+
+    return run
 
 
 def build_observation_table(
@@ -1293,19 +1350,23 @@ def fused_bc_dispatch(
         _tele.SPAN_FUSED_BC, backend=backend,
         reads=int(ds.batch.n_rows), **attrs,
     ):
-        is_mm, _, has_md = batch_md_arrays(
-            ds.batch, ds.sidecar, need_ref_codes=False
-        )
-        read_ok = observe_read_mask(b, has_md)
-        residue_ok = observe_residue_mask(ds, b, known_snps)
-        pack_lens_q = _apply_pack_lens(b)
-        pack_lens_b = _apply_pack_lens_bases(b)
-
+        gm = mesh.rows_for(g) if mesh is not None else g
         if mesh is not None:
-            gm = mesh.rows_for(g)
-            if not (rw.alive and rw.device == "mesh"
-                    and rw.g == gm and rw.gl == glc):
-                return None
+            live = rw.device == "mesh"
+        else:
+            live = rw.device is device
+        if not (live and rw.alive and rw.g == gm and rw.gl == glc):
+            return None
+        # the observe half's host prep (and the apply half's per-row
+        # lengths): one bqsr.observe.prep, ended before the dispatch
+        with _tele.TRACE.span(_tele.SPAN_BQSR_OBSERVE_PREP, reads=n):
+            is_mm, _, has_md = batch_md_arrays(
+                ds.batch, ds.sidecar, need_ref_codes=False
+            )
+            read_ok = observe_read_mask(b, has_md)
+            residue_ok = observe_residue_mask(ds, b, known_snps)
+            pack_lens_q = _apply_pack_lens(b)
+            pack_lens_b = _apply_pack_lens_bases(b)
             res_pk = pack_mask_bits(
                 pad_rows_np(residue_ok, gm, False, cols=glc)
             )
@@ -1313,9 +1374,16 @@ def fused_bc_dispatch(
             rd_pad = pad_rows_np(read_ok, gm, False)
             hq_pad = pad_rows_np(b.has_qual, gm, False)
             vd_pad = pad_rows_np(b.valid, gm, False)
-            lens_q_pad = pad_rows_np(pack_lens_q, gm, 0)
-            lens_b_pad = pad_rows_np(pack_lens_b, gm, 0)
+            if mesh is not None:
+                lens_q_pad = pad_rows_np(pack_lens_q, gm, 0)
+                lens_b_pad = pad_rows_np(pack_lens_b, gm, 0)
+            else:
+                total_q = int(pack_lens_q.sum())
+                total_b = int(pack_lens_b.sum())
+                cut_q = min(g * glc, fetch_grid(total_q))
+                cut_b = min(g * glc, fetch_grid(total_b))
 
+        if mesh is not None:
             def dispatch_mesh_fused():
                 faults.point("device.dispatch")
                 if not rw.alive:
@@ -1347,24 +1415,14 @@ def fused_bc_dispatch(
                 )
             if got is None:
                 return None
+            count_observe_residues(b, read_ok)
+            count_apply_residues(b)
             total, mism, (q_slices, b_slices) = got
             handle = (ds, b, ("packed2", q_slices, pack_lens_q,
                               b_slices, pack_lens_b))
             return handle, (total, mism, rg_names, glc)
 
-        if not (rw.alive and rw.device is device
-                and rw.g == g and rw.gl == glc):
-            return None
         _put = putter(device)
-        res_pk = pack_mask_bits(pad_rows_np(residue_ok, g, False, cols=glc))
-        mm_pk = pack_mask_bits(pad_rows_np(is_mm, g, False, cols=glc))
-        rd_pad = pad_rows_np(read_ok, g, False)
-        hq_pad = pad_rows_np(b.has_qual, g, False)
-        vd_pad = pad_rows_np(b.valid, g, False)
-        total_q = int(pack_lens_q.sum())
-        total_b = int(pack_lens_b.sum())
-        cut_q = min(g * glc, fetch_grid(total_q))
-        cut_b = min(g * glc, fetch_grid(total_b))
 
         def _placed_table():
             if isinstance(phred_table, np.ndarray):
@@ -1399,6 +1457,8 @@ def fused_bc_dispatch(
             )
         if got is None:
             return None
+        count_observe_residues(b, read_ok)
+        count_apply_residues(b)
         total, mism, pq, pb = got
         handle = (ds, b, ("packed2", [(pq, total_q)], pack_lens_q,
                           [(pb, total_b)], pack_lens_b))
@@ -1410,6 +1470,7 @@ def _apply_dispatch_impl(
     mesh=None, pack: bool = False, resident=None,
 ):
     b = ds.batch.to_numpy()
+    count_apply_residues(b)
     if backend == "device" and mesh is not None:
         from adam_tpu.formats.batch import grid_cols, grid_rows, pad_rows_np
         from adam_tpu.utils import compile_ledger, faults

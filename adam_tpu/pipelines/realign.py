@@ -44,6 +44,7 @@ from adam_tpu.api.datasets import AlignmentDataset
 from adam_tpu.formats import schema
 from adam_tpu.models.snp_table import IndelTable
 from adam_tpu.ops.mdtag import MdTag, parse_cigar
+from adam_tpu.utils import telemetry as _tele
 
 MAX_INDEL_SIZE = 500
 MAX_CONSENSUS_NUMBER = 30
@@ -857,298 +858,313 @@ def _realign_indels_py(
     target_mapping: str = "overlap",
     sweep_devices=None,
 ) -> AlignmentDataset:
-    b = ds.batch.to_numpy()
-    n = b.n_rows
-    if n == 0:
-        return ds
-    targets = find_targets(ds, max_target_size, max_indel_size)
-    if not targets:
-        return ds
-    names = ds.seq_dict.names
-    flags = np.asarray(b.flags)
-    mapped = ((flags & schema.FLAG_UNMAPPED) == 0) & np.asarray(b.valid)
-    tidx = map_batch_to_targets(b, targets, names, mode=target_mapping)
+    with _tele.TRACE.span(_tele.SPAN_REALIGN_TARGETS):
+        b = ds.batch.to_numpy()
+        n = b.n_rows
+        if n == 0:
+            return ds
+        targets = find_targets(ds, max_target_size, max_indel_size)
+        if not targets:
+            return ds
+        names = ds.seq_dict.names
+        flags = np.asarray(b.flags)
+        mapped = ((flags & schema.FLAG_UNMAPPED) == 0) & np.asarray(b.valid)
+        tidx = map_batch_to_targets(b, targets, names, mode=target_mapping)
 
-    # group rows by target, position-sorted within the group — the shared
-    # vectorized construction (see _group_candidates for why shared)
-    srows, goff, gtid = _group_candidates(b, tidx, mapped)
-    groups: dict[int, list[int]] = {
-        int(gtid[g]): [int(i) for i in srows[goff[g]:goff[g + 1]]]
-        for g in range(len(gtid))
-    }
+        # group rows by target, position-sorted within the group — the
+        # shared vectorized construction (see _group_candidates for why)
+        srows, goff, gtid = _group_candidates(b, tidx, mapped)
+        groups: dict[int, list[int]] = {
+            int(gtid[g]): [int(i) for i in srows[goff[g]:goff[g + 1]]]
+            for g in range(len(gtid))
+        }
 
-    new_batch = jax.tree.map(np.array, b)  # writable copies
-    side = ds.sidecar
+    with _tele.TRACE.span(_tele.SPAN_REALIGN_CONSENSUS):
+        new_batch = jax.tree.map(np.array, b)  # writable copies
+        side = ds.sidecar
 
-    # vectorized per-row MD columns (one native tokenize, no per-read
-    # parse): mismatch mask -> to_clean membership + positional orig-qual
-    # sums; ref codes -> implied reference for every single-M read
-    from adam_tpu.ops.mdtag import batch_md_arrays
+        # vectorized per-row MD columns (one native tokenize, no per-read
+        # parse): mismatch mask -> to_clean membership + positional
+        # orig-qual sums; ref codes -> implied reference for every
+        # single-M read
+        from adam_tpu.ops.mdtag import batch_md_arrays
 
-    is_mm, ref_codes, has_md_vec = batch_md_arrays(
-        ds.batch, side, need_ref_codes=True
-    )
-    row_has_mm = is_mm.any(axis=1)
-    mm_qual = np.where(is_mm, np.asarray(b.quals), 0).sum(axis=1)
-    # sparse overrides: only realigned rows get new MD/attrs — the full
-    # sidecar is never materialized as python strings (8M reads would
-    # cost ~30s just in string churn)
-    new_md: dict[int, Optional[str]] = {}
-    new_attrs: dict[int, str] = {}
-    rng = rng or random.Random(0)
-
-    # ---- phase 1 (host): per group, rebuild reference + consensuses ----
-    # bulk per-row precomputation over all grouped rows (one LUT/decode
-    # pass instead of a numpy-call per read — the single-core host is the
-    # pipeline's scarce resource)
-    all_rows = np.concatenate([np.asarray(r) for r in groups.values()]) if groups else np.zeros(0, np.int64)
-    seq_of: dict[int, str] = {}
-    ref_of: dict[int, str] = {}
-    if len(all_rows):
-        purev = (
-            (np.asarray(b.cigar_n)[all_rows] == 1)
-            & (np.asarray(b.cigar_ops)[all_rows, 0] == schema.CIGAR_M)
-            & has_md_vec[all_rows]
+        is_mm, ref_codes, has_md_vec = batch_md_arrays(
+            ds.batch, side, need_ref_codes=True
         )
-        prows = all_rows[purev]
-        if len(prows):
-            ref_of = dict(
-                zip(
-                    (int(i) for i in prows),
-                    schema.decode_bases_bulk(
-                        ref_codes[prows], np.asarray(b.lengths)[prows]
-                    ),
-                )
+        row_has_mm = is_mm.any(axis=1)
+        mm_qual = np.where(is_mm, np.asarray(b.quals), 0).sum(axis=1)
+        # sparse overrides: only realigned rows get new MD/attrs — the
+        # full sidecar is never materialized as python strings (8M reads
+        # would cost ~30s just in string churn)
+        new_md: dict[int, Optional[str]] = {}
+        new_attrs: dict[int, str] = {}
+        rng = rng or random.Random(0)
+
+        # ---- phase 1 (host): per group, rebuild reference + consensuses
+        # bulk per-row precomputation over all grouped rows (one
+        # LUT/decode pass instead of a numpy-call per read — the
+        # single-core host is the pipeline's scarce resource)
+        all_rows = (
+            np.concatenate([np.asarray(r) for r in groups.values()])
+            if groups else np.zeros(0, np.int64)
+        )
+        seq_of: dict[int, str] = {}
+        ref_of: dict[int, str] = {}
+        if len(all_rows):
+            purev = (
+                (np.asarray(b.cigar_n)[all_rows] == 1)
+                & (np.asarray(b.cigar_ops)[all_rows, 0] == schema.CIGAR_M)
+                & has_md_vec[all_rows]
             )
-        # sequences are only needed for rows that materialize a _Read —
-        # the pure clean majority (in ref_of, no mismatches) is skipped
-        # by the light path below and never decodes
-        heavy = all_rows[~(purev & ~row_has_mm[all_rows])]
-        if len(heavy):
-            seq_of = dict(
-                zip(
-                    (int(i) for i in heavy),
-                    schema.decode_bases_bulk(
-                        np.asarray(b.bases)[heavy],
-                        np.asarray(b.lengths)[heavy],
-                    ),
-                )
-            )
-    _CC = schema.CIGAR_CHARS
-
-    group_ctx = {}
-    res_q: dict[int, np.ndarray] = {}  # per target: [n_reads, n_cons]
-    res_o: dict[int, np.ndarray] = {}
-
-    # ---- phase 2 machinery, interleaved with phase 1 ------------------
-    # tasks are grouped into power-of-two (read, consensus) length
-    # buckets so a single max_target_size consensus doesn't inflate
-    # every (read x consensus) pair in the batch, and each bucket
-    # flushes to the device in FIXED-size chunks (one compiled shape per
-    # (lr, lc) bucket — a data-dependent batch dim compiled a fresh
-    # kernel per size).  Chunks dispatch asynchronously *while phase 1 is still
-    # building later groups* (quals travel as u8; the kernel widens on
-    # device); results stay on device and one fetch pass drains them
-    # after the last flush — the chip sweeps target k's pairs while the
-    # single-core host rebuilds target k+1's reference.
-    CH = 8192   # tasks per dispatch (fixed -> one compiled shape/bucket)
-    # consensus slots: large enough that dense data (tasks-per-consensus
-    # = group size >= 4) never flushes early on the cons trigger, small
-    # enough that the always-full-size table transfer stays ~1 MB
-    NC = 2048
-    _buckets: dict[tuple[int, int], dict] = {}
-    _pending = []  # (chunk tasks, device (best_q, best_o))
-    _remaining: dict[int, int] = {}  # target -> sweep results outstanding
-    # fan sweep chunks across the pool/mesh device set (probe-paced
-    # weighted round-robin); None = the default device, the old behavior
-    _sched = None
-    if sweep_devices is not None and len(sweep_devices) > 1:
-        from adam_tpu.parallel.device_pool import SweepSchedule
-
-        _sched = SweepSchedule(sweep_devices)
-
-    def _flush_bucket(key) -> None:
-        lr, lc = key
-        st = _buckets.pop(key)
-        tasks = st["tasks"]
-        # two shape tiers per bucket: small flushes (residuals, small
-        # inputs) use a 1024-task shape so a near-empty chunk doesn't
-        # pay the full 8192-row compute on slow backends; both tiers
-        # stay fixed so the compile-shape set is bounded at two
-        ch = CH if len(tasks) > 1024 else 1024
-        nc = NC if ch == CH else 1024
-        rc = np.full((ch, lr), schema.BASE_PAD, np.uint8)
-        rq = np.zeros((ch, lr), np.uint8)
-        rl = np.zeros(ch, np.int32)
-        ct = np.full((nc, lc), schema.BASE_PAD, np.uint8)
-        cl = np.zeros(nc, np.int32)
-        for s, codes in enumerate(st["cons"]):
-            ct[s, : len(codes)] = codes
-            cl[s] = len(codes)
-        cidx = np.zeros(ch, np.int32)
-        for k, (_t, _ri, _ci, r, cs) in enumerate(tasks):
-            rc[k, : len(r.codes)] = r.codes
-            rq[k, : len(r.quals)] = r.quals
-            rl[k] = len(r.codes)
-            cidx[k] = cs
-        # padded task rows gather consensus slot 0 and are never read back
-        from adam_tpu.parallel.device_pool import putter as _putter
-        from adam_tpu.utils import compile_ledger
-
-        dev = _sched.next_device() if _sched is not None else None
-        _put = _putter(dev)  # commit + h2d transfer accounting
-        with compile_ledger.track(("realign.sweep", ch, lr, nc, lc), dev):
-            _pending.append((tasks, sweep_kernel_gather(
-                _put(rc), _put(rq), _put(rl),
-                _put(ct), _put(cl), _put(cidx), lr, lc,
-            )))
-
-    def _enqueue_sweep(task) -> None:
-        t, ri, ci, r, cons_codes = task
-        key = sweep_bucket_shape(len(r.codes), len(cons_codes))
-        st = _buckets.get(key)
-        if st is None:
-            st = _buckets[key] = {"tasks": [], "cmap": {}, "cons": []}
-        cs = st["cmap"].get(id(cons_codes))
-        if cs is None:
-            cs = len(st["cons"])
-            st["cmap"][id(cons_codes)] = cs
-            st["cons"].append(cons_codes)
-        st["tasks"].append((t, ri, ci, r, cs))
-        if len(st["tasks"]) >= CH or len(st["cons"]) >= NC:
-            _flush_bucket(key)
-    for t, rows in groups.items():
-        reads = []
-        extra_refs = []
-        for i in rows:
-            if i in ref_of and not row_has_mm[i]:
-                # pure clean majority: never swept, never rewritten —
-                # contributes only its reference slice to the window
-                # rebuild, so no _Read is materialized at all
-                s0 = int(b.start[i])
-                extra_refs.append((ref_of[i], s0, s0 + int(b.lengths[i])))
-                continue
-            L = int(b.lengths[i])
-            seq = seq_of[i]
-            nc = int(b.cigar_n[i])
-            cig = [
-                (int(b.cigar_lens[i, k]), _CC[b.cigar_ops[i, k]])
-                for k in range(nc)
-            ]
-            pure = nc == 1 and b.cigar_ops[i, 0] == schema.CIGAR_M
-            has_md_i = bool(has_md_vec[i])
-            if pure or not has_md_i:
-                md = None  # pure-M rows never need a parsed MdTag
-            else:
-                md = MdTag.parse(side.md[i], int(b.start[i]))
-            if not has_md_i:
-                ref = None
-            elif pure:
-                ref = ref_of[i]
-            else:
-                ref = md.get_reference(seq, cig)
-            reads.append(
-                _Read(
-                    row=i,
-                    seq=seq,
-                    quals=np.asarray(b.quals[i][:L], np.int32),
-                    start=int(b.start[i]),
-                    cigar=cig,
-                    md=md,
-                    mapq=int(b.mapq[i]),
-                    ref=ref,
-                    pure=pure,
-                    codes=np.asarray(b.bases[i][:L]),
-                )
-            )
-        # reads that already match the reference pass through untouched
-        to_clean = [
-            r for r in reads if not has_md_vec[r.row] or row_has_mm[r.row]
-        ]
-        if not to_clean:
-            continue
-        try:
-            reference, ref_start, ref_end = _get_reference_from_reads(
-                reads, extra_refs
-            )
-        except ValueError:
-            continue
-        contig_idx = targets[t].contig_idx
-
-        # preprocess: left-normalize single-indel reads (and SW-realign
-        # everything first under the smithwaterman model)
-        if consensus_model == "smithwaterman":
-            to_clean = _sw_preprocess(
-                to_clean, reference, ref_start, sw_weights
-            )
-        processed = []
-        for r in to_clean:
-            if cigar_num_alignment_blocks(r.cigar) == 2:
-                new_cigar = left_align_indel(r.seq, r.cigar, r.md)
-                if new_cigar != r.cigar:
-                    md = MdTag.move_alignment(
-                        r.ref, r.seq, cigar_to_string(new_cigar), r.start,
-                    ) if r.md is not None else None
-                    processed.append(
-                        dc_replace(r, cigar=new_cigar, md=md, dirty=True)
+            prows = all_rows[purev]
+            if len(prows):
+                ref_of = dict(
+                    zip(
+                        (int(i) for i in prows),
+                        schema.decode_bases_bulk(
+                            ref_codes[prows], np.asarray(b.lengths)[prows]
+                        ),
                     )
+                )
+            # sequences are only needed for rows that materialize a _Read —
+            # the pure clean majority (in ref_of, no mismatches) is skipped
+            # by the light path below and never decodes
+            heavy = all_rows[~(purev & ~row_has_mm[all_rows])]
+            if len(heavy):
+                seq_of = dict(
+                    zip(
+                        (int(i) for i in heavy),
+                        schema.decode_bases_bulk(
+                            np.asarray(b.bases)[heavy],
+                            np.asarray(b.lengths)[heavy],
+                        ),
+                    )
+                )
+        _CC = schema.CIGAR_CHARS
+
+        group_ctx = {}
+        res_q: dict[int, np.ndarray] = {}  # per target: [n_reads, n_cons]
+        res_o: dict[int, np.ndarray] = {}
+
+        # ---- phase 2 machinery, interleaved with phase 1 ------------------
+        # tasks are grouped into power-of-two (read, consensus) length
+        # buckets so a single max_target_size consensus doesn't inflate
+        # every (read x consensus) pair in the batch, and each bucket
+        # flushes to the device in FIXED-size chunks (one compiled shape
+        # per (lr, lc) bucket — a data-dependent batch dim compiled a
+        # fresh kernel per size).  Chunks dispatch asynchronously *while
+        # phase 1 is still building later groups* (quals travel as u8;
+        # the kernel widens on device); results stay on device and one
+        # fetch pass drains them after the last flush — the chip sweeps
+        # target k's pairs while the single-core host rebuilds target
+        # k+1's reference.
+        CH = 8192   # tasks per dispatch (fixed -> one compiled shape/bucket)
+        # consensus slots: large enough that dense data (tasks-per-consensus
+        # = group size >= 4) never flushes early on the cons trigger, small
+        # enough that the always-full-size table transfer stays ~1 MB
+        NC = 2048
+        _buckets: dict[tuple[int, int], dict] = {}
+        _pending = []  # (chunk tasks, device (best_q, best_o))
+        _remaining: dict[int, int] = {}  # target -> sweep results outstanding
+        # fan sweep chunks across the pool/mesh device set (probe-paced
+        # weighted round-robin); None = the default device, the old behavior
+        _sched = None
+        if sweep_devices is not None and len(sweep_devices) > 1:
+            from adam_tpu.parallel.device_pool import SweepSchedule
+
+            _sched = SweepSchedule(sweep_devices)
+
+        def _flush_bucket(key) -> None:
+            lr, lc = key
+            st = _buckets.pop(key)
+            tasks = st["tasks"]
+            # two shape tiers per bucket: small flushes (residuals, small
+            # inputs) use a 1024-task shape so a near-empty chunk doesn't
+            # pay the full 8192-row compute on slow backends; both tiers
+            # stay fixed so the compile-shape set is bounded at two
+            ch = CH if len(tasks) > 1024 else 1024
+            nc = NC if ch == CH else 1024
+            rc = np.full((ch, lr), schema.BASE_PAD, np.uint8)
+            rq = np.zeros((ch, lr), np.uint8)
+            rl = np.zeros(ch, np.int32)
+            ct = np.full((nc, lc), schema.BASE_PAD, np.uint8)
+            cl = np.zeros(nc, np.int32)
+            for s, codes in enumerate(st["cons"]):
+                ct[s, : len(codes)] = codes
+                cl[s] = len(codes)
+            cidx = np.zeros(ch, np.int32)
+            for k, (_t, _ri, _ci, r, cs) in enumerate(tasks):
+                rc[k, : len(r.codes)] = r.codes
+                rq[k, : len(r.quals)] = r.quals
+                rl[k] = len(r.codes)
+                cidx[k] = cs
+            # padded task rows gather consensus slot 0 and are never read back
+            from adam_tpu.parallel.device_pool import putter as _putter
+            from adam_tpu.utils import compile_ledger
+
+            dev = _sched.next_device() if _sched is not None else None
+            _put = _putter(dev)  # commit + h2d transfer accounting
+            with compile_ledger.track(("realign.sweep", ch, lr, nc, lc), dev):
+                _pending.append((tasks, sweep_kernel_gather(
+                    _put(rc), _put(rq), _put(rl),
+                    _put(ct), _put(cl), _put(cidx), lr, lc,
+                )))
+
+        def _enqueue_sweep(task) -> None:
+            t, ri, ci, r, cons_codes = task
+            key = sweep_bucket_shape(len(r.codes), len(cons_codes))
+            st = _buckets.get(key)
+            if st is None:
+                st = _buckets[key] = {"tasks": [], "cmap": {}, "cons": []}
+            cs = st["cmap"].get(id(cons_codes))
+            if cs is None:
+                cs = len(st["cons"])
+                st["cmap"][id(cons_codes)] = cs
+                st["cons"].append(cons_codes)
+            st["tasks"].append((t, ri, ci, r, cs))
+            if len(st["tasks"]) >= CH or len(st["cons"]) >= NC:
+                _flush_bucket(key)
+        for t, rows in groups.items():
+            reads = []
+            extra_refs = []
+            for i in rows:
+                if i in ref_of and not row_has_mm[i]:
+                    # pure clean majority: never swept, never rewritten —
+                    # contributes only its reference slice to the window
+                    # rebuild, so no _Read is materialized at all
+                    s0 = int(b.start[i])
+                    extra_refs.append((ref_of[i], s0, s0 + int(b.lengths[i])))
+                    continue
+                L = int(b.lengths[i])
+                seq = seq_of[i]
+                nc = int(b.cigar_n[i])
+                cig = [
+                    (int(b.cigar_lens[i, k]), _CC[b.cigar_ops[i, k]])
+                    for k in range(nc)
+                ]
+                pure = nc == 1 and b.cigar_ops[i, 0] == schema.CIGAR_M
+                has_md_i = bool(has_md_vec[i])
+                if pure or not has_md_i:
+                    md = None  # pure-M rows never need a parsed MdTag
+                else:
+                    md = MdTag.parse(side.md[i], int(b.start[i]))
+                if not has_md_i:
+                    ref = None
+                elif pure:
+                    ref = ref_of[i]
+                else:
+                    ref = md.get_reference(seq, cig)
+                reads.append(
+                    _Read(
+                        row=i,
+                        seq=seq,
+                        quals=np.asarray(b.quals[i][:L], np.int32),
+                        start=int(b.start[i]),
+                        cigar=cig,
+                        md=md,
+                        mapq=int(b.mapq[i]),
+                        ref=ref,
+                        pure=pure,
+                        codes=np.asarray(b.bases[i][:L]),
+                    )
+                )
+            # reads that already match the reference pass through untouched
+            to_clean = [
+                r for r in reads if not has_md_vec[r.row] or row_has_mm[r.row]
+            ]
+            if not to_clean:
+                continue
+            try:
+                reference, ref_start, ref_end = _get_reference_from_reads(
+                    reads, extra_refs
+                )
+            except ValueError:
+                continue
+            contig_idx = targets[t].contig_idx
+
+            # preprocess: left-normalize single-indel reads (and SW-realign
+            # everything first under the smithwaterman model)
+            if consensus_model == "smithwaterman":
+                to_clean = _sw_preprocess(
+                    to_clean, reference, ref_start, sw_weights
+                )
+            processed = []
+            for r in to_clean:
+                if cigar_num_alignment_blocks(r.cigar) == 2:
+                    new_cigar = left_align_indel(r.seq, r.cigar, r.md)
+                    if new_cigar != r.cigar:
+                        md = MdTag.move_alignment(
+                            r.ref, r.seq, cigar_to_string(new_cigar), r.start,
+                        ) if r.md is not None else None
+                        processed.append(
+                            dc_replace(r, cigar=new_cigar, md=md, dirty=True)
+                        )
+                    else:
+                        processed.append(r)
                 else:
                     processed.append(r)
+            to_clean = processed
+
+            # find consensuses
+            consensuses: list[Consensus] = []
+            if consensus_model == "knowns" and known_indels is not None:
+                region_name = names[contig_idx]
+                from adam_tpu.models.positions import ReferenceRegion
+
+                for rec in known_indels.get_indels_in_region(
+                    ReferenceRegion(region_name, ref_start, ref_end)
+                ):
+                    consensuses.append(
+                        Consensus(rec.consensus, contig_idx,
+                                  rec.region.start, rec.region.end)
+                    )
             else:
-                processed.append(r)
-        to_clean = processed
-
-        # find consensuses
-        consensuses: list[Consensus] = []
-        if consensus_model == "knowns" and known_indels is not None:
-            region_name = names[contig_idx]
-            from adam_tpu.models.positions import ReferenceRegion
-
-            for rec in known_indels.get_indels_in_region(
-                ReferenceRegion(region_name, ref_start, ref_end)
-            ):
-                consensuses.append(
-                    Consensus(rec.consensus, contig_idx,
-                              rec.region.start, rec.region.end)
+                for r in to_clean:
+                    if r.md is None:
+                        continue
+                    c = generate_alternate_consensus(
+                        r.seq, r.start, contig_idx, r.cigar
+                    )
+                    if c is not None:
+                        consensuses.append(c)
+            # distinct
+            seen = set()
+            uniq = []
+            for c in consensuses:
+                key = (c.consensus, c.index_start, c.index_end)
+                if key not in seen:
+                    seen.add(key)
+                    uniq.append(c)
+            consensuses = uniq
+            if len(consensuses) > max_consensus_number:
+                consensuses = rng.sample(consensuses, max_consensus_number)
+            if not consensuses:
+                # still keep preprocessing results (readsToClean ++ realigned)
+                _write_back(
+                    new_batch, side, new_md, new_attrs, to_clean,
+                    realigned={},
                 )
-        else:
-            for r in to_clean:
-                if r.md is None:
-                    continue
-                c = generate_alternate_consensus(
-                    r.seq, r.start, contig_idx, r.cigar
+                continue
+
+            group_ctx[t] = (
+                to_clean, consensuses, reference, ref_start, ref_end
+            )
+            res_q[t] = np.full(
+                (len(to_clean), len(consensuses)), np.inf, np.float32
+            )
+            res_o[t] = np.full((len(to_clean), len(consensuses)), -1, np.int32)
+            _remaining[t] = len(to_clean) * len(consensuses)
+            for ci, c in enumerate(consensuses):
+                cons_seq = c.insert_into_reference(
+                    reference, ref_start, ref_end
                 )
-                if c is not None:
-                    consensuses.append(c)
-        # distinct
-        seen = set()
-        uniq = []
-        for c in consensuses:
-            key = (c.consensus, c.index_start, c.index_end)
-            if key not in seen:
-                seen.add(key)
-                uniq.append(c)
-        consensuses = uniq
-        if len(consensuses) > max_consensus_number:
-            consensuses = rng.sample(consensuses, max_consensus_number)
-        if not consensuses:
-            # still keep preprocessing results (readsToClean ++ realigned)
-            _write_back(new_batch, side, new_md, new_attrs, to_clean, realigned={})
-            continue
+                # once per consensus
+                cons_codes = schema.encode_bases(cons_seq)
+                for ri, r in enumerate(to_clean):
+                    _enqueue_sweep((t, ri, ci, r, cons_codes))
 
-        group_ctx[t] = (to_clean, consensuses, reference, ref_start, ref_end)
-        res_q[t] = np.full(
-            (len(to_clean), len(consensuses)), np.inf, np.float32
-        )
-        res_o[t] = np.full((len(to_clean), len(consensuses)), -1, np.int32)
-        _remaining[t] = len(to_clean) * len(consensuses)
-        for ci, c in enumerate(consensuses):
-            cons_seq = c.insert_into_reference(reference, ref_start, ref_end)
-            cons_codes = schema.encode_bases(cons_seq)  # once per consensus
-            for ri, r in enumerate(to_clean):
-                _enqueue_sweep((t, ri, ci, r, cons_codes))
-
-    del seq_of, ref_of  # decoded strings live only through phase 1
+        del seq_of, ref_of  # decoded strings live only through phase 1
 
     # ---- phase 2 drain + phase 3, interleaved ----
     # flush residual chunks, then finish each target the moment its last
@@ -1156,9 +1172,10 @@ def _realign_indels_py(
     # while the device is still computing later chunks, instead of
     # blocking through the whole fetch tail first.  Targets write to
     # disjoint rows, so completion order doesn't affect the output.
-    for key in list(_buckets):
-        if _buckets[key]["tasks"]:
-            _flush_bucket(key)
+    with _tele.TRACE.span(_tele.SPAN_REALIGN_SWEEP_DISPATCH):
+        for key in list(_buckets):
+            if _buckets[key]["tasks"]:
+                _flush_bucket(key)
 
     def _finish_target(t: int) -> None:
         to_clean, consensuses, reference, ref_start, ref_end = group_ctx[t]
@@ -1248,25 +1265,29 @@ def _realign_indels_py(
 
     from adam_tpu.utils.transfer import device_fetch as _dfetch
 
-    for chunk, out in _pending:
-        # drain through the transfer helper so the d2h ledger sees the
-        # sweep results (tiny [CH] i32 pairs, but per-pass byte
-        # attribution must not have dark spots)
-        best_q, best_o = _dfetch(out[0]), _dfetch(out[1])
-        for k, (t, ri, ci, _, _) in enumerate(chunk):
-            res_q[t][ri, ci] = best_q[k]
-            res_o[t][ri, ci] = best_o[k]
-            _remaining[t] -= 1
-            if _remaining[t] == 0:
-                _finish_target(t)
+    # decisions interleave with the fetch: each target is decided as
+    # its last sweep result lands, inside realign.sweep_fetch
+    with _tele.TRACE.span(_tele.SPAN_REALIGN_SWEEP_FETCH):
+        for chunk, out in _pending:
+            # drain through the transfer helper so the d2h ledger sees the
+            # sweep results (tiny [CH] i32 pairs, but per-pass byte
+            # attribution must not have dark spots)
+            best_q, best_o = _dfetch(out[0]), _dfetch(out[1])
+            for k, (t, ri, ci, _, _) in enumerate(chunk):
+                res_q[t][ri, ci] = best_q[k]
+                res_o[t][ri, ci] = best_o[k]
+                _remaining[t] -= 1
+                if _remaining[t] == 0:
+                    _finish_target(t)
 
-    from adam_tpu.formats.strings import StringColumn, with_overrides
+    with _tele.TRACE.span(_tele.SPAN_REALIGN_DECIDE):
+        from adam_tpu.formats.strings import StringColumn, with_overrides
 
-    new_side = dc_replace(
-        side,
-        md=with_overrides(StringColumn.of(side.md), new_md),
-        attrs=with_overrides(StringColumn.of(side.attrs), new_attrs),
-    )
+        new_side = dc_replace(
+            side,
+            md=with_overrides(StringColumn.of(side.md), new_md),
+            attrs=with_overrides(StringColumn.of(side.attrs), new_attrs),
+        )
     return ds.with_batch(new_batch, new_side)
 
 
@@ -1363,23 +1384,11 @@ def _realign_indels_native(
     consensus generation / MD rewrite) in C++ (native/realign.cpp) and
     the sweep task machinery vectorized.  Returns None when the native
     library is unavailable (caller falls back to the Python path)."""
-    import time as _time
-
     from adam_tpu import native
-    from adam_tpu.utils import instrumentation as _ins
 
     if not native.available():
         return None
-    _t0 = _time.perf_counter()
-
-    def _phase(label):
-        # phase walls for -print_metrics (SweepReadOverReferenceForQuality
-        # -style named timers, instrumentation/Timers.scala:25-81);
-        # no-ops unless recording
-        nonlocal _t0
-        now = _time.perf_counter()
-        _ins.TIMERS.add(label, int((now - _t0) * 1e9))
-        _t0 = now
+    _tr = _tele.TRACE
 
     def _overlap_once(in_dispatch: bool = False):
         nonlocal overlap_work
@@ -1390,256 +1399,265 @@ def _realign_indels_native(
             else:
                 w()
 
-    b = ds.batch.to_numpy()
-    n = b.n_rows
-    if n == 0:
-        _overlap_once()
-        return ds
-    targets = find_targets(ds, max_target_size, max_indel_size)
-    if not targets:
-        _overlap_once()
-        return ds
-    names = ds.seq_dict.names
-    flags = np.asarray(b.flags)
-    mapped = ((flags & schema.FLAG_UNMAPPED) == 0) & np.asarray(b.valid)
-    tidx = map_batch_to_targets(b, targets, names, mode=target_mapping)
-    srows, goff, gtid = _group_candidates(b, tidx, mapped)
+    from adam_tpu.formats.strings import StringColumn, with_overrides
+
+    # phase spans (SweepReadOverReferenceForQuality-style named phases,
+    # instrumentation/Timers.scala:25-81); the overlapped work between
+    # sweep dispatch and fetch is the caller's own span
+    with _tr.span(_tele.SPAN_REALIGN_TARGETS):
+        b = ds.batch.to_numpy()
+        n = b.n_rows
+        targets = (
+            find_targets(ds, max_target_size, max_indel_size) if n else []
+        )
+        srows = ()
+        if targets:
+            names = ds.seq_dict.names
+            flags = np.asarray(b.flags)
+            mapped = (
+                ((flags & schema.FLAG_UNMAPPED) == 0) & np.asarray(b.valid)
+            )
+            tidx = map_batch_to_targets(b, targets, names, mode=target_mapping)
+            srows, goff, gtid = _group_candidates(b, tidx, mapped)
+        if len(srows):
+            G = len(goff) - 1
+            side = ds.sidecar
+            md_col = StringColumn.of(side.md)
+            if len(md_col) >= n:
+                md_buf, md_off = md_col.buf, md_col.offsets[: n + 1]
+                md_valid = md_col.valid[:n] & np.asarray(b.valid)
+            else:
+                md_buf = np.zeros(0, np.uint8)
+                md_off = np.zeros(n + 1, np.int64)
+                md_valid = np.zeros(n, bool)
+            # consensuses come from the indel table only under the
+            # knowns model WITH a table; otherwise (reads model, or
+            # knowns without a table) they are generated from the reads,
+            # as the Python path's else-branch does (realign.py:994)
+            gen_consensus = not (
+                consensus_model == "knowns" and known_indels is not None
+            )
     if not len(srows):
         _overlap_once()
         return ds
-    G = len(goff) - 1
 
-    from adam_tpu.formats.strings import StringColumn, with_overrides
-
-    side = ds.sidecar
-    md_col = StringColumn.of(side.md)
-    if len(md_col) >= n:
-        md_buf, md_off = md_col.buf, md_col.offsets[: n + 1]
-        md_valid = md_col.valid[:n] & np.asarray(b.valid)
-    else:
-        md_buf = np.zeros(0, np.uint8)
-        md_off = np.zeros(n + 1, np.int64)
-        md_valid = np.zeros(n, bool)
-
-    # consensuses come from the indel table only under the knowns model
-    # WITH a table; otherwise (reads model, or knowns without a table)
-    # they are generated from the reads, as the Python path's else-branch
-    # does (realign.py:994)
-    gen_consensus = not (
-        consensus_model == "knowns" and known_indels is not None
-    )
-    _phase("Realign: target map/group")
-    prep = native.realign_prep(
-        b, md_buf, md_off, md_valid.astype(np.uint8), srows, goff,
-        gen_consensus,
-    )
+    with _tr.span(_tele.SPAN_REALIGN_NATIVE_PREP):
+        prep = native.realign_prep(
+            b, md_buf, md_off, md_valid.astype(np.uint8), srows, goff,
+            gen_consensus,
+        )
     if prep is None:
         return None
-    _phase("Realign: native prep")
 
-    t_status = prep["t_status"]
-    t_ref_off = prep["t_ref_off"]
-    t_ref_start = prep["t_ref_start"]
-    t_ref_end = prep["t_ref_end"]
-    ref_all = prep["t_ref_buf"].tobytes().decode("ascii", "replace")
-    r_group = prep["r_group"]
-    r_row = prep["r_row"]
-    r_dirty = prep["r_dirty"].astype(bool)
-    r_md_set = prep["r_md_set"].astype(bool)
-    r_orig = prep["r_orig_qual"]
-    R = len(r_row)
-    rg_off = np.searchsorted(r_group, np.arange(G + 1))
-    c_group = prep["c_group"]
-    cg_off = np.searchsorted(c_group, np.arange(G + 1))
-    c_off = prep["c_seq_off"]
-    c_all = prep["c_seq_buf"].tobytes().decode("ascii", "replace")
-    c_is = prep["c_is"]
-    c_ie = prep["c_ie"]
+    with _tr.span(_tele.SPAN_REALIGN_CONSENSUS):
+        t_status = prep["t_status"]
+        t_ref_off = prep["t_ref_off"]
+        t_ref_start = prep["t_ref_start"]
+        t_ref_end = prep["t_ref_end"]
+        ref_all = prep["t_ref_buf"].tobytes().decode("ascii", "replace")
+        r_group = prep["r_group"]
+        r_row = prep["r_row"]
+        r_dirty = prep["r_dirty"].astype(bool)
+        r_md_set = prep["r_md_set"].astype(bool)
+        r_orig = prep["r_orig_qual"]
+        R = len(r_row)
+        rg_off = np.searchsorted(r_group, np.arange(G + 1))
+        c_group = prep["c_group"]
+        cg_off = np.searchsorted(c_group, np.arange(G + 1))
+        c_off = prep["c_seq_off"]
+        c_all = prep["c_seq_buf"].tobytes().decode("ascii", "replace")
+        c_is = prep["c_is"]
+        c_ie = prep["c_ie"]
 
-    rng = rng or random.Random(0)
-    lengths = np.asarray(b.lengths).astype(np.int64)
-    _log = logging.getLogger(__name__)
+        rng = rng or random.Random(0)
+        lengths = np.asarray(b.lengths).astype(np.int64)
+        _log = logging.getLogger(__name__)
 
-    # ---- per-group consensus finalize (sampling order == Python path) --
-    # grp_cons[g] = list of (cons_str, index_start, index_end)
-    grp_cons: list = [None] * G
-    for g in range(G):
-        if t_status[g] != 0:
-            continue
-        if rg_off[g + 1] == rg_off[g]:
-            continue
-        if consensus_model == "knowns" and known_indels is not None:
-            from adam_tpu.models.positions import ReferenceRegion
+        # ---- per-group consensus finalize (sampling order == Python path) --
+        # grp_cons[g] = list of (cons_str, index_start, index_end)
+        grp_cons: list = [None] * G
+        for g in range(G):
+            if t_status[g] != 0:
+                continue
+            if rg_off[g + 1] == rg_off[g]:
+                continue
+            if consensus_model == "knowns" and known_indels is not None:
+                from adam_tpu.models.positions import ReferenceRegion
 
-            region_name = names[targets[int(gtid[g])].contig_idx]
-            cons = [
-                (rec.consensus, rec.region.start, rec.region.end)
-                for rec in known_indels.get_indels_in_region(
-                    ReferenceRegion(
-                        region_name, int(t_ref_start[g]), int(t_ref_end[g])
+                region_name = names[targets[int(gtid[g])].contig_idx]
+                cons = [
+                    (rec.consensus, rec.region.start, rec.region.end)
+                    for rec in known_indels.get_indels_in_region(
+                        ReferenceRegion(
+                            region_name, int(t_ref_start[g]), int(t_ref_end[g])
+                        )
                     )
-                )
-            ]
-        else:
-            cons = [
-                (c_all[c_off[k]:c_off[k + 1]], int(c_is[k]), int(c_ie[k]))
-                for k in range(cg_off[g], cg_off[g + 1])
-            ]
-        # distinct (native path pre-dedupes the reads model; the knowns
-        # model and the Python path share this exact dedup)
-        seen = set()
-        uniq = []
-        for c in cons:
-            if c not in seen:
-                seen.add(c)
-                uniq.append(c)
-        cons = uniq
-        if len(cons) > max_consensus_number:
-            # random.sample on an index range picks the same positions
-            # as sampling the list itself, preserving rng-state parity
-            cons = [cons[j] for j in
-                    rng.sample(range(len(cons)), max_consensus_number)]
-        grp_cons[g] = cons
+                ]
+            else:
+                cons = [
+                    (c_all[c_off[k]:c_off[k + 1]], int(c_is[k]), int(c_ie[k]))
+                    for k in range(cg_off[g], cg_off[g + 1])
+                ]
+            # distinct (native path pre-dedupes the reads model; the knowns
+            # model and the Python path share this exact dedup)
+            seen = set()
+            uniq = []
+            for c in cons:
+                if c not in seen:
+                    seen.add(c)
+                    uniq.append(c)
+            cons = uniq
+            if len(cons) > max_consensus_number:
+                # random.sample on an index range picks the same positions
+                # as sampling the list itself, preserving rng-state parity
+                cons = [cons[j] for j in
+                        rng.sample(range(len(cons)), max_consensus_number)]
+            grp_cons[g] = cons
 
-    # ---- build the spliced consensus sequences + (target, cons) pairs --
-    # each pair tile sweeps <= rt reads of one target against one
-    # consensus; tiles group by (off, rt) into fixed-shape GEMM batches
-    cons_strs: list = []   # spliced full sequences, global ids
-    grp_cons_base = np.zeros(G + 1, np.int64)
-    for g in range(G):
-        cons = grp_cons[g]
-        grp_cons_base[g + 1] = grp_cons_base[g] + (len(cons) if cons else 0)
-        if not cons:
-            continue
-        ref_start = int(t_ref_start[g])
-        ref_end = int(t_ref_end[g])
-        reference = ref_all[t_ref_off[g]:t_ref_off[g + 1]]
-        for cs, cis, cie in cons:
-            # Consensus.insert_into_reference (realign.py:612-620)
-            if (cis < ref_start or cis > ref_end
-                    or cie - 1 < ref_start or cie - 1 > ref_end):
-                raise ValueError("consensus and reference do not overlap")
-            cons_strs.append(
-                reference[: cis - ref_start] + cs
-                + reference[cie - 1 - ref_start:]
-            )
-
-    # flat result layout: per group, ci-major [nc, nr]
-    grp_task_base = np.zeros(G + 1, np.int64)
-    for g in range(G):
-        nr = int(rg_off[g + 1] - rg_off[g])
-        nc = int(grp_cons_base[g + 1] - grp_cons_base[g])
-        grp_task_base[g + 1] = grp_task_base[g] + nr * nc
-    NT = int(grp_task_base[G])
-    res_q = np.full(NT, np.inf, np.float32)
-    res_o = np.full(NT, -1, np.int32)
-    if NT:
-        cons_lens = np.array([len(s) for s in cons_strs], np.int64)
-        max_cl = int(cons_lens.max()) if len(cons_strs) else 1
-        cons_mat = np.full((len(cons_strs), max_cl), schema.BASE_PAD, np.uint8)
-        for k, s in enumerate(cons_strs):
-            cons_mat[k, : len(s)] = schema.encode_bases(s)
-
-        # pair tiles: rt=16 for small targets, 128-read tiles for large
-        p_res, p_n, p_cid, p_lo, p_off = [], [], [], [], []
+        # ---- build the spliced consensus sequences + (target, cons) pairs --
+        # each pair tile sweeps <= rt reads of one target against one
+        # consensus; tiles group by (off, rt) into fixed-shape GEMM batches
+        cons_strs: list = []   # spliced full sequences, global ids
+        grp_cons_base = np.zeros(G + 1, np.int64)
         for g in range(G):
             cons = grp_cons[g]
+            grp_cons_base[g + 1] = (
+                grp_cons_base[g] + (len(cons) if cons else 0)
+            )
             if not cons:
                 continue
+            ref_start = int(t_ref_start[g])
+            ref_end = int(t_ref_end[g])
+            reference = ref_all[t_ref_off[g]:t_ref_off[g + 1]]
+            for cs, cis, cie in cons:
+                # Consensus.insert_into_reference (realign.py:612-620)
+                if (cis < ref_start or cis > ref_end
+                        or cie - 1 < ref_start or cie - 1 > ref_end):
+                    raise ValueError("consensus and reference do not overlap")
+                cons_strs.append(
+                    reference[: cis - ref_start] + cs
+                    + reference[cie - 1 - ref_start:]
+                )
+
+        # flat result layout: per group, ci-major [nc, nr]
+        grp_task_base = np.zeros(G + 1, np.int64)
+        for g in range(G):
             nr = int(rg_off[g + 1] - rg_off[g])
-            rl_g = lengths[r_row[rg_off[g]:rg_off[g + 1]]]
-            for ci in range(len(cons)):
-                cid = int(grp_cons_base[g]) + ci
-                clen = int(cons_lens[cid])
-                base = int(grp_task_base[g]) + ci * nr
-                for lo in range(0, nr, 128):
-                    nrt = min(128, nr - lo)
-                    need = clen - int(rl_g[lo:lo + nrt].min())
-                    p_res.append(base + lo)
-                    p_n.append(nrt)
-                    p_cid.append(cid)
-                    p_lo.append(int(rg_off[g]) + lo)
-                    p_off.append(max(need, 1))
-        p_res = np.asarray(p_res, np.int64)
-        p_n = np.asarray(p_n, np.int32)
-        p_cid = np.asarray(p_cid, np.int64)
-        p_lo = np.asarray(p_lo, np.int64)
-        p_rt = np.where(p_n <= 16, 16, 128).astype(np.int32)
-        p_offb = _pow2_vec(p_off, 512).astype(np.int64)
-        # intermediate 384 tier: WGS-shaped targets need 250-330 offsets,
-        # and the sweep's im2col+GEMM cost scales linearly with the
-        # padded off — the pow2 jump to 512 wasted ~40% on that band
-        p_offb = np.where(
-            (p_offb == 512) & (np.asarray(p_off) <= 384), 384, p_offb
-        )
+            nc = int(grp_cons_base[g + 1] - grp_cons_base[g])
+            grp_task_base[g + 1] = grp_task_base[g] + nr * nc
+        NT = int(grp_task_base[G])
+        res_q = np.full(NT, np.inf, np.float32)
+        res_o = np.full(NT, -1, np.int32)
+        if NT:
+            cons_lens = np.array([len(s) for s in cons_strs], np.int64)
+            max_cl = int(cons_lens.max()) if len(cons_strs) else 1
+            cons_mat = np.full(
+                (len(cons_strs), max_cl), schema.BASE_PAD, np.uint8
+            )
+            for k, s in enumerate(cons_strs):
+                cons_mat[k, : len(s)] = schema.encode_bases(s)
 
-        _phase("Realign: consensus + tiles")
-        bases_np = np.asarray(b.bases)
-        quals_np = np.asarray(b.quals)
-        L = bases_np.shape[1]
-        lr = int(_pow2_vec(np.array([max(int(lengths.max()), 1)]), 32)[0])
-        cols = min(L, lr)
+            # pair tiles: rt=16 for small targets, 128-read tiles for large
+            p_res, p_n, p_cid, p_lo, p_off = [], [], [], [], []
+            for g in range(G):
+                cons = grp_cons[g]
+                if not cons:
+                    continue
+                nr = int(rg_off[g + 1] - rg_off[g])
+                rl_g = lengths[r_row[rg_off[g]:rg_off[g + 1]]]
+                for ci in range(len(cons)):
+                    cid = int(grp_cons_base[g]) + ci
+                    clen = int(cons_lens[cid])
+                    base = int(grp_task_base[g]) + ci * nr
+                    for lo in range(0, nr, 128):
+                        nrt = min(128, nr - lo)
+                        need = clen - int(rl_g[lo:lo + nrt].min())
+                        p_res.append(base + lo)
+                        p_n.append(nrt)
+                        p_cid.append(cid)
+                        p_lo.append(int(rg_off[g]) + lo)
+                        p_off.append(max(need, 1))
+            p_res = np.asarray(p_res, np.int64)
+            p_n = np.asarray(p_n, np.int32)
+            p_cid = np.asarray(p_cid, np.int64)
+            p_lo = np.asarray(p_lo, np.int64)
+            p_rt = np.where(p_n <= 16, 16, 128).astype(np.int32)
+            p_offb = _pow2_vec(p_off, 512).astype(np.int64)
+            # intermediate 384 tier: WGS-shaped targets need 250-330 offsets,
+            # and the sweep's im2col+GEMM cost scales linearly with the
+            # padded off — the pow2 jump to 512 wasted ~40% on that band
+            p_offb = np.where(
+                (p_offb == 512) & (np.asarray(p_off) <= 384), 384, p_offb
+            )
 
-        # rows into the flat to_clean read index -> batch row, as i32
-        r_row32 = r_row.astype(np.int32)
-        pending = []  # (pair slice indices, device, lazy (best_q, best_o))
-        # fan GEMM chunks across the pool/mesh devices (probe-paced
-        # weighted round-robin, ROADMAP "realign sweep scheduling"):
-        # until now every bucket dispatched to the default device while
-        # the rest of the pool idled through the 1.31 s sweep net
-        from adam_tpu.parallel.device_pool import putter as _putter
+    pending = []  # (pair slice indices, device, lazy (best_q, best_o))
+    with _tr.span(_tele.SPAN_REALIGN_SWEEP_DISPATCH):
+        if NT:
+            bases_np = np.asarray(b.bases)
+            quals_np = np.asarray(b.quals)
+            L = bases_np.shape[1]
+            lr = int(_pow2_vec(np.array([max(int(lengths.max()), 1)]), 32)[0])
+            cols = min(L, lr)
 
-        _sched = None
-        if sweep_devices is not None and len(sweep_devices) > 1:
-            from adam_tpu.parallel.device_pool import SweepSchedule
+            # rows into the flat to_clean read index -> batch row, as i32
+            r_row32 = r_row.astype(np.int32)
+            # fan GEMM chunks across the pool/mesh devices (probe-paced
+            # weighted round-robin, ROADMAP "realign sweep scheduling"):
+            # until now every bucket dispatched to the default device while
+            # the rest of the pool idled through the 1.31 s sweep net
+            from adam_tpu.parallel.device_pool import putter as _putter
 
-            _sched = SweepSchedule(sweep_devices)
-        key = p_offb * 1024 + p_rt
-        border = np.argsort(key, kind="stable")
-        ukeys, ustarts = np.unique(key[border], return_index=True)
-        ustarts = np.append(ustarts, len(border))
-        for u in range(len(ukeys)):
-            seg = border[ustarts[u]:ustarts[u + 1]]
-            off = int(ukeys[u] // 1024)
-            rt = int(ukeys[u] % 1024)
-            P = _sweep_gemm_P(off, rt)
-            lc = off + lr
-            for s in range(0, len(seg), P):
-                part = seg[s:s + P]
-                # chunk-local read block [P*rt, lr]: row j*rt+k is read k
-                # of pair j — no device gather, and the compiled shape is
-                # independent of the dataset size
-                rc = np.full((P * rt, lr), schema.BASE_PAD, np.uint8)
-                rq = np.zeros((P * rt, lr), np.uint8)
-                rl = np.zeros(P * rt, np.int32)
-                pm = np.zeros(P * rt, bool)
-                ct = np.full((P, lc), schema.BASE_PAD, np.uint8)
-                cl = np.zeros(P, np.int32)
-                for j, pi in enumerate(part):
-                    nrt = int(p_n[pi])
-                    lo = int(p_lo[pi])
-                    rows_t = r_row32[lo:lo + nrt]
-                    rc[j * rt: j * rt + nrt, :cols] = bases_np[rows_t, :cols]
-                    rq[j * rt: j * rt + nrt, :cols] = quals_np[rows_t, :cols]
-                    rl[j * rt: j * rt + nrt] = lengths[rows_t]
-                    pm[j * rt: j * rt + nrt] = True
-                    cid = int(p_cid[pi])
-                    cc = min(int(cons_lens[cid]), lc)
-                    ct[j, :cc] = cons_mat[cid, :cc]
-                    cl[j] = cons_lens[cid]
-                dev = _sched.next_device() if _sched is not None else None
-                _put = _putter(dev)  # commit + h2d transfer accounting
-                pending.append((part, dev, sweep_gemm_kernel(
-                    _put(rc), _put(rq), _put(rl),
-                    _put(pm), _put(ct), _put(cl),
-                    off, rt, lr,
-                )))
+            _sched = None
+            if sweep_devices is not None and len(sweep_devices) > 1:
+                from adam_tpu.parallel.device_pool import SweepSchedule
 
-        _phase("Realign: sweep dispatch (host assembly)")
-        # host work hides under the device queue drain — genuinely
-        # overlapped only when sweeps are actually in flight
-        _overlap_once(in_dispatch=bool(pending))
-        _phase("Realign: overlapped host work")
+                _sched = SweepSchedule(sweep_devices)
+            key = p_offb * 1024 + p_rt
+            border = np.argsort(key, kind="stable")
+            ukeys, ustarts = np.unique(key[border], return_index=True)
+            ustarts = np.append(ustarts, len(border))
+            for u in range(len(ukeys)):
+                seg = border[ustarts[u]:ustarts[u + 1]]
+                off = int(ukeys[u] // 1024)
+                rt = int(ukeys[u] % 1024)
+                P = _sweep_gemm_P(off, rt)
+                lc = off + lr
+                for s in range(0, len(seg), P):
+                    part = seg[s:s + P]
+                    # chunk-local read block [P*rt, lr]: row j*rt+k is read k
+                    # of pair j — no device gather, and the compiled shape is
+                    # independent of the dataset size
+                    rc = np.full((P * rt, lr), schema.BASE_PAD, np.uint8)
+                    rq = np.zeros((P * rt, lr), np.uint8)
+                    rl = np.zeros(P * rt, np.int32)
+                    pm = np.zeros(P * rt, bool)
+                    ct = np.full((P, lc), schema.BASE_PAD, np.uint8)
+                    cl = np.zeros(P, np.int32)
+                    for j, pi in enumerate(part):
+                        nrt = int(p_n[pi])
+                        lo = int(p_lo[pi])
+                        rows_t = r_row32[lo:lo + nrt]
+                        blk = slice(j * rt, j * rt + nrt)
+                        rc[blk, :cols] = bases_np[rows_t, :cols]
+                        rq[blk, :cols] = quals_np[rows_t, :cols]
+                        rl[blk] = lengths[rows_t]
+                        pm[blk] = True
+                        cid = int(p_cid[pi])
+                        cc = min(int(cons_lens[cid]), lc)
+                        ct[j, :cc] = cons_mat[cid, :cc]
+                        cl[j] = cons_lens[cid]
+                    dev = _sched.next_device() if _sched is not None else None
+                    _put = _putter(dev)  # commit + h2d transfer accounting
+                    pending.append((part, dev, sweep_gemm_kernel(
+                        _put(rc), _put(rq), _put(rl),
+                        _put(pm), _put(ct), _put(cl),
+                        off, rt, lr,
+                    )))
+
+    # host work hides under the device queue drain — genuinely
+    # overlapped only when sweeps are actually in flight
+    _overlap_once(in_dispatch=bool(pending))
+    with _tr.span(_tele.SPAN_REALIGN_SWEEP_FETCH):
         if pending:
             # one fused fetch PER DEVICE: per-chunk fetches each pay a
             # device round trip, and chunks
@@ -1675,188 +1693,192 @@ def _realign_indels_native(
                     res_q[rb:rb + nrt] = q2[j, :nrt]
                     res_o[rb:rb + nrt] = o2[j, :nrt]
 
-    _phase("Realign: sweep fetch")
     _overlap_once()  # NT == 0: nothing was dispatched, run it here
-    # ---- scoring + rewrite decisions (numpy, one pass per group) -------
-    new_batch = jax.tree.map(np.array, b)
-    new_md: dict[int, Optional[str]] = {}
-    new_attrs: dict[int, str] = {}
-    cmax = new_batch.cmax
+    with _tr.span(_tele.SPAN_REALIGN_DECIDE):
+        # ---- scoring + rewrite decisions (numpy, one pass per group) -------
+        new_batch = jax.tree.map(np.array, b)
+        new_md: dict[int, Optional[str]] = {}
+        new_attrs: dict[int, str] = {}
+        cmax = new_batch.cmax
 
-    # realigned-read accumulators (one native MD-move call at the end)
-    ra_rows, ra_g, ra_off, ra_head, ra_midl, ra_mido, ra_end = (
-        [], [], [], [], [], [], [])
-    ra_start, ra_newend = [], []
-    realigned_mask = np.zeros(R, bool)
+        # realigned-read accumulators (one native MD-move call at the end)
+        ra_rows, ra_g, ra_off, ra_head, ra_midl, ra_mido, ra_end = (
+            [], [], [], [], [], [], [])
+        ra_start, ra_newend = [], []
+        realigned_mask = np.zeros(R, bool)
 
-    for g in range(G):
-        cons = grp_cons[g]
-        if not cons:
-            continue
-        nr = int(rg_off[g + 1] - rg_off[g])
-        nc = len(cons)
-        sl = slice(int(grp_task_base[g]), int(grp_task_base[g + 1]))
-        # ci-major flat -> [nr, nc]
-        q = res_q[sl].reshape(nc, nr).T
-        o = res_o[sl].reshape(nc, nr).T
-        orig = r_orig[rg_off[g]:rg_off[g + 1]].astype(np.int64)
-        pre_total = int(orig.sum())
-        use = q < orig[:, None]
-        qi = np.zeros_like(q, dtype=np.int64)
-        qi[use] = q[use].astype(np.int64)
-        contrib = np.where(use, qi, orig[:, None])
-        totals = contrib.sum(axis=0)
-        best_ci = int(nc - 1 - np.argmin(totals[::-1]))
-        best_total = int(totals[best_ci])
-        lod = (pre_total - best_total) / 10.0
-        ref_start = int(t_ref_start[g])
-        ref_len = int(t_ref_off[g + 1] - t_ref_off[g])
-        _log.debug(
-            "On target %d [%d, %d), before realignment, sum was %d; "
-            "best consensus %d has sum %d (LOD %.2f)",
-            int(gtid[g]), ref_start, ref_start + ref_len, pre_total,
-            best_ci, best_total, lod,
-        )
-        if lod <= lod_threshold:
-            continue
-        cons_str, cis, cie = cons[best_ci]
-        best_map = np.where(use[:, best_ci], o[:, best_ci], -1)
-        okm = best_map >= 0
-        if not okm.any():
-            continue
-        ridx = np.flatnonzero(okm) + int(rg_off[g])
-        om = best_map[okm].astype(np.int64)
-        rows_g = r_row[ridx]
-        Lr = lengths[rows_g]
-        new_start = ref_start + om
-        if cis == cie - 1:  # insertion
-            id_len = len(cons_str)
-            id_op = ord("I")
-            end_len = Lr - id_len - (cis - new_start)
-            end_pen = -id_len
-        else:  # deletion
-            id_len = cie - 1 - cis
-            id_op = ord("D")
-            end_len = Lr - (cis - new_start)
-            end_pen = len(cons_str)
-        head_len = cis - new_start
-        three = (head_len > 0) & (end_len > 0)
-        new_end = np.where(three, new_start + Lr + end_pen, new_start + Lr)
-        keep = om + (new_end - new_start) <= ref_len
-        if not keep.any():
-            continue
-        k = np.flatnonzero(keep)
-        realigned_mask[ridx[k]] = True
-        ra_rows.append(rows_g[k])
-        ra_g.append(np.full(len(k), g, np.int32))
-        ra_off.append(om[k])
-        ra_head.append(np.where(three[k], head_len[k], Lr[k]).astype(np.int32))
-        ra_midl.append(np.where(three[k], id_len, 0).astype(np.int32))
-        ra_mido.append(np.where(three[k], id_op, 0).astype(np.uint8))
-        ra_end.append(np.where(three[k], end_len[k], 0).astype(np.int32))
-        ra_start.append(new_start[k])
-        ra_newend.append(new_end[k])
-
-    # ---- write back: realigned rows ------------------------------------
-    if ra_rows:
-        rows_a = np.concatenate(ra_rows)
-        g_a = np.concatenate(ra_g)
-        off_a = np.concatenate(ra_off)
-        head_a = np.concatenate(ra_head)
-        midl_a = np.concatenate(ra_midl)
-        mido_a = np.concatenate(ra_mido)
-        end_a = np.concatenate(ra_end)
-        start_a = np.concatenate(ra_start)
-        newend_a = np.concatenate(ra_newend)
-        moved = native.md_move_batch(
-            b, rows_a, prep["t_ref_buf"], t_ref_off, g_a, off_a,
-            head_a, midl_a, mido_a, end_a, start_a,
-        )
-        if moved is None:
-            return None
-        mbuf, moff = moved
-        mstr = mbuf.tobytes().decode("ascii")
-
-        three_a = mido_a != 0
-        if three_a.any() and cmax < 3:
-            raise ValueError("realigned cigar exceeds batch cmax")
-        # OC/OP provenance from the pre-realignment columns
-        oc = native.cigar_strings(
-            np.asarray(b.cigar_ops)[rows_a],
-            np.asarray(b.cigar_lens)[rows_a],
-            np.asarray(b.cigar_n)[rows_a],
-        )
-        if oc is not None:
-            oc_buf, oc_off = oc
-            oc_all = oc_buf.tobytes().decode("ascii")
-            old_cigs = [
-                oc_all[oc_off[k]:oc_off[k + 1]] for k in range(len(rows_a))
-            ]
-        else:
-            old_cigs = [
-                schema.decode_cigar(
-                    np.asarray(b.cigar_ops)[r], np.asarray(b.cigar_lens)[r],
-                    int(np.asarray(b.cigar_n)[r]),
-                )
-                for r in rows_a
-            ]
-        attrs_col = StringColumn.of(side.attrs)
-        old_starts = np.asarray(b.start)[rows_a]
-        for k, row in enumerate(rows_a):
-            row = int(row)
-            tag = f"OC:Z:{old_cigs[k]}\tOP:i:{int(old_starts[k]) + 1}"
-            cur = attrs_col[row] or ""
-            new_attrs[row] = cur + "\t" + tag if cur else tag
-            new_md[row] = mstr[moff[k]:moff[k + 1]]
-        ops_new = np.zeros((len(rows_a), cmax), np.uint8)
-        ops_new[:] = schema.CIGAR_PAD
-        lens_new = np.zeros((len(rows_a), cmax), np.int32)
-        ncig_new = np.where(three_a, 3, 1).astype(np.int32)
-        ops_new[:, 0] = schema.CIGAR_M
-        lens_new[:, 0] = head_a
-        if three_a.any() and cmax >= 3:
-            ops_new[three_a, 1] = np.where(
-                mido_a[three_a] == ord("I"), schema.CIGAR_I, schema.CIGAR_D
+        for g in range(G):
+            cons = grp_cons[g]
+            if not cons:
+                continue
+            nr = int(rg_off[g + 1] - rg_off[g])
+            nc = len(cons)
+            sl = slice(int(grp_task_base[g]), int(grp_task_base[g + 1]))
+            # ci-major flat -> [nr, nc]
+            q = res_q[sl].reshape(nc, nr).T
+            o = res_o[sl].reshape(nc, nr).T
+            orig = r_orig[rg_off[g]:rg_off[g + 1]].astype(np.int64)
+            pre_total = int(orig.sum())
+            use = q < orig[:, None]
+            qi = np.zeros_like(q, dtype=np.int64)
+            qi[use] = q[use].astype(np.int64)
+            contrib = np.where(use, qi, orig[:, None])
+            totals = contrib.sum(axis=0)
+            best_ci = int(nc - 1 - np.argmin(totals[::-1]))
+            best_total = int(totals[best_ci])
+            lod = (pre_total - best_total) / 10.0
+            ref_start = int(t_ref_start[g])
+            ref_len = int(t_ref_off[g + 1] - t_ref_off[g])
+            _log.debug(
+                "On target %d [%d, %d), before realignment, sum was %d; "
+                "best consensus %d has sum %d (LOD %.2f)",
+                int(gtid[g]), ref_start, ref_start + ref_len, pre_total,
+                best_ci, best_total, lod,
             )
-            lens_new[three_a, 1] = midl_a[three_a]
-            ops_new[three_a, 2] = schema.CIGAR_M
-            lens_new[three_a, 2] = end_a[three_a]
-        new_batch.cigar_ops[rows_a] = ops_new
-        new_batch.cigar_lens[rows_a] = lens_new
-        new_batch.cigar_n[rows_a] = ncig_new
-        new_batch.start[rows_a] = start_a
-        new_batch.end[rows_a] = newend_a
-        new_batch.mapq[rows_a] = np.asarray(b.mapq)[rows_a] + 10
+            if lod <= lod_threshold:
+                continue
+            cons_str, cis, cie = cons[best_ci]
+            best_map = np.where(use[:, best_ci], o[:, best_ci], -1)
+            okm = best_map >= 0
+            if not okm.any():
+                continue
+            ridx = np.flatnonzero(okm) + int(rg_off[g])
+            om = best_map[okm].astype(np.int64)
+            rows_g = r_row[ridx]
+            Lr = lengths[rows_g]
+            new_start = ref_start + om
+            if cis == cie - 1:  # insertion
+                id_len = len(cons_str)
+                id_op = ord("I")
+                end_len = Lr - id_len - (cis - new_start)
+                end_pen = -id_len
+            else:  # deletion
+                id_len = cie - 1 - cis
+                id_op = ord("D")
+                end_len = Lr - (cis - new_start)
+                end_pen = len(cons_str)
+            head_len = cis - new_start
+            three = (head_len > 0) & (end_len > 0)
+            new_end = np.where(three, new_start + Lr + end_pen, new_start + Lr)
+            keep = om + (new_end - new_start) <= ref_len
+            if not keep.any():
+                continue
+            k = np.flatnonzero(keep)
+            realigned_mask[ridx[k]] = True
+            ra_rows.append(rows_g[k])
+            ra_g.append(np.full(len(k), g, np.int32))
+            ra_off.append(om[k])
+            ra_head.append(
+                np.where(three[k], head_len[k], Lr[k]).astype(np.int32)
+            )
+            ra_midl.append(np.where(three[k], id_len, 0).astype(np.int32))
+            ra_mido.append(np.where(three[k], id_op, 0).astype(np.uint8))
+            ra_end.append(np.where(three[k], end_len[k], 0).astype(np.int32))
+            ra_start.append(new_start[k])
+            ra_newend.append(new_end[k])
 
-    # ---- write back: dirty (left-normalized) non-realigned rows --------
-    dirty_idx = np.flatnonzero(r_dirty & ~realigned_mask)
-    if len(dirty_idx):
-        cig_off = prep["r_cigar_off"]
-        cig_all = prep["r_cigar_buf"].tobytes().decode("ascii")
-        md_off2 = prep["r_md_off"]
-        md_all = prep["r_md_buf"].tobytes().decode("ascii")
-        for i in dirty_idx:
-            row = int(r_row[i])
-            cig = cig_all[cig_off[i]:cig_off[i + 1]]
-            elems = parse_cigar(cig)
-            ops, lens_, ncig = schema.encode_cigar(cig, max(cmax, len(elems)))
-            if ncig > cmax:
+        # ---- write back: realigned rows ------------------------------------
+        if ra_rows:
+            rows_a = np.concatenate(ra_rows)
+            g_a = np.concatenate(ra_g)
+            off_a = np.concatenate(ra_off)
+            head_a = np.concatenate(ra_head)
+            midl_a = np.concatenate(ra_midl)
+            mido_a = np.concatenate(ra_mido)
+            end_a = np.concatenate(ra_end)
+            start_a = np.concatenate(ra_start)
+            newend_a = np.concatenate(ra_newend)
+            moved = native.md_move_batch(
+                b, rows_a, prep["t_ref_buf"], t_ref_off, g_a, off_a,
+                head_a, midl_a, mido_a, end_a, start_a,
+            )
+            if moved is None:
+                return None
+            mbuf, moff = moved
+            mstr = mbuf.tobytes().decode("ascii")
+
+            three_a = mido_a != 0
+            if three_a.any() and cmax < 3:
                 raise ValueError("realigned cigar exceeds batch cmax")
-            new_batch.cigar_ops[row] = ops[:cmax]
-            new_batch.cigar_lens[row] = lens_[:cmax]
-            new_batch.cigar_n[row] = ncig
-            new_batch.end[row] = int(new_batch.start[row]) + cigar_ref_len(
-                elems
+            # OC/OP provenance from the pre-realignment columns
+            oc = native.cigar_strings(
+                np.asarray(b.cigar_ops)[rows_a],
+                np.asarray(b.cigar_lens)[rows_a],
+                np.asarray(b.cigar_n)[rows_a],
             )
-            if r_md_set[i]:
-                new_md[row] = md_all[md_off2[i]:md_off2[i + 1]]
+            if oc is not None:
+                oc_buf, oc_off = oc
+                oc_all = oc_buf.tobytes().decode("ascii")
+                old_cigs = [
+                    oc_all[oc_off[k]:oc_off[k + 1]] for k in range(len(rows_a))
+                ]
+            else:
+                old_cigs = [
+                    schema.decode_cigar(
+                        np.asarray(b.cigar_ops)[r],
+                        np.asarray(b.cigar_lens)[r],
+                        int(np.asarray(b.cigar_n)[r]),
+                    )
+                    for r in rows_a
+                ]
+            attrs_col = StringColumn.of(side.attrs)
+            old_starts = np.asarray(b.start)[rows_a]
+            for k, row in enumerate(rows_a):
+                row = int(row)
+                tag = f"OC:Z:{old_cigs[k]}\tOP:i:{int(old_starts[k]) + 1}"
+                cur = attrs_col[row] or ""
+                new_attrs[row] = cur + "\t" + tag if cur else tag
+                new_md[row] = mstr[moff[k]:moff[k + 1]]
+            ops_new = np.zeros((len(rows_a), cmax), np.uint8)
+            ops_new[:] = schema.CIGAR_PAD
+            lens_new = np.zeros((len(rows_a), cmax), np.int32)
+            ncig_new = np.where(three_a, 3, 1).astype(np.int32)
+            ops_new[:, 0] = schema.CIGAR_M
+            lens_new[:, 0] = head_a
+            if three_a.any() and cmax >= 3:
+                ops_new[three_a, 1] = np.where(
+                    mido_a[three_a] == ord("I"), schema.CIGAR_I, schema.CIGAR_D
+                )
+                lens_new[three_a, 1] = midl_a[three_a]
+                ops_new[three_a, 2] = schema.CIGAR_M
+                lens_new[three_a, 2] = end_a[three_a]
+            new_batch.cigar_ops[rows_a] = ops_new
+            new_batch.cigar_lens[rows_a] = lens_new
+            new_batch.cigar_n[rows_a] = ncig_new
+            new_batch.start[rows_a] = start_a
+            new_batch.end[rows_a] = newend_a
+            new_batch.mapq[rows_a] = np.asarray(b.mapq)[rows_a] + 10
 
-    new_side = dc_replace(
-        side,
-        md=with_overrides(StringColumn.of(side.md), new_md),
-        attrs=with_overrides(StringColumn.of(side.attrs), new_attrs),
-    )
-    _phase("Realign: decisions + rewrite")
+        # ---- write back: dirty (left-normalized) non-realigned rows --------
+        dirty_idx = np.flatnonzero(r_dirty & ~realigned_mask)
+        if len(dirty_idx):
+            cig_off = prep["r_cigar_off"]
+            cig_all = prep["r_cigar_buf"].tobytes().decode("ascii")
+            md_off2 = prep["r_md_off"]
+            md_all = prep["r_md_buf"].tobytes().decode("ascii")
+            for i in dirty_idx:
+                row = int(r_row[i])
+                cig = cig_all[cig_off[i]:cig_off[i + 1]]
+                elems = parse_cigar(cig)
+                ops, lens_, ncig = schema.encode_cigar(
+                    cig, max(cmax, len(elems))
+                )
+                if ncig > cmax:
+                    raise ValueError("realigned cigar exceeds batch cmax")
+                new_batch.cigar_ops[row] = ops[:cmax]
+                new_batch.cigar_lens[row] = lens_[:cmax]
+                new_batch.cigar_n[row] = ncig
+                new_batch.end[row] = int(new_batch.start[row]) + cigar_ref_len(
+                    elems
+                )
+                if r_md_set[i]:
+                    new_md[row] = md_all[md_off2[i]:md_off2[i + 1]]
+
+        new_side = dc_replace(
+            side,
+            md=with_overrides(StringColumn.of(side.md), new_md),
+            attrs=with_overrides(StringColumn.of(side.attrs), new_attrs),
+        )
     return ds.with_batch(new_batch, new_side)
 
 

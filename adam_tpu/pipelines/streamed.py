@@ -1007,8 +1007,9 @@ def _transform_streamed_impl(
             return
         seen_grid_shapes.add(key)
         n_rg = len(ds.read_groups) + 1
-        t_pw = time.monotonic_ns()
-        try:
+        # the umbrella records the WALL (the stats view subtracts
+        # it back out of the enclosing pass's row)
+        with tr.span(tele.SPAN_POOL_PREWARM):
             if mp is not None:
                 entries = []
                 if mark_duplicates:
@@ -1052,13 +1053,6 @@ def _transform_streamed_impl(
                     ),
                     tracer=tr,
                 )
-        finally:
-            # the umbrella records the WALL (the stats view subtracts
-            # it back out of the enclosing pass's row)
-            tr.add_span(
-                tele.SPAN_POOL_PREWARM, t_pw,
-                time.monotonic_ns() - t_pw,
-            )
 
     def _prewarm_observe_shape(ds):
         """Tail hook: warm the observe kernel at the REALIGNED part's
@@ -1072,8 +1066,7 @@ def _transform_streamed_impl(
             return
         b = ds.batch.to_numpy()
         n_rg = len(ds.read_groups) + 1
-        t_pw = time.monotonic_ns()
-        try:
+        with tr.span(tele.SPAN_POOL_PREWARM):
             if mp is not None:
                 entries = [part_mod.mesh_observe_prewarm_entry(b, n_rg, mp)]
                 if use_resident:
@@ -1102,11 +1095,6 @@ def _transform_streamed_impl(
                         )
                     )
                 dpool.prewarm(entries, tracer=tr)
-        finally:
-            tr.add_span(
-                tele.SPAN_POOL_PREWARM, t_pw,
-                time.monotonic_ns() - t_pw,
-            )
 
     # transfer-ledger pass attribution: every h2d put / d2h fetch on
     # this thread inside the scope lands under the pass's bucket in the
@@ -1544,95 +1532,100 @@ def _transform_streamed_impl(
     # under the device wait), then observe the realigned part with its
     # post-realignment alignments (markdup -> realign -> BQSR, the
     # reference's Transform composition) ---------------------------------
-    t_tail_ns = time.monotonic_ns()
-    realigned: Optional[AlignmentDataset] = None
-    # resume fast path for the realign tail: when the realigned part
-    # (index n_windows) is already durably published AND its
-    # contribution to the recalibration table is recoverable (the
-    # solved table itself, or its persisted observe histogram), the
-    # whole candidate realign — the GEMM sweeps — is skippable.  The
-    # sidecar is LOADED here, not just probed: an unreadable sidecar
-    # must force the re-realign, or the merged table would silently
-    # miss the realigned part's observations.
-    skip_realign = False
-    r_obs = None
-    if (
-        candidates and journal is not None and journal.resumed
-        and len(windows) in done_parts
-    ):
-        if not recalibrate or resume_table is not None:
-            skip_realign = True
-        else:
-            r_obs = journal.load_observation(len(windows))
-            skip_realign = r_obs is not None
-    if candidates and not skip_realign:
-        cand = AlignmentDataset.concat(candidates)
-        tr.count(tele.C_CANDIDATE_ROWS, int(cand.batch.n_rows))
-        # fan the sweep GEMM buckets across the run's device set
-        # (probe-paced weighted round-robin) instead of queueing them
-        # all on the default device while the rest of the pool idles
-        sweep_devs = None
-        if use_device and not res["device_lost"]:
-            if exec_state["mesh"] is not None:
-                sweep_devs = exec_state["mesh"].devices
-            elif dpool is not None:
-                alive = dpool.alive_devices()
-                sweep_devs = alive if len(alive) > 1 else None
-        with tele.pass_scope("sweep"):
-            # the sweep scope covers the realign GEMM dispatch+drain;
-            # the overlapped observe pass shadows it with its own scope
-            realigned = realign_mod.realign_indels(
-                cand,
-                consensus_model=consensus_model,
-                known_indels=known_indels,
-                max_indel_size=mis,
-                max_consensus_number=mcn,
-                lod_threshold=lod,
-                max_target_size=mts,
-                overlap_work=_observe_remainders,
-                sweep_devices=sweep_devs,
+    with tr.span(tele.SPAN_TAIL):
+        realigned: Optional[AlignmentDataset] = None
+        # resume fast path for the realign tail: when the realigned part
+        # (index n_windows) is already durably published AND its
+        # contribution to the recalibration table is recoverable (the
+        # solved table itself, or its persisted observe histogram), the
+        # whole candidate realign — the GEMM sweeps — is skippable.  The
+        # sidecar is LOADED here, not just probed: an unreadable sidecar
+        # must force the re-realign, or the merged table would silently
+        # miss the realigned part's observations.
+        skip_realign = False
+        r_obs = None
+        if (
+            candidates and journal is not None and journal.resumed
+            and len(windows) in done_parts
+        ):
+            if not recalibrate or resume_table is not None:
+                skip_realign = True
+            else:
+                r_obs = journal.load_observation(len(windows))
+                skip_realign = r_obs is not None
+        if candidates and not skip_realign:
+            cand = AlignmentDataset.concat(candidates)
+            tr.count(tele.C_CANDIDATE_ROWS, int(cand.batch.n_rows))
+            # fan the sweep GEMM buckets across the run's device set
+            # (probe-paced weighted round-robin) instead of queueing them
+            # all on the default device while the rest of the pool idles
+            sweep_devs = None
+            if use_device and not res["device_lost"]:
+                if exec_state["mesh"] is not None:
+                    sweep_devs = exec_state["mesh"].devices
+                elif dpool is not None:
+                    alive = dpool.alive_devices()
+                    sweep_devs = alive if len(alive) > 1 else None
+            with tele.pass_scope("sweep"):
+                # the sweep scope covers the realign GEMM dispatch+drain;
+                # the overlapped observe pass shadows it with its own scope
+                realigned = realign_mod.realign_indels(
+                    cand,
+                    consensus_model=consensus_model,
+                    known_indels=known_indels,
+                    max_indel_size=mis,
+                    max_consensus_number=mcn,
+                    lod_threshold=lod,
+                    max_target_size=mts,
+                    overlap_work=_observe_remainders,
+                    sweep_devices=sweep_devs,
+                )
+            if (
+                recalibrate and realigned.batch.n_rows
+                and resume_table is None
+            ):
+                with tr.span(tele.SPAN_OBSERVE_REALIGNED):
+                    # the realigned part's grid shape rarely matches any
+                    # ingest window's: warm its observe kernel before
+                    # the dispatch
+                    _prewarm_observe_shape(realigned)
+                    # the realigned part is a window too: place it
+                    # resident so its observe AND its pass-C apply
+                    # dispatch off one ingest placement, like every
+                    # streamed window
+                    _make_resident(len(windows), realigned)
+                    got = _observe_window(len(windows), realigned)
+                    if got is not None:
+                        obs_parts.append(got[0])
+                        obs_replays.append(got[1])
+                        obs_windows.append(len(windows))
+            # subtract the observe wall from the tail ONLY when realign
+            # reports it genuinely ran under the sweeps' device drain — on
+            # the serial paths (Python fallback, no dispatched sweeps) the
+            # old unconditional subtraction understated realign's serial
+            # wall and inflated the derived cfg4 bench line
+            hidden = bool(
+                getattr(_observe_remainders, "overlap_ran_in_dispatch", False)
             )
-        if recalibrate and realigned.batch.n_rows and resume_table is None:
-            # the realigned part's grid shape rarely matches any ingest
-            # window's: warm its observe kernel before the dispatch
-            _prewarm_observe_shape(realigned)
-            # the realigned part is a window too: place it resident so
-            # its observe AND its pass-C apply dispatch off one ingest
-            # placement, like every streamed window
-            _make_resident(len(windows), realigned)
-            got = _observe_window(len(windows), realigned)
-            if got is not None:
-                obs_parts.append(got[0])
-                obs_replays.append(got[1])
+        elif skip_realign:
+            # journaled realigned part + recoverable table contribution:
+            # observe the remaining windows (persisted histograms load, the
+            # rest recompute) and splice the realigned part's persisted
+            # histogram in at its window-plan position — the same part
+            # order the uninterrupted run merges
+            _observe_remainders()
+            if r_obs is not None:
+                tr.count(tele.C_RESUME_HISTOGRAMS_LOADED)
+                obs_parts.append(
+                    (np.asarray(r_obs[0]), np.asarray(r_obs[1]), r_obs[2])
+                )
+                obs_replays.append(None)
                 obs_windows.append(len(windows))
-        # subtract the observe wall from the tail ONLY when realign
-        # reports it genuinely ran under the sweeps' device drain — on
-        # the serial paths (Python fallback, no dispatched sweeps) the
-        # old unconditional subtraction understated realign's serial
-        # wall and inflated the derived cfg4 bench line
-        hidden = bool(
-            getattr(_observe_remainders, "overlap_ran_in_dispatch", False)
-        )
-    elif skip_realign:
-        # journaled realigned part + recoverable table contribution:
-        # observe the remaining windows (persisted histograms load, the
-        # rest recompute) and splice the realigned part's persisted
-        # histogram in at its window-plan position — the same part
-        # order the uninterrupted run merges
-        _observe_remainders()
-        if r_obs is not None:
-            tr.count(tele.C_RESUME_HISTOGRAMS_LOADED)
-            obs_parts.append(
-                (np.asarray(r_obs[0]), np.asarray(r_obs[1]), r_obs[2])
-            )
-            obs_replays.append(None)
-            obs_windows.append(len(windows))
-        hidden = False
-    else:
-        _observe_remainders()
-        # no realignment ran: the tail wall IS the observe pass
-        hidden = False
-    tr.add_span(tele.SPAN_TAIL, t_tail_ns, time.monotonic_ns() - t_tail_ns)
+            hidden = False
+        else:
+            _observe_remainders()
+            # no realignment ran: the tail wall IS the observe pass
+            hidden = False
     tr.gauge(tele.G_OBSERVE_HIDDEN, 1 if hidden else 0)
     stats["observe_overlap_hidden"] = hidden
 
@@ -1937,31 +1930,28 @@ def _transform_streamed_impl(
             for item in plist:
                 bw = item[1].batch
                 seen_dims.setdefault((bw.n_rows, bw.lmax), item[1])
-            t_pwc = time.monotonic_ns()
-            pw_entries = []
-            for w in seen_dims.values():
-                bw = w.batch.to_numpy()
-                if use_packed and use_resident:
-                    # the resident bases+quals pack2, plus the
-                    # quals-only pack a dead handle falls back to
-                    pw_entries.append(part_mod.mesh_apply_prewarm_entry(
-                        bw, table.shape[0], table.shape[2], mp,
-                        pack2=True,
-                    ))
-                if use_packed:
-                    pw_entries.append(part_mod.mesh_apply_prewarm_entry(
-                        bw, table.shape[0], table.shape[2], mp,
-                        pack=True,
-                    ))
-                else:
-                    pw_entries.append(part_mod.mesh_apply_prewarm_entry(
-                        bw, table.shape[0], table.shape[2], mp,
-                    ))
-            mp.prewarm(pw_entries, tracer=tr)
-            tr.add_span(
-                tele.SPAN_POOL_PREWARM_C, t_pwc,
-                time.monotonic_ns() - t_pwc,
-            )
+            mesh_apply_entry = part_mod.mesh_apply_prewarm_entry
+            with tr.span(tele.SPAN_POOL_PREWARM_C):
+                pw_entries = []
+                for w in seen_dims.values():
+                    bw = w.batch.to_numpy()
+                    if use_packed and use_resident:
+                        # the resident bases+quals pack2, plus the
+                        # quals-only pack a dead handle falls back to
+                        pw_entries.append(mesh_apply_entry(
+                            bw, table.shape[0], table.shape[2], mp,
+                            pack2=True,
+                        ))
+                    if use_packed:
+                        pw_entries.append(mesh_apply_entry(
+                            bw, table.shape[0], table.shape[2], mp,
+                            pack=True,
+                        ))
+                    else:
+                        pw_entries.append(mesh_apply_entry(
+                            bw, table.shape[0], table.shape[2], mp,
+                        ))
+                mp.prewarm(pw_entries, tracer=tr)
         except Exception as e:
             _mesh_degrade(e, "pass-C table placement")
             rem = list(plist)
@@ -2099,39 +2089,35 @@ def _transform_streamed_impl(
                 seen_dims.setdefault(
                     (bw.n_rows, bw.lmax), item[1]
                 )
-            t_pwc = time.monotonic_ns()
-            pw_entries = []
-            for w in seen_dims.values():
-                bw = w.batch.to_numpy()
-                if use_packed and use_resident:
-                    # the resident bases+quals pack2 (what pass C will
-                    # actually dispatch), beside the quals-only pack a
-                    # dead handle falls back to
-                    pw_entries.append(apply_prewarm_entry(
-                        bw, table.shape[0], table.shape[2],
-                        pack=True, resident=True,
-                    ))
-                if use_packed:
-                    pw_entries.append(apply_prewarm_entry(
-                        bw, table.shape[0], table.shape[2], pack=True,
-                    ))
-                # the plain gather stays warm on every leg: eviction
-                # replays re-apply with pack=False on a survivor, and
-                # one entry covers both twins (resident warms the
-                # donating variant alongside the plain one)
-                pw_entries.append(apply_prewarm_entry(
-                    bw, table.shape[0], table.shape[2],
-                    resident=use_resident,
-                ))
-            dpool.prewarm(pw_entries, tracer=tr)
             # umbrella wall for the re-warm: the stats view
             # folds it into prewarm_s and subtracts it from
             # apply_split_s, so compile time never shows up as
             # host encode/submit time
-            tr.add_span(
-                tele.SPAN_POOL_PREWARM_C, t_pwc,
-                time.monotonic_ns() - t_pwc,
-            )
+            with tr.span(tele.SPAN_POOL_PREWARM_C):
+                pw_entries = []
+                for w in seen_dims.values():
+                    bw = w.batch.to_numpy()
+                    if use_packed and use_resident:
+                        # the resident bases+quals pack2 (what pass C will
+                        # actually dispatch), beside the quals-only pack a
+                        # dead handle falls back to
+                        pw_entries.append(apply_prewarm_entry(
+                            bw, table.shape[0], table.shape[2],
+                            pack=True, resident=True,
+                        ))
+                    if use_packed:
+                        pw_entries.append(apply_prewarm_entry(
+                            bw, table.shape[0], table.shape[2], pack=True,
+                        ))
+                    # the plain gather stays warm on every leg: eviction
+                    # replays re-apply with pack=False on a survivor, and
+                    # one entry covers both twins (resident warms the
+                    # donating variant alongside the plain one)
+                    pw_entries.append(apply_prewarm_entry(
+                        bw, table.shape[0], table.shape[2],
+                        resident=use_resident,
+                    ))
+                dpool.prewarm(pw_entries, tracer=tr)
         # in-flight queue of (part idx, device, handle): depth
         # 2 single-device (the classic double buffer); with a
         # pool a double buffer per device — window j+1's gather
@@ -2538,7 +2524,7 @@ def _finish_trace(tr: tele.Tracer, stats: dict, hb=None) -> None:
         ("md_cols_fetch_s", "Streamed MarkDup Columns (device fetch)"),
         ("resolve_s", "Streamed Barrier (dup resolve + targets)"),
         ("split_s", "Streamed Pass B (candidate split)"),
-        ("observe_s", "Streamed BQSR Observe (hidden under sweeps)"),
+        ("observe_s", "Streamed BQSR Observe (inside the realign tail)"),
         ("realign_s", "Streamed Tail (realign net of overlap)"),
         ("obs_merge_fetch_s", "Streamed Observe Merge (device fetch)"),
         ("solve_s", "Streamed Barrier (solve recalibration)"),

@@ -436,21 +436,27 @@ class WindowCoalescer:
 
         # the host-side mask prep runs on the JOB's thread (parallel
         # across jobs); the dispatcher thread only fuses and dispatches
-        b, read_ok, residue_ok, is_mm, n_rg = bqsr_mod.observe_inputs(
-            ds, known_snps
-        )
-        g = grid_rows(b.n_rows)
-        gl = grid_cols(b.lmax)
-        payload = {
-            "b": b,
-            "resident": resident,
-            "n_rg": n_rg,
-            "masks": (
-                pack_mask_bits(pad_rows_np(residue_ok, g, False, cols=gl)),
-                pack_mask_bits(pad_rows_np(is_mm, g, False, cols=gl)),
-                pad_rows_np(read_ok, g, False),
-            ),
-        }
+        with tele.TRACE.span(
+            tele.SPAN_BQSR_OBSERVE_PREP, reads=int(ds.batch.n_rows)
+        ):
+            b, read_ok, residue_ok, is_mm, n_rg = bqsr_mod.observe_inputs(
+                ds, known_snps
+            )
+            bqsr_mod.count_observe_residues(b, read_ok)
+            g = grid_rows(b.n_rows)
+            gl = grid_cols(b.lmax)
+            payload = {
+                "b": b,
+                "resident": resident,
+                "n_rg": n_rg,
+                "masks": (
+                    pack_mask_bits(
+                        pad_rows_np(residue_ok, g, False, cols=gl)
+                    ),
+                    pack_mask_bits(pad_rows_np(is_mm, g, False, cols=gl)),
+                    pad_rows_np(read_ok, g, False),
+                ),
+            }
         return self._submit(
             "observe", ("observe", gl), job, tenant, window,
             b.n_rows, g, gl, payload,
@@ -463,8 +469,10 @@ class WindowCoalescer:
         # fused table's own shape, exactly like apply_table_body
         from adam_tpu.formats.batch import grid_cols, grid_rows, pad_rows_np
         from adam_tpu.ops.colpack import pack_lengths
+        from adam_tpu.pipelines import bqsr as bqsr_mod
 
         b = ds.batch.to_numpy()
+        bqsr_mod.count_apply_residues(b)
         g = grid_rows(b.n_rows)
         gl = grid_cols(b.lmax)
         payload = {

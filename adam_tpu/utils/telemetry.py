@@ -95,6 +95,10 @@ SPAN_RESOLVE = _span("streamed.barrier.resolve")
 SPAN_SPLIT = _span("streamed.pass_b.split")
 SPAN_OBSERVE = _span("streamed.observe")
 SPAN_TAIL = _span("streamed.tail")
+# the realigned part's observe inside the tail: its prewarm, resident
+# placement and observe dispatch (the remainders' observe, overlapped
+# with the realigner, is streamed.observe)
+SPAN_OBSERVE_REALIGNED = _span("streamed.observe.realigned")
 SPAN_OBS_MERGE = _span("streamed.observe.merge_fetch")
 SPAN_SOLVE = _span("streamed.barrier.solve")
 SPAN_PASS_C = _span("streamed.pass_c")
@@ -106,10 +110,29 @@ SPAN_TOTAL = _span("streamed.total")
 # ---- per-call spans with backend attribution (pipelines/bqsr.py,
 # pipelines/markdup.py) ----
 SPAN_BQSR_OBSERVE = _span("bqsr.observe.window")
+# the host half of one observe call: MD mismatch mask, read filter,
+# reference positions + known-SNP mask, padding and bit-packing — from
+# the start of input construction to just before the dispatch
+SPAN_BQSR_OBSERVE_PREP = _span("bqsr.observe.prep")
 SPAN_BQSR_APPLY_DISPATCH = _span("bqsr.apply.dispatch")
 SPAN_BQSR_APPLY_FETCH = _span("bqsr.apply.fetch")
 SPAN_BQSR_APPLY_HOST = _span("bqsr.apply.host")
 SPAN_MD_COLUMNS = _span("markdup.columns.dispatch")
+
+# ---- realigner phases (pipelines/realign.py), one span each per call;
+# the work overlapped between dispatch and fetch is the caller's
+# (streamed.observe), so the six sum to the realigner's own host wall ----
+SPAN_REALIGN_TARGETS = _span("realign.targets")
+SPAN_REALIGN_NATIVE_PREP = _span("realign.native_prep")
+SPAN_REALIGN_CONSENSUS = _span("realign.consensus")
+SPAN_REALIGN_SWEEP_DISPATCH = _span("realign.sweep_dispatch")
+SPAN_REALIGN_SWEEP_FETCH = _span("realign.sweep_fetch")
+SPAN_REALIGN_DECIDE = _span("realign.decide")
+REALIGN_PHASE_SPANS = (
+    SPAN_REALIGN_TARGETS, SPAN_REALIGN_NATIVE_PREP, SPAN_REALIGN_CONSENSUS,
+    SPAN_REALIGN_SWEEP_DISPATCH, SPAN_REALIGN_SWEEP_FETCH,
+    SPAN_REALIGN_DECIDE,
+)
 # the megakernel tier (PR 18): one fused B→C dispatch per window when
 # the recalibration table is known up front; the gauge records the
 # tier decision (streamed.fused_bc 1/0) once per run
@@ -194,6 +217,13 @@ C_PARTS_WRITTEN = _metric("parquet.parts.written")
 C_ENCODE_BYTES_IN = _metric("parquet.encode.bytes_in")
 C_ENCODE_BYTES_OUT = _metric("parquet.encode.bytes_out")
 C_CANDIDATE_ROWS = _metric("realign.candidate_rows")
+# BQSR work in the algorithm's units, whatever grid implements it: the
+# summed read length of the reads the observe filter admits (per observe
+# call) and of the reads whose qualities pass C rewrites (valid, with
+# qualities; per apply or fused dispatch) — a replayed window counts
+# again, since its work is done again
+C_OBSERVE_RESIDUES = _metric("bqsr.observe.residues")
+C_APPLY_RESIDUES = _metric("bqsr.apply.residues")
 C_POOL_PREWARM_COMPILES = _metric("device.pool.prewarm.compiles")
 # resilience counters: injected faults (utils/faults.point), retry
 # attempts actually taken (utils/retry.retry_call — 0 on a clean run),
@@ -738,9 +768,35 @@ class _NullSpan:
 
 _NULL_SPAN = _NullSpan()
 
+#: ``jax.profiler.TraceAnnotation``, cached once JAX is loaded.
+_ANNOTATION = None
+_ANNOTATION_LOCK = threading.Lock()
+
+
+def _annotation_class():
+    """``jax.profiler.TraceAnnotation`` once JAX is imported, else None:
+    no profiler session can collect before JAX loads, and this module
+    imports and records without JAX."""
+    global _ANNOTATION
+    if "jax" not in sys.modules:
+        return None
+    with _ANNOTATION_LOCK:
+        if _ANNOTATION is None:
+            try:
+                from jax.profiler import TraceAnnotation
+            except ImportError:
+                return None
+            _ANNOTATION = TraceAnnotation
+        return _ANNOTATION
+
 
 class _Span:
-    __slots__ = ("_tr", "name", "attrs", "_t0", "_parent")
+    """A recorded span.  While a ``jax.profiler`` session collects, it
+    also opens a ``TraceAnnotation`` of the same name, so the span lies
+    on the profiler's host plane, on the device trace's clock; with no
+    session the cost is one ``is_enabled()`` check."""
+
+    __slots__ = ("_tr", "name", "attrs", "_t0", "_parent", "_annot")
 
     def __init__(self, tr: "Tracer", name: str, attrs: dict):
         self._tr = tr
@@ -751,11 +807,19 @@ class _Span:
         tls = self._tr._tls
         self._parent = getattr(tls, "span", None)
         tls.span = self
+        ann = _ANNOTATION or _annotation_class()
+        if ann is not None and ann.is_enabled():
+            self._annot = ann(self.name)
+            self._annot.__enter__()
+        else:
+            self._annot = None
         self._t0 = time.monotonic_ns()
         return self
 
     def __exit__(self, *exc):
         dur = time.monotonic_ns() - self._t0
+        if self._annot is not None:
+            self._annot.__exit__(*exc)
         self._tr._tls.span = self._parent
         self._tr._record(
             self.name, self._t0, dur, self.attrs,

@@ -1109,3 +1109,126 @@ def test_merge_snapshots_quota_sums_spend_keeps_budgets():
     assert got["t1"]["budget_bytes"] == 1 << 20
     assert got["t1"]["budget_compute_s"] == 60.0
     assert got["t2"]["charges"] == 9
+
+
+# --------------------------------------------------------------------------
+# Profiler annotations: recorded spans on the profiler's host plane
+# --------------------------------------------------------------------------
+class _FakeAnnotation:
+    """Stands in for ``jax.profiler.TraceAnnotation``; logs enter/exit."""
+
+    enabled = True
+    log: list = []
+
+    def __init__(self, name):
+        self.name = name
+
+    @classmethod
+    def is_enabled(cls):
+        return cls.enabled
+
+    def __enter__(self):
+        self.log.append(("enter", self.name))
+
+    def __exit__(self, *exc):
+        self.log.append(("exit", self.name))
+
+
+@pytest.fixture
+def fake_annotation(monkeypatch):
+    _FakeAnnotation.log = []
+    monkeypatch.setattr(tele, "_ANNOTATION", _FakeAnnotation)
+    return _FakeAnnotation
+
+
+@pytest.mark.parametrize("recording,enabled,expect", [
+    (True, True, True),
+    (True, False, False),
+    (False, True, False),
+    (False, False, False),
+])
+def test_span_opens_annotation_only_when_recording_and_collecting(
+    fake_annotation, monkeypatch, recording, enabled, expect
+):
+    monkeypatch.setattr(fake_annotation, "enabled", enabled)
+    tr = tele.Tracer(recording=recording)
+    with tr.span(tele.SPAN_BQSR_OBSERVE_PREP, reads=3):
+        pass
+    want = [("enter", "bqsr.observe.prep"), ("exit", "bqsr.observe.prep")]
+    assert fake_annotation.log == (want if expect else [])
+    # the recorded span is the same either way
+    assert tr.snapshot()["spans"].get("bqsr.observe.prep", {}).get(
+        "count", 0) == (1 if recording else 0)
+
+
+def test_annotations_nest_like_the_spans(fake_annotation):
+    tele.TRACE.recording = False
+    tr = tele.Tracer(recording=True)
+    with tr.span(tele.SPAN_TAIL):
+        with tr.span(tele.SPAN_REALIGN_TARGETS):
+            pass
+        with tele.TRACE.span(tele.SPAN_OBSERVE):  # off: no annotation
+            pass
+        with tr.span(tele.SPAN_OBSERVE):
+            with tr.span(tele.SPAN_BQSR_OBSERVE_PREP):
+                pass
+    assert fake_annotation.log == [
+        ("enter", "streamed.tail"),
+        ("enter", "realign.targets"), ("exit", "realign.targets"),
+        ("enter", "streamed.observe"),
+        ("enter", "bqsr.observe.prep"), ("exit", "bqsr.observe.prep"),
+        ("exit", "streamed.observe"),
+        ("exit", "streamed.tail"),
+    ]
+    ev = {e["name"]: e for e in tr.events()}
+    assert ev["bqsr.observe.prep"]["parent"] == "streamed.observe"
+
+
+def test_annotation_closes_when_the_span_raises(fake_annotation):
+    tr = tele.Tracer(recording=True)
+    with pytest.raises(KeyError):
+        with tr.span(tele.SPAN_REALIGN_DECIDE):
+            raise KeyError("x")
+    assert fake_annotation.log[-1] == ("exit", "realign.decide")
+    assert tr.snapshot()["spans"]["realign.decide"]["count"] == 1
+
+
+def test_annotation_class_is_looked_up_only_once_jax_is_loaded(monkeypatch):
+    monkeypatch.setattr(tele, "_ANNOTATION", None)
+    monkeypatch.delitem(sys.modules, "jax")
+    assert tele._annotation_class() is None  # nothing imported
+    assert "jax" not in sys.modules
+    monkeypatch.undo()
+    monkeypatch.setattr(tele, "_ANNOTATION", None)
+    import jax.profiler
+
+    assert tele._annotation_class() is jax.profiler.TraceAnnotation
+
+
+def test_span_lands_on_the_profiler_host_plane(tmp_path):
+    """With a real profiler session, a recorded span is an event of the
+    same name on the host plane, inside the enclosing annotation."""
+    import jax.profiler as jp
+    from jax.profiler import ProfileData
+
+    tr = tele.Tracer(recording=True)
+    jp.start_trace(str(tmp_path))
+    with jp.TraceAnnotation("outer"):
+        with tr.span(tele.SPAN_REALIGN_CONSENSUS):
+            pass
+    jp.stop_trace()
+    with tr.span(tele.SPAN_REALIGN_CONSENSUS):  # no session: no event
+        pass
+    path = sorted(tmp_path.rglob("*.xplane.pb"))[-1]
+    evs = {}
+    for plane in ProfileData.from_file(str(path)).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    evs.setdefault(e.name, []).append(
+                        (e.start_ns, e.start_ns + e.duration_ns))
+    assert len(evs["realign.consensus"]) == 1
+    (s, e), = evs["realign.consensus"]
+    (os_, oe), = evs["outer"]
+    assert os_ <= s <= e <= oe
+    assert tr.snapshot()["spans"]["realign.consensus"]["count"] == 2
