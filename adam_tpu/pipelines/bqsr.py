@@ -446,10 +446,28 @@ def observe_residue_mask(
         & (np.asarray(b.bases) < 4) & (ref_pos >= 0)
     )
     if known_snps is not None and len(known_snps):
-        rok &= ~known_snps.mask_positions(
-            ds.seq_dict.names, np.asarray(b.contig_idx), ref_pos
+        rok &= ~known_snp_mask(
+            known_snps, ds.seq_dict.names, b.contig_idx, ref_pos, b.start
         )
     return rok
+
+
+def known_snp_mask(
+    known_snps: SnpTable, contig_names, contig_idx, ref_pos, start
+) -> np.ndarray:
+    """One observe call's known-SNP mask -> bool[N, L], timed by the span
+    ``bqsr.observe.snp_mask`` and counted by ``bqsr.snp_mask.rows``,
+    ``.hit_rows`` (rows whose reference span holds a site) and
+    ``.fallback_rows`` (rows masked by the ``searchsorted`` route)."""
+    with _tele.TRACE.span(_tele.SPAN_BQSR_SNP_MASK, reads=len(ref_pos)):
+        got = known_snps.mask_routed(
+            contig_names, np.asarray(contig_idx), ref_pos, start
+        )
+    if _tele.TRACE.recording:
+        _tele.TRACE.count(_tele.C_SNP_MASK_ROWS, len(ref_pos))
+        _tele.TRACE.count(_tele.C_SNP_MASK_HIT_ROWS, got.hit_rows)
+        _tele.TRACE.count(_tele.C_SNP_MASK_FALLBACK_ROWS, got.fallback_rows)
+    return got.mask
 
 
 def observe_inputs(ds: AlignmentDataset, known_snps=None) -> tuple:

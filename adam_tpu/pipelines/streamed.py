@@ -2125,6 +2125,13 @@ def _transform_streamed_impl(
         apply_depth = 2 if dpool is None else 2 * dpool.n
         pend_q: deque = deque()
         hb_queues.append((pend_q, 1))  # items: (idx, dev, handle)
+        # window -> monotonic time of its pass-C dispatch.  The health
+        # board reads each window's dispatch+fetch wall: a pool pass
+        # dispatches up to two windows per device before its first
+        # fetch, so that fetch waits out its whole kernel while the
+        # peers' fetches find theirs done, and a fetch-only wall would
+        # read the chip of the first-fetched window as a straggler
+        sent_at: dict = {}
 
         def _host_apply(w):
             return bqsr_mod.apply_recalibration(
@@ -2272,6 +2279,8 @@ def _transform_streamed_impl(
                 thr = health_board.hedge_threshold("bqsr.apply")
             try:
                 t_fetch = time.monotonic()
+                # a fetch-only window (the fused tier's) counts from here
+                t_sent = sent_at.pop(p_idx, t_fetch)
                 hedged = False
                 with tr.span(
                     tele.SPAN_APPLY_FETCH, window=p_idx, **attrs
@@ -2327,7 +2336,7 @@ def _transform_streamed_impl(
                     # pooled run on this process-wide board
                     health_board.observe_latency(
                         "bqsr.apply", p_dev,
-                        time.monotonic() - t_fetch, tracer=tr,
+                        time.monotonic() - t_sent, tracer=tr,
                     )
             except Exception as e:
                 # the replay re-applies synchronously (survivor chip or
@@ -2405,6 +2414,7 @@ def _transform_streamed_impl(
                 continue
 
             def _dispatch_one(dev, idx=idx, w=w):
+                sent_at[idx] = time.monotonic()
                 with tr.span(
                     tele.SPAN_APPLY_DISPATCH, window=idx,
                     **dp_mod.span_attrs(dev),

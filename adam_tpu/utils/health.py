@@ -648,6 +648,13 @@ _PROBE_JIT = None
 _PROBE_ARGS = None
 
 
+def probe_product(x, y):
+    """The probe's device program: an int32 matmul.  Its operands stay
+    below 127 over 64 terms, so int32 holds the exact product; a 64-bit
+    dot does not compile for a TPU."""
+    return x @ y
+
+
 def probe_known_answer(device) -> bool:
     """The re-admission probe: one small **integer** matmul dispatched
     on ``device`` whose result must come back bit-exact against the
@@ -674,9 +681,7 @@ def probe_known_answer(device) -> bool:
         a, b = _PROBE_ARGS
         expect = a.astype(np.int64) @ b.astype(np.int64)
         if _PROBE_JIT is None:
-            _PROBE_JIT = jax.jit(
-                lambda x, y: x.astype("int64") @ y.astype("int64")
-            )
+            _PROBE_JIT = jax.jit(probe_product)
         da = jax.device_put(a, device)
         db = jax.device_put(b, device)
         got = device_fetch(_PROBE_JIT(da, db))

@@ -114,6 +114,8 @@ SPAN_BQSR_OBSERVE = _span("bqsr.observe.window")
 # reference positions + known-SNP mask, padding and bit-packing — from
 # the start of input construction to just before the dispatch
 SPAN_BQSR_OBSERVE_PREP = _span("bqsr.observe.prep")
+# the known-SNP mask inside it (pipelines/bqsr.known_snp_mask)
+SPAN_BQSR_SNP_MASK = _span("bqsr.observe.snp_mask")
 SPAN_BQSR_APPLY_DISPATCH = _span("bqsr.apply.dispatch")
 SPAN_BQSR_APPLY_FETCH = _span("bqsr.apply.fetch")
 SPAN_BQSR_APPLY_HOST = _span("bqsr.apply.host")
@@ -224,6 +226,13 @@ C_CANDIDATE_ROWS = _metric("realign.candidate_rows")
 # again, since its work is done again
 C_OBSERVE_RESIDUES = _metric("bqsr.observe.residues")
 C_APPLY_RESIDUES = _metric("bqsr.apply.residues")
+# the known-SNP mask's route (models/snp_table.SnpTable.mask_routed), per
+# masked call: its rows, the rows whose reference span holds a known
+# site (the only rows the searchsorted fallback searches), and the rows
+# masked by that fallback (a span too wide for the map)
+C_SNP_MASK_ROWS = _metric("bqsr.snp_mask.rows")
+C_SNP_MASK_HIT_ROWS = _metric("bqsr.snp_mask.hit_rows")
+C_SNP_MASK_FALLBACK_ROWS = _metric("bqsr.snp_mask.fallback_rows")
 C_POOL_PREWARM_COMPILES = _metric("device.pool.prewarm.compiles")
 # resilience counters: injected faults (utils/faults.point), retry
 # attempts actually taken (utils/retry.retry_call — 0 on a clean run),

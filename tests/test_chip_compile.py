@@ -158,6 +158,17 @@ def test_sw_fill_pallas(one_chip):
     assert "tpu_custom_call" in c.as_text()
 
 
+def test_health_probe(one_chip):
+    """The re-admission probe's int32 matmul (a 64-bit dot does not
+    compile for a TPU, and a probe that cannot run evicts its chip)."""
+    import jax
+
+    from adam_tpu.utils.health import probe_product
+
+    x = np.zeros((64, 64), np.int32)
+    _compile(jax.jit(probe_product), *_shapes((x, x), one_chip))
+
+
 @pytest.mark.parametrize("kind", ["markdup", "fused_bc"])
 def test_mesh_kernel_four_chips(mesh4, kind):
     """The ``--partitioner mesh`` programs over a 2x2 host's four chips:

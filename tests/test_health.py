@@ -725,6 +725,43 @@ def test_streamed_hedge_rescues_straggler_byte_identically(
     assert _parts_hash(out) == clean_baseline
 
 
+def test_streamed_latency_is_the_dispatch_to_fetch_wall(
+    wgs_input, clean_baseline, monkeypatch
+):
+    """The board reads each pass-C window's wall from its dispatch to
+    the end of its fetch.  A pool pass dispatches up to two windows per
+    device before its first fetch; a wall that started at the fetch
+    would charge the first-fetched window's whole kernel to its chip
+    alone, and read the peers, whose kernels ran meanwhile, as ~0."""
+    from adam_tpu.pipelines import bqsr as bqsr_mod
+
+    pause = 0.05
+    walls = []
+    real_observe = health_mod.BOARD.observe_latency
+    real_dispatch = bqsr_mod.apply_recalibration_dispatch
+
+    def observe(kernel, device, seconds, tracer=None):
+        walls.append(seconds)
+        return real_observe(kernel, device, seconds, tracer=tracer)
+
+    def dispatch(*a, **k):
+        handle = real_dispatch(*a, **k)
+        time.sleep(pause)  # host work between this dispatch and the next
+        return handle
+
+    monkeypatch.setattr(health_mod.BOARD, "observe_latency", observe)
+    monkeypatch.setattr(bqsr_mod, "apply_recalibration_dispatch", dispatch)
+    d, path = wgs_input
+    out = str(d / "walls.adam")
+    _run(path, out, None, devices=2)
+    assert len(walls) >= 4
+    # every wall holds its own window's dispatch; the first fetched
+    # holds the dispatches of the three windows queued behind it too
+    assert min(walls) >= pause
+    assert walls[0] >= 4 * pause
+    assert _parts_hash(out) == clean_baseline
+
+
 def test_heartbeat_carries_device_health_field():
     tr = tele.Tracer(recording=True)
     hb = tele.Heartbeat([tr], sink="stderr", interval_s=60.0)

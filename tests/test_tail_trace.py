@@ -104,6 +104,26 @@ def test_program_spans_on_the_profiler_host_plane(runs):
 
 
 @pytest.mark.parametrize("run", sorted(RUNS))
+def test_snp_mask_inside_every_prep(runs, run):
+    """Every observe call masks its known SNPs once, every row of it —
+    the windows' and the realigned part's.  The windows of the sorted
+    input take the window-local site map; the realigned part's few rows
+    span both contigs, more bases than their N x L positions hold, so
+    they take the searchsorted route."""
+    snap = runs[run]["snap"]
+    spans, c = snap["spans"], snap["counters"]
+    assert spans[tele.SPAN_BQSR_SNP_MASK]["count"] == (
+        spans[tele.SPAN_BQSR_OBSERVE_PREP]["count"])
+    assert c["bqsr.snp_mask.rows"] == N_READS + c["realign.candidate_rows"]
+    assert 0 < c["bqsr.snp_mask.hit_rows"] < N_READS
+    assert c["bqsr.snp_mask.fallback_rows"] == c["realign.candidate_rows"]
+    if runs[run]["xprof"]:
+        got = _host_events(runs[run]["xprof"])
+        assert got[tele.SPAN_BQSR_SNP_MASK] == got[
+            tele.SPAN_BQSR_OBSERVE_PREP]
+
+
+@pytest.mark.parametrize("run", sorted(RUNS))
 def test_tail_is_accounted_for_by_its_parts(runs, run):
     spans = runs[run]["snap"]["spans"]
     tail = spans[tele.SPAN_TAIL]["total_s"]
