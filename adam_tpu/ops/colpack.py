@@ -4,17 +4,25 @@ The streamed pipeline's pass C used to fetch each window's recalibrated
 quals as a dense ``u8[N, L]`` matrix and re-walk it on the host into the
 Arrow string layout (one flat byte buffer + offsets).  The device
 already knows every row's true length, so the kernel here does the
-compaction *before* the bytes cross the link: scatter each row's
-in-read prefix at its exclusive-cumsum offset, ship ``packed[:total]``
-— the exact column payload, padding lanes never cross d2h — and hand
-the host a buffer that IS the Arrow data buffer (io/arrow_pack.py wraps
-it zero-copy).  Offsets never cross at all: the host holds the same
-lengths and rebuilds them with one cumsum.
+compaction *before* the bytes cross the link: each row's in-read prefix
+moves to its exclusive-cumsum offset, the window ships
+``packed[:total]`` — the exact column payload, padding lanes never
+cross d2h — and the host gets a buffer that IS the Arrow data buffer
+(io/arrow_pack.py wraps it zero-copy).  Offsets never cross at all: the
+host holds the same lengths and rebuilds them with one cumsum.
 
-The same shrink-the-d2h move as PR 8's barrier-2 mesh psum, applied to
-the pass-C apply fetch (the ROADMAP "kill the apply/encode/write tail"
-item): on trimmed/short-read libraries — adapter-trimmed short-insert
-runs, small-RNA reads at a fraction of the instrument read length —
+The compaction is a log-step shift network, not a scatter.  Every byte
+of row ``r`` moves left by the row's deficit ``r * L - offset[r]``;
+stage ``k`` moves the bytes whose deficit has bit ``k`` set by
+``2**k``, so a window of ``N * L`` lanes packs in
+``bit_length((N - 1) * L)`` stages, one loop of a shifted slice and two
+selects each.  No sort, scatter or gather is left in the program (a u8
+scatter makes the TPU compiler sort its whole index array first), and
+the cost depends on the static grid alone, not on the length mix.
+Base codes decode to ASCII with five selects for the same reason.
+
+On trimmed/short-read libraries — adapter-trimmed short-insert runs,
+small-RNA reads at a fraction of the instrument read length —
 ``sum(lengths)`` is several times smaller than ``N*L``, and the ledger's
 pass-C ``device.d2h.bytes`` entry shrinks by the same factor.
 
@@ -32,6 +40,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from adam_tpu.formats import schema
 
@@ -48,26 +57,55 @@ def pack_lengths(lengths, valid, has_qual=None) -> np.ndarray:
 
 
 def pack_rows_body(mat, lens, size: int):
-    """Traceable pack: scatter row prefixes ``mat[i, :lens[i]]`` at
-    exclusive-cumsum offsets into a flat ``[size]`` buffer.
+    """Traceable pack: row prefixes ``mat[i, :lens[i]]`` concatenated in
+    row order into a flat ``[size]`` buffer, zero after ``sum(lens)``.
+
+    A monotone compaction by a log-step shift network: every lane of
+    row ``r`` moves left by the row's deficit ``r * w - offset[r]``,
+    which never decreases along the flat order.  Stage ``k`` moves each
+    kept lane whose deficit has bit ``k`` set left by ``2**k``; taken in
+    increasing ``k``, no two kept lanes ever meet and their order holds.
+    The stages are one loop over the flat lanes, each a shifted slice
+    and two selects: no sort, scatter or gather, and a cost set by the
+    static grid alone, not by the length mix.
 
     ``size`` must be static and >= ``sum(lens)``; callers use the
     window's dense grid area (``g * gl``) so the jit cache sees no new
     shapes — the *fetch* is what shrinks (``packed[:total]``), not the
     device allocation, which aliases the matrix footprint it replaces.
-    Padding positions scatter to index ``size`` and drop.
     """
     n, w = mat.shape
-    lens = lens.astype(jnp.int64)
-    offsets = jnp.cumsum(lens) - lens  # exclusive row starts
-    col = jnp.arange(w, dtype=jnp.int64)[None, :]
-    in_row = col < lens[:, None]
-    idx = jnp.where(in_row, offsets[:, None] + col, size)
-    return (
-        jnp.zeros(size, mat.dtype)
-        .at[idx.ravel()]
-        .set(mat.ravel(), mode="drop")
-    )
+    flat = n * w
+    total = max(flat, size)
+    bits = max((n - 1) * w, 0).bit_length()  # every deficit < 2**bits
+    reach = (1 << bits) >> 1  # the longest shift
+    assert total + reach < 2**31, "pack offsets are i32"
+    lens = jnp.clip(lens.astype(jnp.int32), 0, w)
+    deficit = jnp.arange(n, dtype=jnp.int32) * w - (jnp.cumsum(lens) - lens)
+    in_row = jnp.arange(w, dtype=jnp.int32)[None, :] < lens[:, None]
+    # key: the lane's deficit, -1 where no byte is kept; lanes past the
+    # grid hold none, so a shift past the end reads empty lanes
+    tail = total - flat + reach
+    key = jnp.pad(jnp.where(in_row, deficit[:, None], -1).ravel(),
+                  (0, tail), constant_values=-1)
+    val = jnp.pad(jnp.where(in_row, mat, 0).astype(jnp.int32).ravel(),
+                  (0, tail))
+
+    def stage(k, kv):
+        key, val = kv
+        s = jnp.left_shift(1, k)
+        key_in = lax.dynamic_slice(key, (s,), (total,))
+        arrive = (key_in >= 0) & (key_in & s != 0)
+        stay = (key[:total] >= 0) & (key[:total] & s == 0)
+        return [
+            lax.dynamic_update_slice(x, jnp.where(
+                arrive, lax.dynamic_slice(x, (s,), (total,)),
+                jnp.where(stay, x[:total], fill)), (0,))
+            for x, fill in ((key, -1), (val, 0))
+        ]
+
+    _, val = lax.fori_loop(0, bits, stage, [key, val])
+    return val[:size].astype(mat.dtype)
 
 
 @partial(jax.jit, static_argnames=("size",))
@@ -81,7 +119,7 @@ def pack_rows_kernel(mat, lens, size: int):
 def pack_rows_np(mat: np.ndarray, lens: np.ndarray) -> np.ndarray:
     """Host twin of :func:`pack_rows_body` (exact total, no padding):
     one boolean mask-select in row-major order — concatenated row
-    prefixes, bitwise the device scatter's first ``sum(lens)`` bytes."""
+    prefixes, bitwise the device pack's first ``sum(lens)`` bytes."""
     mat = np.ascontiguousarray(mat)
     lens = np.asarray(lens, np.int64)
     n, w = mat.shape if mat.ndim == 2 else (len(lens), 0)
@@ -106,9 +144,13 @@ def base_decode_body(bases):
     ``schema.BASE_DECODE_LUT256`` (code -> ACGTN. ASCII), so packed
     base buffers come home ready to BE the Arrow ``sequence`` column
     data (the bases half of the packed tail: with the window resident
-    on device, decoding there costs one tiny gather instead of a host
-    LUT walk per part)."""
-    return jnp.asarray(schema.BASE_DECODE_LUT256)[bases.astype(jnp.uint8)]
+    on device, decoding there costs five elementwise selects instead of
+    a host LUT walk per part; codes past ``BASE_N`` decode as ``.``)."""
+    lut = schema.BASE_DECODE_LUT
+    out = jnp.full(bases.shape, lut[schema.BASE_PAD], jnp.uint8)
+    for code in range(schema.BASE_PAD):
+        out = jnp.where(bases == code, lut[code], out)
+    return out
 
 
 def pack_mask_bits(mask: np.ndarray) -> np.ndarray:

@@ -1009,9 +1009,7 @@ def apply_pack_body(
         bases, quals, lengths, flags, read_group_idx, has_qual, valid,
         phred_table, lmax,
     )
-    pack_lens = jnp.where(
-        valid & has_qual, lengths.astype(jnp.int64), 0
-    )
+    pack_lens = jnp.where(valid & has_qual, lengths, 0)
     return pack_rows_body(sanger_body(new_q), pack_lens, size)
 
 
@@ -1050,10 +1048,8 @@ def apply_pack2_body(
         bases, quals, lengths, flags, read_group_idx, has_qual, valid,
         phred_table, lmax,
     )
-    qual_lens = jnp.where(
-        valid & has_qual, lengths.astype(jnp.int64), 0
-    )
-    base_lens = jnp.where(valid, lengths.astype(jnp.int64), 0)
+    qual_lens = jnp.where(valid & has_qual, lengths, 0)
+    base_lens = jnp.where(valid, lengths, 0)
     return (
         pack_rows_body(sanger_body(new_q), qual_lens, size),
         pack_rows_body(base_decode_body(bases), base_lens, size),

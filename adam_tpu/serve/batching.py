@@ -233,9 +233,7 @@ def _fused_apply_body(cols, extras, table, segs, lmax: int,
     if not pack_size:
         return new_q
     lengths_cat, hq_cat, vd_cat = cat[2], cat[5], cat[6]
-    pack_lens = jnp.where(
-        vd_cat & hq_cat, lengths_cat.astype(jnp.int64), 0
-    )
+    pack_lens = jnp.where(vd_cat & hq_cat, lengths_cat, 0)
     return pack_rows_body(sanger_body(new_q), pack_lens, pack_size)
 
 

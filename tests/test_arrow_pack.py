@@ -77,6 +77,62 @@ def test_pack_rows_empty():
     assert dev.shape == (4,) and not dev.any()
 
 
+def _mix_lengths(case, rng, n):
+    """-> (lengths, valid, has_qual) of ``n`` rows for one length mix."""
+    valid = np.ones(n, bool)
+    has_qual = np.ones(n, bool)
+    if case == "full100":
+        lengths = np.full(n, 100)
+    elif case == "cell_mix":  # the WGS cell: 12% of rows 60-99 nt
+        lengths = np.where(rng.random(n) < 0.12, rng.integers(60, 100, n), 100)
+    elif case == "trimmed":
+        lengths = rng.integers(22, 33, n)
+    elif case == "sparse":  # mostly empty rows: long shifts
+        lengths = np.where(rng.random(n) < 0.02, rng.integers(1, 129, n), 0)
+    elif case == "no_qual":
+        lengths = rng.integers(60, 129, n)
+        has_qual = rng.random(n) < 0.7
+    else:  # random, with empty and invalid rows
+        lengths = rng.integers(0, 129, n)
+        lengths[::7] = 0
+        valid = rng.random(n) < 0.9
+    return lengths, valid, has_qual
+
+
+@pytest.mark.parametrize("case,n,extra", [
+    ("full100", 96, 0),
+    ("cell_mix", 257, 0),
+    ("trimmed", 200, 0),
+    ("random", 300, 0),
+    ("no_qual", 120, 0),
+    ("random", 1, 0),
+    ("trimmed", 40, 1000),
+    ("sparse", 3000, 0),
+])
+def test_pack_rows_body_matches_np_whole_buffer(case, n, extra):
+    """The shift network against its host twin over the whole
+    ``u8[size]`` buffer: the packed prefix, then zeros to ``size``."""
+    rng = np.random.default_rng(n)
+    lengths, valid, has_qual = _mix_lengths(case, rng, n)
+    mat = rng.integers(0, 256, (n, 128)).astype(np.uint8)
+    for lens in (colpack.pack_lengths(lengths, valid, has_qual),
+                 colpack.pack_lengths(lengths, valid)):
+        size = n * 128 + extra
+        dev = np.asarray(colpack.pack_rows_kernel(mat, lens, size))
+        host = colpack.pack_rows_np(mat, lens)
+        want = np.zeros(size, np.uint8)
+        want[: host.size] = host
+        assert dev.dtype == np.uint8
+        np.testing.assert_array_equal(dev, want)
+
+
+def test_base_decode_body_matches_lut():
+    codes = np.arange(256, dtype=np.uint8).reshape(16, 16)
+    dev = np.asarray(colpack.base_decode_body(codes))
+    assert dev.dtype == np.uint8
+    np.testing.assert_array_equal(dev, schema.BASE_DECODE_LUT256[codes])
+
+
 def test_sanger_body_matches_lut():
     q = np.arange(256, dtype=np.uint8).reshape(16, 16)
     dev = np.asarray(colpack.sanger_body(q))

@@ -13,6 +13,7 @@ the worker that runs this file loads the TPU library.
 """
 
 import os
+import re
 
 import numpy as np
 import pytest
@@ -67,6 +68,10 @@ def _shapes(arrays, sharding):
     )
 
 
+#: An HLO instruction that sorts, scatters or gathers.
+_INDEX_OP = re.compile(r"\s(sort|scatter|gather)\(")
+
+
 def _compile(fn, *args, **kw):
     compiled = fn.lower(*args, **kw).compile()
     assert compiled.as_text()
@@ -76,12 +81,14 @@ def _compile(fn, *args, **kw):
 _ROW5 = ("bases", "quals", "lengths", "flags", "read_group_idx")
 _BQSR_ARGS = {
     "observe_packed": _ROW5 + ("res_bits", "mm_bits", "read_ok"),
+    "apply_pack": _ROW5 + ("has_qual", "valid", "table"),
     "apply_pack2": _ROW5 + ("has_qual", "valid", "table"),
     "fused_bc": _ROW5 + ("res_bits", "mm_bits", "read_ok", "has_qual",
                          "valid", "table"),
 }
 _BQSR_STATICS = {
     "observe_packed": (N_RG, GL),
+    "apply_pack": (GL, G * GL),
     "apply_pack2": (GL, G * GL),
     "fused_bc": (N_RG, GL, G * GL),
 }
@@ -111,7 +118,24 @@ def test_bqsr_kernel(one_chip, kind, donate):
 
     w = _window()
     args = _shapes([w[k] for k in _BQSR_ARGS[kind]], one_chip)
-    _compile(jit_variant(kind, donate), *args, *_BQSR_STATICS[kind])
+    c = _compile(jit_variant(kind, donate), *args, *_BQSR_STATICS[kind])
+    ops = _INDEX_OP.findall(c.as_text())
+    if kind != "observe_packed":
+        # the column packs are shift networks: a u8 scatter would bring
+        # back the whole-window sort the compiler puts ahead of it
+        assert "sort" not in ops
+    if kind.startswith("apply"):
+        assert ops == ["gather"]  # the table lookup, and nothing else
+
+
+def test_pack_rows_kernel(one_chip):
+    """The standalone column pack holds no index op at all."""
+    from adam_tpu.ops.colpack import pack_rows_kernel
+
+    w = _window()
+    args = _shapes((w["quals"], w["lengths"]), one_chip)
+    assert not _INDEX_OP.search(
+        _compile(pack_rows_kernel, *args, size=G * GL).as_text())
 
 
 @pytest.mark.parametrize("off,rt", [(384, 16), (512, 128)])
@@ -191,3 +215,4 @@ def test_mesh_kernel_four_chips(mesh4, kind):
     c = _compile(_mesh_jit(kind), *args, mesh=mesh4, **statics)
     if kind == "fused_bc":
         assert "all-reduce" in c.as_text()
+        assert "sort" not in _INDEX_OP.findall(c.as_text())
