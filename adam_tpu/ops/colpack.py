@@ -189,13 +189,3 @@ def fetch_grid(nbytes: int, floor: int = 4096) -> int:
     q = max(floor, 1 << max(0, n.bit_length() - 4))
     return -(-n // q) * q
 
-
-def packed_columns_enabled(default: bool = True) -> bool:
-    """Resolve the ``ADAM_TPU_PACKED_COLS`` toggle for the pass-C
-    packed-column fetch: ``auto``/unset -> ``default`` (on wherever the
-    device apply runs), ``1/on/true`` and ``0/off/false`` force; a typo
-    warns and keeps the default (``utils/retry.env_toggle``, the shared
-    tuning-var contract)."""
-    from adam_tpu.utils.retry import env_toggle
-
-    return env_toggle("ADAM_TPU_PACKED_COLS", default)

@@ -229,29 +229,6 @@ def donation_ok(device=None) -> bool:
     return on_tpu(device)
 
 
-def resident_windows_enabled(default: bool = True) -> bool:
-    """Resolve the ``ADAM_TPU_RESIDENT`` toggle for device-resident
-    windows: ``auto``/unset -> ``default`` (on wherever the device
-    backend runs), ``1/on/true`` and ``0/off/false`` force; a typo
-    warns and keeps the default (``utils/retry.env_toggle``, the shared
-    tuning-var contract — same parser as ``ADAM_TPU_PACKED_COLS``).
-
-    Precedence (documented in docs/PERF.md "Device-resident windows"):
-    the backend decides first (``ADAM_TPU_BQSR_BACKEND`` — residency
-    exists only under ``device``; host backends have no device to be
-    resident on), then ``--partitioner``/``ADAM_TPU_PARTITIONER``
-    decides the placement SHAPE (pool: per-device pinned; mesh: one
-    batch-sharded placement), and this toggle last decides whether
-    windows stay resident at all — off forces the legacy
-    re-ship-per-pass path on either partitioner.  ``ADAM_TPU_PACKED_COLS``
-    composes the same way: it gates what pass C *fetches* (packed
-    columns vs the [N, L] matrix), residency gates what pass A/B/C
-    *ship*, and the bases half of the packed tail needs both on."""
-    from adam_tpu.utils.retry import env_toggle
-
-    return env_toggle("ADAM_TPU_RESIDENT", default)
-
-
 class ResidentWindow:
     """One window's ingest-resident device payload: the five arrays
     every per-residue pass reads (``bases``/``quals`` [g, gl] and
@@ -273,19 +250,23 @@ class ResidentWindow:
     MASKS rows (geometry preserved), and the updated ``valid``/
     ``has_qual`` bools ship per pass.
 
-    **Refcounted release**: the streamed pipeline holds ONE base
-    reference per handle and releases it after the window's pass-C
-    fetch, so HBM frees window by window instead of at run end — all
-    passes run on the single driver thread and jax pins the buffers of
-    in-flight executions internally, so no current consumer needs more
-    than that one reference.  :meth:`retain` exists for a consumer
-    that must pin the handle across a genuinely concurrent boundary
-    (none does today — wire it before adding one).  :meth:`drop` is the
-    fault path (device evicted, mesh degraded): the handle dies, every
-    later dispatch falls back to re-shipping from the host-retained
-    ingest copy (``pipelines/streamed.py`` keeps each window's decoded
-    batch until its part publishes — the replay source of truth,
-    docs/ROBUSTNESS.md)."""
+    **Pinned or transient** — how long a handle lives is the only
+    policy.  A handle pinned at ingest (best-effort) lives until its
+    part submits in pass C: the streamed pipeline holds ONE base
+    reference and releases it then, so HBM frees window by window.  A
+    pass whose target holds no live handle (the pin failed, the device
+    was evicted, a donating attempt consumed the handle, the mesh
+    degraded, or a hedge twin runs on another chip) places a transient
+    one from the host-retained ingest copy (``pipelines/streamed.py``
+    keeps each window's decoded batch until its part publishes — the
+    replay source of truth, docs/ROBUSTNESS.md) and releases it as soon
+    as the dispatch that needed it returns; the runtime keeps the
+    buffers of the queued computation alive until it completes.
+    :meth:`retain` exists for a consumer that must pin the handle
+    across a genuinely concurrent boundary (none does today — wire it
+    before adding one).  :meth:`drop` is the fault path (device
+    evicted, mesh degraded): the handle dies and the next pass places
+    a transient one on a survivor."""
 
     FIELDS = ("bases", "quals", "lengths", "flags", "read_group_idx")
 
@@ -323,8 +304,8 @@ class ResidentWindow:
     def mark_consumed(self) -> None:
         """A donating dispatch consumed the bases/quals buffers: the
         handle stops offering them (a retry after a partial donating
-        execution must re-ship from the host copy, never re-pass a
-        deleted buffer)."""
+        execution places a transient handle from the host copy, never
+        re-passes a deleted buffer)."""
         with self._lock:
             self._consumed = True
 
@@ -354,30 +335,45 @@ class ResidentWindow:
             return held
 
 
-def make_resident_window(b, window: int, device=None) -> ResidentWindow:
-    """Place one window's resident payload on ``device`` (the pool
-    path's pinned placement; ``None`` = the single-chip default
-    device).  ``b`` is the window batch's numpy view; arrays pad to the
-    (pow2-rows, lane-aligned) grid — exactly the pads the markdup/
-    observe/apply dispatches would have shipped per pass.  Callers wrap
-    this in ``telemetry.pass_scope("ingest")`` so the h2d ledger books
-    the one placement under the ingest bucket."""
+def resident_arrays(b, g: int, gl: int, put) -> tuple:
+    """The five :attr:`ResidentWindow.FIELDS` arrays of the numpy batch
+    view ``b``, padded to the (``g`` rows, ``gl`` lanes) grid and placed
+    with ``put`` -> ``(arrays in FIELDS order, host bytes)``.  The one
+    place a window's bases/quals cross host->device: the pinned and
+    transient handles below and in ``partitioner.mesh_resident_window``,
+    and the in-memory API's single-device legs, all place through it."""
     from adam_tpu.formats import schema
-    from adam_tpu.formats.batch import grid_cols, grid_rows, pad_rows_np
+    from adam_tpu.formats.batch import pad_rows_np
+
+    host = (
+        pad_rows_np(b.bases, g, schema.BASE_PAD, cols=gl),
+        pad_rows_np(b.quals, g, schema.QUAL_PAD, cols=gl),
+        pad_rows_np(b.lengths, g, 0),
+        pad_rows_np(b.flags, g, schema.FLAG_UNMAPPED),
+        pad_rows_np(b.read_group_idx, g, -1),
+    )
+    return (
+        tuple(put(a) for a in host), sum(int(a.nbytes) for a in host)
+    )
+
+
+def make_resident_window(b, window: int, device=None) -> ResidentWindow:
+    """Place one window's resident payload on ``device`` (a pool chip;
+    ``None`` = the single-chip default device).  ``b`` is the window
+    batch's numpy view; arrays pad to the (pow2-rows, lane-aligned)
+    grid the markdup/observe/apply kernels take.  The streamed pipeline
+    calls this for the pin at ingest (under ``telemetry.pass_scope(
+    "ingest")``, so the h2d ledger books it there) and for a pass's
+    transient handle (booked under that pass)."""
+    from adam_tpu.formats.batch import grid_cols, grid_rows
 
     g = grid_rows(b.n_rows)
     gl = grid_cols(b.lmax)
-    _put = putter(device)
-    host = {
-        "bases": pad_rows_np(b.bases, g, schema.BASE_PAD, cols=gl),
-        "quals": pad_rows_np(b.quals, g, schema.QUAL_PAD, cols=gl),
-        "lengths": pad_rows_np(b.lengths, g, 0),
-        "flags": pad_rows_np(b.flags, g, schema.FLAG_UNMAPPED),
-        "read_group_idx": pad_rows_np(b.read_group_idx, g, -1),
-    }
-    nbytes = sum(int(a.nbytes) for a in host.values())
-    arrays = {k: _put(a) for k, a in host.items()}
-    return ResidentWindow(window, device, arrays, g, gl, nbytes)
+    arrays, nbytes = resident_arrays(b, g, gl, putter(device))
+    return ResidentWindow(
+        window, device, dict(zip(ResidentWindow.FIELDS, arrays)), g, gl,
+        nbytes,
+    )
 
 
 class DevicePool:
@@ -879,26 +875,28 @@ def markdup_dummy_args(b, g: int, gl: int, gc: int) -> tuple:
 
 
 def observe_dummy_args(b, g: int, gl: int) -> tuple:
-    """observe_kernel's 8 array args at grid (g rows, gl lanes) —
-    static (n_rg, gl) follow at the call site."""
+    """observe_packed_body's 8 array args at grid (g rows, gl lanes):
+    the resident five, the two bit-packed MD masks and the read filter
+    — static (n_rg, gl) follow at the call site."""
     from adam_tpu.formats import schema
 
     _z = dummy_like
+    npk = -(-gl // 8)
     return (
         _z(b.bases, (g, gl), schema.BASE_PAD),
         _z(b.quals, (g, gl), schema.QUAL_PAD),
         _z(b.lengths, (g,)),
         _z(b.flags, (g,), schema.FLAG_UNMAPPED),
         _z(b.read_group_idx, (g,), -1),
-        np.zeros((g, gl), bool), np.zeros((g, gl), bool),
+        np.zeros((g, npk), np.uint8), np.zeros((g, npk), np.uint8),
         np.zeros((g,), bool),
     )
 
 
 def apply_dummy_args(b, g: int, gl: int) -> tuple:
-    """apply_table_kernel's 7 per-row args at grid (g rows, gl lanes) —
-    the u8 table dummy (shape depends on the solved width) and static
-    gl follow at the call site."""
+    """apply_pack2_body's 7 per-row args at grid (g rows, gl lanes) —
+    the u8 table dummy (shape depends on the solved width) and the
+    statics (gl, g*gl) follow at the call site."""
     from adam_tpu.formats import schema
 
     _z = dummy_like
@@ -912,9 +910,18 @@ def apply_dummy_args(b, g: int, gl: int) -> tuple:
     )
 
 
+def fused_bc_dummy_args(b, g: int, gl: int) -> tuple:
+    """fused_bc_body's 10 per-row args at grid (g rows, gl lanes) —
+    the observe signature, then the apply side's ``has_qual``/``valid``;
+    the u8 table dummy and the statics (n_rg, gl, g*gl) follow at the
+    call site."""
+    return observe_dummy_args(b, g, gl) + (
+        np.zeros((g,), bool), np.zeros((g,), bool),
+    )
+
+
 def streamed_prewarm_entries(
     b, n_rg: int, *, mark_duplicates: bool = True, recalibrate: bool = True,
-    packed_apply: bool = False, resident: bool = False,
     fused_n_cyc: int | None = None,
 ) -> list[tuple]:
     """The grid-quantized kernel set the streamed device path dispatches,
@@ -922,15 +929,15 @@ def streamed_prewarm_entries(
     (shapes AND dtypes must match the real dispatches bit-for-bit or the
     jit cache treats the warm call as a different program).
 
-    Covers: markdup [N, L] key/score reductions (pass A), the BQSR
-    observe scatter-add (pass B), and the apply table-gather (pass C).
-    ``resident=True`` warms the resident-window variants the passes
-    actually dispatch — the bit-packed-mask observe, the fused
-    bases+quals pack2 apply, and (where :func:`donation_ok`) the
-    donating twins — ALONGSIDE the plain kernels, which stay warm as
-    the replay/fallback path.  ``fused_n_cyc`` (the known table's cycle
-    width) additionally warms the fused B→C megakernel the known-table
-    tier dispatches (docs/PERF.md "Megakernel tier").
+    One program per pass, each the variant a dispatch against a
+    :class:`ResidentWindow` calls (the donating twin where
+    :func:`donation_ok`): the markdup key/score reductions (pass A), the
+    bit-packed-mask observe (pass B), and the fused bases+quals pack2
+    apply (pass C) at window 0's own table width — pass C re-warms at
+    the solved width through :func:`apply_prewarm_entry`.
+    ``fused_n_cyc`` (the known table's cycle width) additionally warms
+    the fused B→C megakernel the known-table tier dispatches
+    (docs/PERF.md "Megakernel tier").
     """
     import jax
 
@@ -953,52 +960,19 @@ def streamed_prewarm_entries(
                 jax.device_put(a, dev)
                 for a in markdup_dummy_args(b, g, gl, gc)
             )
-            jax.block_until_ready(get_columns_jit()(*args))
-            if resident and donation_ok(dev):
-                # the resident dispatch donates its per-pass start/
-                # n_ops temporaries — a distinct executable
-                jax.block_until_ready(get_columns_jit(donate=True)(*(
-                    jax.device_put(a, dev)
-                    for a in markdup_dummy_args(b, g, gl, gc)
-                )))
+            jax.block_until_ready(
+                get_columns_jit(donate=donation_ok(dev))(*args)
+            )
 
         entries.append((("markdup.columns", g, gc, gl), warm_markdup))
 
     if recalibrate:
-        def warm_observe(dev, g=g, gl=gl):
-            from adam_tpu.pipelines.bqsr import observe_kernel
-
-            out = observe_kernel(*(
-                jax.device_put(a, dev)
-                for a in observe_dummy_args(b, g, gl)
-            ), n_rg, gl)
-            jax.block_until_ready(out)
-
-        entries.append((("bqsr.observe", g, gl, n_rg), warm_observe))
-        if resident:
-            entries.append(observe_packed_prewarm_entry(b, n_rg))
+        entries.append(observe_packed_prewarm_entry(b, n_rg))
         # pass A can only assume the solved table will match window 0's
-        # grid width; pass C re-warms with the REAL merged width via
-        # apply_prewarm_entry (same key space, so uniform-lmax inputs
-        # dedupe it to a no-op)
-        if packed_apply and resident:
-            entries.append(_apply_entry(
-                b, n_rg, g, gl, 2 * gl + 1, pack=True, resident=True
-            ))
-        if packed_apply:
-            # the quals-only pack stays warm on resident runs too: a
-            # residency miss (evicted handle) re-dispatches through it
-            entries.append(
-                _apply_entry(b, n_rg, g, gl, 2 * gl + 1, pack=True)
-            )
-        # the plain gather stays warm even on packed runs: the
-        # eviction replay path re-applies with pack=False on a
-        # survivor, and that dispatch must never cold-compile inside
-        # the window it is rescuing
-        entries.append(_apply_entry(
-            b, n_rg, g, gl, 2 * gl + 1, resident=resident
-        ))
-        if fused_n_cyc is not None and resident:
+        # grid width; pass C re-warms with the REAL merged width (same
+        # key space, so uniform-lmax inputs dedupe it to a no-op)
+        entries.append(apply_prewarm_entry(b, n_rg, 2 * gl + 1))
+        if fused_n_cyc is not None:
             # the fused B→C megakernel, at the KNOWN table's real
             # cycle width (never 2*gl+1: the known table's geometry is
             # the cohort's, not this window's)
@@ -1006,188 +980,79 @@ def streamed_prewarm_entries(
     return entries
 
 
-def _apply_entry(b, n_rg: int, g: int, gl: int, n_cyc: int,
-                 pack: bool = False, resident: bool = False) -> tuple:
+def _warm_variant(kind: str, args_fn, statics: tuple):
+    """A prewarm thunk for one ``bqsr.jit_variant`` kind: the variant
+    its dispatch calls on ``dev`` (donating where :func:`donation_ok`),
+    run to completion on ``args_fn()``'s dummies."""
     import jax
 
-    def warm_apply(dev):
-        from adam_tpu.pipelines.bqsr import N_DINUC, N_QUAL, jit_variant
+    def warm(dev):
+        from adam_tpu.pipelines.bqsr import jit_variant
 
-        def placed_args():
-            args = apply_dummy_args(b, g, gl) + (
-                np.zeros((n_rg, N_QUAL, n_cyc, N_DINUC), np.uint8),
-            )
-            return tuple(jax.device_put(a, dev) for a in args)
+        placed = tuple(jax.device_put(a, dev) for a in args_fn())
+        jax.block_until_ready(
+            jit_variant(kind, donation_ok(dev))(*placed, *statics)
+        )
 
-        donate = resident and donation_ok(dev)
-        if pack and resident:
-            kinds = ["apply_pack2"]
-        elif pack:
-            kinds = ["apply_pack"]
-        else:
-            kinds = ["apply"]
-        for kind in kinds:
-            if kind == "apply":
-                out = jit_variant(kind, donate)(*placed_args(), gl)
-            else:
-                out = jit_variant(kind, donate)(*placed_args(), gl, g * gl)
-            jax.block_until_ready(out)
-            if donate:
-                # the non-donating twin stays warm beside it: a
-                # consumed-handle retry re-dispatches without donation
-                if kind == "apply":
-                    out = jit_variant(kind, False)(*placed_args(), gl)
-                else:
-                    out = jit_variant(kind, False)(
-                        *placed_args(), gl, g * gl
-                    )
-                jax.block_until_ready(out)
-
-    # literal key tuples (not one with a computed kernel name): the
-    # dispatch-ledger rule's prewarm cross-check parses these literals
-    if pack and resident:
-        return (("bqsr.apply_pack2", g, gl, n_rg, n_cyc), warm_apply)
-    if pack:
-        return (("bqsr.apply_pack", g, gl, n_rg, n_cyc), warm_apply)
-    return (("bqsr.apply", g, gl, n_rg, n_cyc), warm_apply)
+    return warm
 
 
 def observe_packed_prewarm_entry(b, n_rg: int) -> tuple:
-    """Prewarm entry for the resident-window observe variant: the
-    bit-packed-mask kernel (``bqsr.observe_packed_body``), donating its
-    mask temporaries where :func:`donation_ok`."""
-    import jax
-
+    """Prewarm entry for the pass-B observe (``bqsr.observe_packed_body``,
+    bit-packed masks, donating its mask temporaries where
+    :func:`donation_ok`) at one batch view's grid shape.  Also the
+    long-tail re-warm: residual windows and the realigned tail part land
+    on grids window 0 never saw, so the streamed pipeline re-prewarms
+    on first sight of a new shape through the same process-wide dedupe
+    cache (already-warm shapes are free)."""
     from adam_tpu.formats.batch import grid_cols, grid_rows
 
     g = grid_rows(b.n_rows)
     gl = grid_cols(b.lmax)
-
-    def warm_observe_packed(dev, g=g, gl=gl):
-        from adam_tpu.pipelines.bqsr import jit_variant
-
-        def placed_args():
-            base = observe_dummy_args(b, g, gl)
-            # the packed-mask signature: masks ride bit-packed u8
-            args = base[:5] + (
-                np.zeros((g, gl // 8 + (1 if gl % 8 else 0)), np.uint8),
-                np.zeros((g, gl // 8 + (1 if gl % 8 else 0)), np.uint8),
-                base[7],
-            )
-            return tuple(jax.device_put(a, dev) for a in args)
-
-        donate = donation_ok(dev)
-        out = jit_variant("observe_packed", donate)(
-            *placed_args(), n_rg, gl
-        )
-        jax.block_until_ready(out)
-        if donate:
-            out = jit_variant("observe_packed", False)(
-                *placed_args(), n_rg, gl
-            )
-            jax.block_until_ready(out)
-
-    return (("bqsr.observe_packed", g, gl, n_rg), warm_observe_packed)
-
-
-def fused_bc_dummy_args(b, g: int, gl: int) -> tuple:
-    """fused_bc_body's 10 per-row args at grid (g rows, gl lanes) —
-    the observe signature's resident five + bit-packed masks + read
-    filter, then the apply side's ``has_qual``/``valid``; the u8 table
-    dummy and the statics (n_rg, gl, g*gl) follow at the call site."""
-    base = observe_dummy_args(b, g, gl)
-    npk = gl // 8 + (1 if gl % 8 else 0)
-    return base[:5] + (
-        np.zeros((g, npk), np.uint8), np.zeros((g, npk), np.uint8),
-        base[7],
-        np.zeros((g,), bool), np.zeros((g,), bool),
-    )
+    return (("bqsr.observe_packed", g, gl, n_rg), _warm_variant(
+        "observe_packed", lambda: observe_dummy_args(b, g, gl), (n_rg, gl),
+    ))
 
 
 def fused_bc_prewarm_entry(b, n_rg: int, table_n_cyc: int) -> tuple:
     """Prewarm entry for the fused B→C megakernel
     (``bqsr.fused_bc_body``) keyed by the known table's real cycle
     width — dispatched when the recalibration table is available at
-    ingest (known-sites runs, discovered-table resumes).  Warms the
-    donating twin where :func:`donation_ok` plus the plain twin beside
-    it (a consumed-handle retry re-dispatches without donation)."""
-    import jax
-
+    ingest (known-sites runs, discovered-table resumes)."""
     from adam_tpu.formats.batch import grid_cols, grid_rows
+    from adam_tpu.pipelines.bqsr import N_DINUC, N_QUAL
 
     g = grid_rows(b.n_rows)
     gl = grid_cols(b.lmax)
-
-    def warm_fused_bc(dev, g=g, gl=gl):
-        from adam_tpu.pipelines.bqsr import N_DINUC, N_QUAL, jit_variant
-
-        def placed_args():
-            args = fused_bc_dummy_args(b, g, gl) + (
-                np.zeros(
-                    (n_rg, N_QUAL, table_n_cyc, N_DINUC), np.uint8
-                ),
-            )
-            return tuple(jax.device_put(a, dev) for a in args)
-
-        donate = donation_ok(dev)
-        out = jit_variant("fused_bc", donate)(
-            *placed_args(), n_rg, gl, g * gl
-        )
-        jax.block_until_ready(out)
-        if donate:
-            out = jit_variant("fused_bc", False)(
-                *placed_args(), n_rg, gl, g * gl
-            )
-            jax.block_until_ready(out)
-
-    return (("bqsr.fused_bc", g, gl, n_rg, table_n_cyc), warm_fused_bc)
+    return (("bqsr.fused_bc", g, gl, n_rg, table_n_cyc), _warm_variant(
+        "fused_bc",
+        lambda: fused_bc_dummy_args(b, g, gl) + (np.zeros(
+            (n_rg, N_QUAL, table_n_cyc, N_DINUC), np.uint8
+        ),),
+        (n_rg, gl, g * gl),
+    ))
 
 
-def apply_prewarm_entry(b, n_rg: int, table_n_cyc: int,
-                        pack: bool = False,
-                        resident: bool = False) -> tuple:
-    """Pass-C re-warm entry: the apply table-gather keyed by the SOLVED
-    table's real cycle width.  ``merge_observations`` widens the table
-    to the maximum window grid, which can exceed the window-0 width the
-    pass-A prewarm assumed — without this, every device would pay the
-    apply compile inside pass C on variable-length inputs.  Shares the
-    pass-A entry's key space, so the uniform-lmax common case dedupes
-    to a no-op against the process-wide cache.  ``pack=True`` warms the
-    fused apply+pack kernel (the packed-column pass-C dispatch);
-    ``resident=True`` selects the resident-window variants (the
-    bases+quals pack2 when packed, and the donating twins where
-    :func:`donation_ok`)."""
+def apply_prewarm_entry(b, n_rg: int, table_n_cyc: int) -> tuple:
+    """Pass-C prewarm entry: the fused bases+quals pack2 apply
+    (``bqsr.apply_pack2_body``) keyed by the table's real cycle width.
+    ``merge_observations`` widens the table to the maximum window grid,
+    which can exceed the window-0 width the pass-A prewarm assumed —
+    so pass C re-warms at the SOLVED width; the shared key space
+    dedupes the uniform-lmax common case to a no-op against the
+    process-wide cache."""
     from adam_tpu.formats.batch import grid_cols, grid_rows
-
-    return _apply_entry(
-        b, n_rg, grid_rows(b.n_rows), grid_cols(b.lmax), table_n_cyc,
-        pack=pack, resident=resident,
-    )
-
-
-def observe_prewarm_entry(b, n_rg: int) -> tuple:
-    """Observe-only prewarm entry at one batch view's grid shape — the
-    long-tail re-warm hook: residual windows and the realigned tail
-    part land on grids window 0 never saw (the measured grid-1024
-    0.26 s in-window cold compile, docs/PERF.md), so the streamed
-    pipeline re-prewarms on first sight of a new shape through the same
-    process-wide dedupe cache (already-warm shapes are free)."""
-    import jax
-
-    from adam_tpu.formats.batch import grid_cols, grid_rows
+    from adam_tpu.pipelines.bqsr import N_DINUC, N_QUAL
 
     g = grid_rows(b.n_rows)
     gl = grid_cols(b.lmax)
-
-    def warm_observe(dev, g=g, gl=gl):
-        from adam_tpu.pipelines.bqsr import observe_kernel
-
-        out = observe_kernel(*(
-            jax.device_put(a, dev) for a in observe_dummy_args(b, g, gl)
-        ), n_rg, gl)
-        jax.block_until_ready(out)
-
-    return (("bqsr.observe", g, gl, n_rg), warm_observe)
+    return (("bqsr.apply_pack2", g, gl, n_rg, table_n_cyc), _warm_variant(
+        "apply_pack2",
+        lambda: apply_dummy_args(b, g, gl) + (np.zeros(
+            (n_rg, N_QUAL, table_n_cyc, N_DINUC), np.uint8
+        ),),
+        (gl, g * gl),
+    ))
 
 
 # --------------------------------------------------------------------------

@@ -256,107 +256,6 @@ def _mesh_specs(n_args: int):
     return tuple(P(BATCH_AXIS) for _ in range(n_args))
 
 
-def _mesh_observe_jit_builder():
-    import jax
-    from jax import shard_map
-    from jax.sharding import PartitionSpec as P
-
-    from adam_tpu.parallel.mesh import BATCH_AXIS
-
-    @partial(jax.jit, static_argnames=("n_rg", "lmax", "mesh"))
-    def run(bases, quals, lengths, flags, rg, res_ok, is_mm, rd_ok,
-            n_rg, lmax, mesh):
-        from adam_tpu.pipelines.bqsr import observe_kernel
-
-        @partial(
-            shard_map, mesh=mesh, in_specs=_mesh_specs(8),
-            out_specs=(P(), P()), check_vma=False,
-        )
-        def body(b, q, le, fl, r, ro, mm, ok):
-            # the exact single-chip kernel body per shard; the i64
-            # cross-shard psum is the on-device analog of the pool's
-            # host-side window-order merge — integer adds, so the sums
-            # are bitwise identical in any order
-            total, mism = observe_kernel.__wrapped__(
-                b, q, le, fl, r, ro, mm, ok, n_rg, lmax
-            )
-            return (
-                jax.lax.psum(total, BATCH_AXIS),
-                jax.lax.psum(mism, BATCH_AXIS),
-            )
-
-        return body(bases, quals, lengths, flags, rg, res_ok, is_mm, rd_ok)
-
-    return run
-
-
-def _mesh_apply_jit_builder(donate: bool):
-    import jax
-    from jax import shard_map
-    from jax.sharding import PartitionSpec as P
-
-    from adam_tpu.parallel.mesh import BATCH_AXIS
-
-    def run(bases, quals, lengths, flags, rg, has_qual, valid, table,
-            lmax, mesh):
-        from adam_tpu.pipelines.bqsr import apply_table_kernel
-
-        @partial(
-            shard_map, mesh=mesh, in_specs=_mesh_specs(7) + (P(),),
-            out_specs=P(BATCH_AXIS), check_vma=False,
-        )
-        def body(b, q, le, fl, r, hq, v, tbl):
-            return apply_table_kernel.__wrapped__(
-                b, q, le, fl, r, hq, v, tbl, lmax
-            )
-
-        return body(bases, quals, lengths, flags, rg, has_qual, valid, table)
-
-    kw = {"static_argnames": ("lmax", "mesh")}
-    if donate:
-        # the new quals alias the old quals' shape/dtype: donating the
-        # input buffer keeps pass C's HBM footprint at one [g, gl] u8
-        # per in-flight window instead of two
-        kw["donate_argnums"] = (1,)
-    return partial(jax.jit, **kw)(run)
-
-
-def _mesh_apply_pack_jit_builder(donate: bool):
-    import jax
-    from jax import shard_map
-    from jax.sharding import PartitionSpec as P
-
-    from adam_tpu.parallel.mesh import BATCH_AXIS
-
-    def run(bases, quals, lengths, flags, rg, has_qual, valid, table,
-            lmax, mesh):
-        from adam_tpu.pipelines.bqsr import apply_pack_body
-
-        @partial(
-            shard_map, mesh=mesh, in_specs=_mesh_specs(7) + (P(),),
-            out_specs=P(BATCH_AXIS), check_vma=False,
-        )
-        def body(b, q, le, fl, r, hq, v, tbl):
-            # each shard fuses the gather with the column pack over its
-            # own row block (size static at trace: local rows x lanes);
-            # the global flat output is shard payloads in shard order —
-            # which IS row order, so the host-side concat of the
-            # per-shard payload slices is the single-device pack
-            return apply_pack_body(
-                b, q, le, fl, r, hq, v, tbl, lmax,
-                b.shape[0] * b.shape[1],
-            )
-
-        return body(bases, quals, lengths, flags, rg, has_qual, valid, table)
-
-    kw = {"static_argnames": ("lmax", "mesh")}
-    if donate:
-        # the flat packed output matches the donated quals buffer's
-        # byte size exactly ([g*gl] u8 vs [g, gl] u8)
-        kw["donate_argnums"] = (1,)
-    return partial(jax.jit, **kw)(run)
-
-
 def _mesh_observe_packed_jit_builder(donate: bool):
     import jax
     from jax import shard_map
@@ -518,22 +417,14 @@ def _mesh_jit(kind: str, donate: bool = False):
         with _MESH_JITS_LOCK:
             fn = _MESH_JITS.get(key)
             if fn is None:
-                builder = {
-                    "observe": _mesh_observe_jit_builder,
-                    "markdup": _mesh_markdup_jit_builder,
-                }.get(kind)
-                if builder is not None:
-                    fn = builder()
-                elif kind == "observe_packed":
-                    fn = _mesh_observe_packed_jit_builder(donate)
-                elif kind == "apply_pack":
-                    fn = _mesh_apply_pack_jit_builder(donate)
-                elif kind == "apply_pack2":
-                    fn = _mesh_apply_pack2_jit_builder(donate)
-                elif kind == "fused_bc":
-                    fn = _mesh_fused_bc_jit_builder(donate)
+                if kind == "markdup":
+                    fn = _mesh_markdup_jit_builder()
                 else:
-                    fn = _mesh_apply_jit_builder(donate)
+                    fn = {
+                        "observe_packed": _mesh_observe_packed_jit_builder,
+                        "apply_pack2": _mesh_apply_pack2_jit_builder,
+                        "fused_bc": _mesh_fused_bc_jit_builder,
+                    }[kind](donate)
                 _MESH_JITS[key] = fn
     return fn
 
@@ -619,18 +510,6 @@ class MeshPartitioner:
         return self._put(x, self._rep, getattr(x, "nbytes", 0))
 
     # ---- pass B: observe + on-device accumulate ------------------------
-    def observe_window(self, arrays: tuple, n_rg: int, gl: int):
-        """Dispatch one window's observe scatter-add across the mesh ->
-        lazy replicated (total, mism) i64 device arrays.
-
-        ``arrays``: the 8 host arrays of the observe kernel signature,
-        already padded to (:meth:`rows_for`(g), gl) rows/lanes.
-        """
-        placed = tuple(self.put_rows(a) for a in arrays)
-        # adam-tpu: noqa[dispatch-ledger] reason=every caller (bqsr._observe_device mesh branch and the mesh prewarm) wraps this dispatch in its own track keyed mesh.observe
-        return _mesh_jit("observe")(*placed, n_rg=n_rg, lmax=gl,
-                                    mesh=self.mesh)
-
     def accumulate(self, total, mism, gl: int) -> None:
         """Fold one window's lazy histograms into the device-resident
         running table for its grid width (i64 adds: bitwise identical
@@ -673,11 +552,12 @@ class MeshPartitioner:
 
     # ---- pass A: markdup columns ---------------------------------------
     def markdup_window(self, arrays: tuple):
-        """Row-sharded [N, L] markdup reductions -> lazy (five, score)
-        row-sharded device arrays (padded rows included; caller
-        slices)."""
+        """Row-sharded [N, L] markdup reductions over the 8 host arrays
+        of the kernel signature -> lazy (five, score) row-sharded device
+        arrays (the mesh prewarm's seam; windows dispatch through
+        :meth:`markdup_window_resident`)."""
         placed = tuple(self.put_rows(a) for a in arrays)
-        # adam-tpu: noqa[dispatch-ledger] reason=every caller (markdup_columns_dispatch mesh branch and the mesh prewarm) wraps this dispatch in its own track keyed mesh.markdup
+        # adam-tpu: noqa[dispatch-ledger] reason=the mesh prewarm, its only caller, wraps this dispatch in its own track keyed mesh.markdup
         return _mesh_jit("markdup")(*placed, mesh=self.mesh)
 
     def markdup_window_resident(self, rw, fresh: tuple):
@@ -722,42 +602,6 @@ class MeshPartitioner:
         from adam_tpu.parallel.mesh import on_tpu
 
         return all(on_tpu(d) for d in self.devices)
-
-    def apply_window(self, arrays: tuple, table_dev, gl: int):
-        """Dispatch one window's recalibration gather across the mesh
-        -> lazy row-sharded u8[g, gl] quals.  ``table_dev`` must come
-        from :meth:`put_replicated` — placed once, device-resident for
-        every window of pass C (the B→C no-round-trip contract)."""
-        placed = tuple(self.put_rows(a) for a in arrays)
-        # adam-tpu: noqa[dispatch-ledger] reason=every caller (bqsr apply mesh branch and the mesh prewarm) wraps this dispatch in its own track keyed mesh.apply
-        return _mesh_jit("apply", donate=self.apply_supports_donation())(
-            *placed, table_dev, lmax=gl, mesh=self.mesh
-        )
-
-    def apply_pack_window(self, arrays: tuple, table_dev, gl: int):
-        """Fused apply + column pack across the mesh -> lazy flat
-        u8[g*gl], row-sharded: shard k's segment starts with exactly
-        its rows' packed SANGER qual bytes (``ops/colpack``).  Pair
-        with :meth:`packed_payload_slices` to fetch only the real
-        column payload — the pass-C d2h shrink."""
-        placed = tuple(self.put_rows(a) for a in arrays)
-        # adam-tpu: noqa[dispatch-ledger] reason=every caller (bqsr apply_pack mesh branch and the mesh prewarm) wraps this dispatch in its own track keyed mesh.apply_pack
-        return _mesh_jit(
-            "apply_pack", donate=self.apply_supports_donation()
-        )(*placed, table_dev, lmax=gl, mesh=self.mesh)
-
-    def apply_window_resident(self, rw, has_qual, valid, table_dev,
-                              gl: int):
-        """Resident-window plain apply: the five resident arrays plus
-        the post-split ``has_qual``/``valid`` bools (the only per-pass
-        h2d) -> lazy row-sharded u8[g, gl] quals."""
-        placed = rw.args() + (
-            self.put_rows(has_qual), self.put_rows(valid),
-        )
-        # adam-tpu: noqa[dispatch-ledger] reason=every caller (bqsr apply mesh resident branch) wraps this dispatch in its own track keyed mesh.apply
-        return _mesh_jit("apply", donate=self.apply_supports_donation())(
-            *placed, table_dev, lmax=gl, mesh=self.mesh
-        )
 
     def apply_pack2_placed(self, placed: tuple, table_dev, gl: int):
         """Dispatch the fused apply + bases+quals pack collective over
@@ -883,32 +727,28 @@ class MeshPartitioner:
 def mesh_resident_window(b, window: int, part: MeshPartitioner):
     """Place one window's resident payload as batch-sharded mesh arrays
     (the mesh analog of ``device_pool.make_resident_window``): one
-    ``NamedSharding`` placement at ingest, reused by every shard_map
-    pass.  Rows pad to the mesh width; callers wrap this in
-    ``telemetry.pass_scope("ingest")`` for the h2d ledger."""
-    from adam_tpu.formats import schema
-    from adam_tpu.formats.batch import grid_cols, grid_rows, pad_rows_np
-    from adam_tpu.parallel.device_pool import ResidentWindow
+    ``NamedSharding`` placement, reused by every shard_map pass — the
+    pin at ingest, or a pass's transient handle.  Rows pad to the mesh
+    width; callers set the ``telemetry.pass_scope`` the h2d ledger books
+    it under."""
+    from adam_tpu.formats.batch import grid_cols, grid_rows
+    from adam_tpu.parallel.device_pool import ResidentWindow, resident_arrays
 
     gm = part.rows_for(grid_rows(b.n_rows))
     gl = grid_cols(b.lmax)
-    host = {
-        "bases": pad_rows_np(b.bases, gm, schema.BASE_PAD, cols=gl),
-        "quals": pad_rows_np(b.quals, gm, schema.QUAL_PAD, cols=gl),
-        "lengths": pad_rows_np(b.lengths, gm, 0),
-        "flags": pad_rows_np(b.flags, gm, schema.FLAG_UNMAPPED),
-        "read_group_idx": pad_rows_np(b.read_group_idx, gm, -1),
-    }
-    nbytes = sum(int(a.nbytes) for a in host.values())
-    arrays = {k: part.put_rows(a) for k, a in host.items()}
-    return ResidentWindow(window, "mesh", arrays, gm, gl, nbytes)
+    arrays, nbytes = resident_arrays(b, gm, gl, part.put_rows)
+    return ResidentWindow(
+        window, "mesh", dict(zip(ResidentWindow.FIELDS, arrays)), gm, gl,
+        nbytes,
+    )
 
 
 def mesh_observe_packed_prewarm_entry(b, n_rg: int,
                                       part: MeshPartitioner) -> tuple:
     """Prewarm entry for the mesh bit-packed-mask observe jit (the
-    resident-window pass-B dispatch variant) at one window's grid
-    shape."""
+    pass-B dispatch) at one window's grid shape — the pool entry's
+    dummy args (``device_pool.observe_dummy_args``), only the row count
+    pads to the mesh width."""
     import jax
 
     from adam_tpu.formats.batch import grid_cols, grid_rows
@@ -918,41 +758,14 @@ def mesh_observe_packed_prewarm_entry(b, n_rg: int,
     gl = grid_cols(b.lmax)
 
     def warm(_dev, g=g, gl=gl):
-        base = observe_dummy_args(b, g, gl)
-        npk = gl // 8 + (1 if gl % 8 else 0)
         placed = tuple(
-            part.put_rows(a) for a in base[:5] + (
-                np.zeros((g, npk), np.uint8),
-                np.zeros((g, npk), np.uint8),
-                base[7],
-            )
+            part.put_rows(a) for a in observe_dummy_args(b, g, gl)
         )
         jax.block_until_ready(
             part.observe_packed_window(placed, n_rg, gl)
         )
 
     return (("mesh.observe_packed", g, gl, n_rg), warm)
-
-
-def mesh_observe_prewarm_entry(b, n_rg: int, part: MeshPartitioner) -> tuple:
-    """Prewarm entry for the mesh observe jit at one window's grid
-    shape — the same kernel dummy args as the pool entry
-    (``device_pool.observe_dummy_args``, the single source of truth per
-    kernel signature), only the row count pads to the mesh width."""
-    import jax
-
-    from adam_tpu.formats.batch import grid_cols, grid_rows
-    from adam_tpu.parallel.device_pool import observe_dummy_args
-
-    g = part.rows_for(grid_rows(b.n_rows))
-    gl = grid_cols(b.lmax)
-
-    def warm(_dev, g=g, gl=gl):
-        jax.block_until_ready(
-            part.observe_window(observe_dummy_args(b, g, gl), n_rg, gl)
-        )
-
-    return (("mesh.observe", g, gl, n_rg), warm)
 
 
 def mesh_markdup_prewarm_entry(b, part: MeshPartitioner) -> tuple:
@@ -980,15 +793,11 @@ def mesh_markdup_prewarm_entry(b, part: MeshPartitioner) -> tuple:
 
 
 def mesh_apply_prewarm_entry(b, n_rg: int, n_cyc: int,
-                             part: MeshPartitioner,
-                             pack: bool = False,
-                             pack2: bool = False) -> tuple:
-    """Prewarm entry for the mesh apply jit keyed by the SOLVED table's
-    real cycle width (the pass-C re-warm, device_pool.apply_prewarm_entry
-    semantics; ``device_pool.apply_dummy_args``).  ``pack=True`` warms
-    the fused apply+pack variant, ``pack2=True`` the resident-window
-    bases+quals pack (each its own executable — the key carries the
-    kernel name, so all can coexist warm)."""
+                             part: MeshPartitioner) -> tuple:
+    """Prewarm entry for the mesh pack2 apply jit keyed by the SOLVED
+    table's real cycle width (the pass-C re-warm,
+    ``device_pool.apply_prewarm_entry`` semantics;
+    ``device_pool.apply_dummy_args``)."""
     import jax
 
     from adam_tpu.formats.batch import grid_cols, grid_rows
@@ -1002,26 +811,12 @@ def mesh_apply_prewarm_entry(b, n_rg: int, n_cyc: int,
         tbl = part.put_replicated(
             np.zeros((n_rg, N_QUAL, n_cyc, N_DINUC), np.uint8)
         )
-        if pack2:
-            placed = tuple(
-                part.put_rows(a) for a in apply_dummy_args(b, g, gl)
-            )
-            jax.block_until_ready(
-                part.apply_pack2_placed(placed, tbl, gl)
-            )
-            return
-        runner = part.apply_pack_window if pack else part.apply_window
-        jax.block_until_ready(
-            runner(apply_dummy_args(b, g, gl), tbl, gl)
+        placed = tuple(
+            part.put_rows(a) for a in apply_dummy_args(b, g, gl)
         )
+        jax.block_until_ready(part.apply_pack2_placed(placed, tbl, gl))
 
-    # literal key tuples (not one with a computed kernel name): the
-    # dispatch-ledger rule's prewarm cross-check parses these literals
-    if pack2:
-        return (("mesh.apply_pack2", g, gl, n_rg, n_cyc), warm)
-    if pack:
-        return (("mesh.apply_pack", g, gl, n_rg, n_cyc), warm)
-    return (("mesh.apply", g, gl, n_rg, n_cyc), warm)
+    return (("mesh.apply_pack2", g, gl, n_rg, n_cyc), warm)
 
 
 def mesh_fused_bc_prewarm_entry(b, n_rg: int, n_cyc: int,

@@ -243,15 +243,14 @@ _JIT_VARIANTS_LOCK = threading.Lock()
 
 
 def jit_variant(kind: str, donate: bool = False):
-    """The jit for one kernel ``kind`` (``observe_packed`` / ``apply``
-    / ``apply_pack`` / ``apply_pack2`` / ``fused_bc``) with or without
-    buffer donation.  Donation aliases the dead-after-apply inputs into
-    the outputs (the resident quals buffer becomes the packed qual
-    column, the resident bases buffer the packed base column; the
-    observe variant donates its per-pass mask temporaries), halving
-    pass-C's per-window HBM footprint — only offered where the runtime
-    honors it (``device_pool.donation_ok``; CPU runtimes warn and
-    copy).
+    """The jit for one streamed kernel ``kind`` (``observe_packed`` /
+    ``apply_pack2`` / ``fused_bc``) with or without buffer donation.
+    Donation aliases the dead-after-apply inputs into the outputs (the
+    resident quals buffer becomes the packed qual column, the resident
+    bases buffer the packed base column; the observe variant donates
+    its per-pass mask temporaries), halving pass-C's per-window HBM
+    footprint — only offered where the runtime honors it
+    (``device_pool.donation_ok``; CPU runtimes warn and copy).
 
     Keyed by ``(kind, donate)``."""
     key = (kind, bool(donate))
@@ -262,26 +261,19 @@ def jit_variant(kind: str, donate: bool = False):
         fn = _JIT_VARIANTS.get(key)
         if fn is not None:
             return fn
-        if not donate and kind == "apply":
-            fn = apply_table_kernel
-        else:
-            body, statics, donums = {
-                "observe_packed": (
-                    observe_packed_body, ("n_rg", "lmax"), (5, 6)
-                ),
-                "apply": (apply_table_body, ("lmax",), (1,)),
-                "apply_pack": (apply_pack_body, ("lmax", "size"), (1,)),
-                "apply_pack2": (
-                    apply_pack2_body, ("lmax", "size"), (0, 1)
-                ),
-                "fused_bc": (
-                    fused_bc_body, ("n_rg", "lmax", "size"), (0, 1, 5, 6)
-                ),
-            }[kind]
-            kw = {"static_argnames": statics}
-            if donate:
-                kw["donate_argnums"] = donums
-            fn = partial(jax.jit, **kw)(body)
+        body, statics, donums = {
+            "observe_packed": (
+                observe_packed_body, ("n_rg", "lmax"), (5, 6)
+            ),
+            "apply_pack2": (apply_pack2_body, ("lmax", "size"), (0, 1)),
+            "fused_bc": (
+                fused_bc_body, ("n_rg", "lmax", "size"), (0, 1, 5, 6)
+            ),
+        }[kind]
+        kw = {"static_argnames": statics}
+        if donate:
+            kw["donate_argnums"] = donums
+        fn = partial(jax.jit, **kw)(body)
         _JIT_VARIANTS[key] = fn
     return fn
 
@@ -382,20 +374,20 @@ def _observe_device(
 
     ``device``: explicit jax device for the ``device`` backend's
     scatter-add (the multi-chip pool's round-robin target); ``None``
-    keeps the default device.  ``mesh``: a
-    :class:`~adam_tpu.parallel.partitioner.MeshPartitioner` — the
-    window's [N, L] arrays shard over its ``batch`` axis, the
-    scatter-add runs per shard and the histograms ``psum`` on-device;
-    the returned (total, mism) are lazy *replicated* device arrays the
-    streamed pipeline folds into its device-resident accumulator
-    instead of fetching per window.  Downstream consumers dispatch on
-    ``isinstance(total, np.ndarray)`` so each path stays on its side of
-    the device link.  ``resident``: the window's ingest-resident device
-    payload (``device_pool.ResidentWindow``) — bases/quals/lengths/
-    flags/rg dispatch off the handle and only the bit-packed per-pass
-    masks ship (``colpack.pack_mask_bits``); a dead or mismatched
-    handle falls back to the full re-ship, bitwise the same
-    histograms."""
+    keeps the default device.  ``resident``: the window's device
+    payload (``device_pool.ResidentWindow``, pinned at ingest or placed
+    for the pass) — the streamed leg: bases/quals/lengths/flags/rg
+    dispatch off the handle and only the bit-packed per-pass masks ship
+    (``colpack.pack_mask_bits``) into :func:`observe_packed_body`.
+    ``mesh``: a :class:`~adam_tpu.parallel.partitioner.MeshPartitioner`
+    (with a mesh-sharded ``resident``) — the scatter-add runs per shard
+    and the histograms ``psum`` on-device; the returned (total, mism)
+    are lazy *replicated* device arrays the streamed pipeline folds
+    into its device-resident accumulator instead of fetching per
+    window.  Without a handle the ``device`` backend runs the in-memory
+    API's single-device leg (:func:`observe_kernel`).  Downstream
+    consumers dispatch on ``isinstance(total, np.ndarray)`` so each
+    path stays on its side of the device link."""
     backend = bqsr_backend(backend)
     from adam_tpu.parallel.device_pool import span_attrs
 
@@ -642,144 +634,82 @@ def _observe_kernel_dispatch(
 
         return run_numpy
     from adam_tpu.formats.batch import pad_rows_np
+    from adam_tpu.parallel.device_pool import (
+        donation_ok, putter, resident_arrays,
+    )
     from adam_tpu.utils import compile_ledger, faults
     from adam_tpu.utils import retry as _retry
 
     site = "bqsr.observe.dispatch"
-
-    if mesh is not None:
-        gm = mesh.rows_for(g)
-        rw = resident
-        if rw is not None and not (
-            rw.alive and rw.device == "mesh"
-            and rw.g == gm and rw.gl == gl
-        ):
-            rw = None
-        if rw is not None:
-            from adam_tpu.ops.colpack import pack_mask_bits
-
-            res_pk = pack_mask_bits(
-                pad_rows_np(residue_ok, gm, False, cols=gl)
-            )
-            mm_pk = pack_mask_bits(
-                pad_rows_np(is_mm, gm, False, cols=gl)
-            )
-            rd_pad = pad_rows_np(read_ok, gm, False)
-
-            def dispatch_mesh_resident():
-                # per-attempt placement of the small per-pass
-                # inputs keeps the retry idempotent even when the
-                # donating variant consumed a prior attempt's masks
-                faults.point("device.dispatch")
-                return mesh.observe_window_resident(
-                    rw, res_pk, mm_pk, rd_pad, n_rg, gl
-                )
-
-            def run_mesh_resident():
-                with compile_ledger.track(
-                    ("mesh.observe_packed", gm, gl, n_rg), mesh.ledger_key()
-                ):
-                    return _retry.retry_call(dispatch_mesh_resident, site=site)
-
-            return run_mesh_resident
-
-        args = (
-            # adam-tpu: noqa[residency] reason=non-resident fallback: residency off, a dead handle, or a replay re-ships from the host ingest copy
-            pad_rows_np(b.bases, gm, schema.BASE_PAD, cols=gl),
-            # adam-tpu: noqa[residency] reason=non-resident fallback: residency off, a dead handle, or a replay re-ships from the host ingest copy
-            pad_rows_np(b.quals, gm, schema.QUAL_PAD, cols=gl),
-            pad_rows_np(b.lengths, gm, 0),
-            pad_rows_np(b.flags, gm, schema.FLAG_UNMAPPED),
-            pad_rows_np(b.read_group_idx, gm, -1),
-            pad_rows_np(residue_ok, gm, False, cols=gl),
-            pad_rows_np(is_mm, gm, False, cols=gl),
-            pad_rows_np(read_ok, gm, False),
+    rw = resident
+    if rw is None:
+        if mesh is not None:
+            raise ValueError("the mesh observe dispatches on a resident "
+                             "window handle")
+        # the in-memory API's single-device leg: the plain kernel over
+        # this call's own placement of the row arrays and bool masks
+        _put = putter(device)
+        masks = (
+            pad_rows_np(residue_ok, g, False, cols=gl),
+            pad_rows_np(is_mm, g, False, cols=gl),
+            pad_rows_np(read_ok, g, False),
         )
 
-        def dispatch_mesh():
-            # the sharded placement + collective dispatch re-run as
-            # one idempotent unit, exactly like the pool path
+        def dispatch():
+            # ship + scatter-add as one retryable unit: re-running
+            # the commit and the jit dispatch is idempotent
+            faults.point("device.dispatch", device=device)
+            rows, _nbytes = resident_arrays(b, g, gl, _put)
+            return observe_kernel(
+                *rows, *(_put(m) for m in masks), n_rg, gl
+            )
+
+        def run():
+            # adam-tpu: noqa[dispatch-ledger] reason=the in-memory API's single-device leg: no streamed pass dispatches it, so no prewarm entry builds its key
+            with compile_ledger.track(("bqsr.observe", g, gl, n_rg), device):
+                return _retry.retry_call(dispatch, site=site)
+
+        return run
+
+    # the streamed leg: the five row arrays come from the window's
+    # resident handle and only the bit-packed masks + read filter ship
+    # (placed per attempt, so a consumed mask never re-enters a retry)
+    from adam_tpu.ops.colpack import pack_mask_bits
+
+    res_pk = pack_mask_bits(pad_rows_np(residue_ok, rw.g, False, cols=gl))
+    mm_pk = pack_mask_bits(pad_rows_np(is_mm, rw.g, False, cols=gl))
+    rd_pad = pad_rows_np(read_ok, rw.g, False)
+    if mesh is not None:
+        def dispatch_mesh_resident():
             faults.point("device.dispatch")
-            return mesh.observe_window(args, n_rg, gl)
+            return mesh.observe_window_resident(
+                rw, res_pk, mm_pk, rd_pad, n_rg, gl
+            )
 
-        def run_mesh():
-            # ledger key == the mesh prewarm entry key: an in-window
-            # miss here is a mesh prewarm coverage gap
+        def run_mesh_resident():
             with compile_ledger.track(
-                ("mesh.observe", gm, gl, n_rg), mesh.ledger_key()
+                ("mesh.observe_packed", rw.g, gl, n_rg), mesh.ledger_key()
             ):
-                return _retry.retry_call(dispatch_mesh, site=site)
+                return _retry.retry_call(dispatch_mesh_resident, site=site)
 
-        return run_mesh
-
-    from adam_tpu.parallel.device_pool import donation_ok, putter
+        return run_mesh_resident
 
     _put = putter(device)
-    rw = resident
-    if rw is not None and not (
-        rw.alive and rw.device is device
-        and rw.g == g and rw.gl == gl
-    ):
-        rw = None
-    if rw is not None:
-        from adam_tpu.ops.colpack import pack_mask_bits
 
-        res_pk = pack_mask_bits(
-            pad_rows_np(residue_ok, g, False, cols=gl)
-        )
-        mm_pk = pack_mask_bits(
-            pad_rows_np(is_mm, g, False, cols=gl)
-        )
-        rd_pad = pad_rows_np(read_ok, g, False)
-
-        def dispatch_resident():
-            # ingest-once H2D: the five resident arrays stay
-            # put; only the bit-packed masks + read filter ship
-            # (fresh placements per attempt, so the donating
-            # variant's consumed masks never re-enter a retry)
-            faults.point("device.dispatch", device=device)
-            return jit_variant(
-                "observe_packed", donation_ok(device)
-            )(
-                *rw.args(), _put(res_pk), _put(mm_pk),
-                _put(rd_pad), n_rg, gl,
-            )
-
-        def run_resident():
-            # ledger key == observe_packed_prewarm_entry's key
-            with compile_ledger.track(
-                ("bqsr.observe_packed", g, gl, n_rg), device
-            ):
-                return _retry.retry_call(dispatch_resident, site=site)
-
-        return run_resident
-
-    padded = (
-        # adam-tpu: noqa[residency] reason=non-resident fallback: residency off, a dead handle, or a replay re-ships from the host ingest copy
-        pad_rows_np(b.bases, g, schema.BASE_PAD, cols=gl),
-        # adam-tpu: noqa[residency] reason=non-resident fallback: residency off, a dead handle, or a replay re-ships from the host ingest copy
-        pad_rows_np(b.quals, g, schema.QUAL_PAD, cols=gl),
-        pad_rows_np(b.lengths, g, 0),
-        pad_rows_np(b.flags, g, schema.FLAG_UNMAPPED),
-        pad_rows_np(b.read_group_idx, g, -1),
-        pad_rows_np(residue_ok, g, False, cols=gl),
-        pad_rows_np(is_mm, g, False, cols=gl),
-        pad_rows_np(read_ok, g, False),
-    )
-
-    def dispatch():
-        # ship + scatter-add as one retryable unit: re-running
-        # the commit and the jit dispatch is idempotent
+    def dispatch_resident():
         faults.point("device.dispatch", device=device)
-        return observe_kernel(*[_put(a) for a in padded], n_rg, gl)
+        return jit_variant("observe_packed", donation_ok(device))(
+            *rw.args(), _put(res_pk), _put(mm_pk), _put(rd_pad), n_rg, gl,
+        )
 
-    def run():
-        # ledger key == the prewarm entry key ("bqsr.observe"):
-        # an in-window miss here is a prewarm coverage gap
-        with compile_ledger.track(("bqsr.observe", g, gl, n_rg), device):
-            return _retry.retry_call(dispatch, site=site)
+    def run_resident():
+        # ledger key == observe_packed_prewarm_entry's key
+        with compile_ledger.track(
+            ("bqsr.observe_packed", g, gl, n_rg), device
+        ):
+            return _retry.retry_call(dispatch_resident, site=site)
 
-    return run
+    return run_resident
 
 
 def build_observation_table(
@@ -955,9 +885,9 @@ def apply_table_body(
     phred_table, lmax: int,
 ):
     """Traceable body of the table application (shared by the plain
-    kernel, the fused apply+pack kernel below, and the mesh shard_map
-    bodies — ONE copy of the math, so every path is bitwise the same
-    gather)."""
+    kernel, the fused apply+pack2 kernel below, the mesh shard_map
+    bodies and the coalescer's fused grid — ONE copy of the math, so
+    every path is bitwise the same gather)."""
     n_rg = phred_table.shape[0]
     gl = (phred_table.shape[2] - 1) // 2
     rg = jnp.where(read_group_idx >= 0, read_group_idx, n_rg - 1).astype(jnp.int32)
@@ -995,39 +925,6 @@ def apply_table_kernel(
     )
 
 
-def apply_pack_body(
-    bases, quals, lengths, flags, read_group_idx, has_qual, valid,
-    phred_table, lmax: int, size: int,
-):
-    """Traceable fused apply + column pack: the table gather of
-    :func:`apply_table_body` followed by the on-device SANGER encode
-    and row-prefix pack (:mod:`adam_tpu.ops.colpack`) — the encode-ready
-    payload the pass-C fetch ships instead of the [N, L] matrix."""
-    from adam_tpu.ops.colpack import pack_rows_body, sanger_body
-
-    new_q = apply_table_body(
-        bases, quals, lengths, flags, read_group_idx, has_qual, valid,
-        phred_table, lmax,
-    )
-    pack_lens = jnp.where(valid & has_qual, lengths, 0)
-    return pack_rows_body(sanger_body(new_q), pack_lens, size)
-
-
-def apply_pack_kernel(
-    bases, quals, lengths, flags, read_group_idx, has_qual, valid,
-    phred_table, lmax: int, size: int,
-):
-    """Jit entry point over :func:`apply_pack_body` (the pool path's
-    pass-C dispatch when packed columns are on; the mesh path fuses the
-    same body per shard in ``parallel/partitioner``).  ``size`` is the
-    window's dense grid area — static per (g, gl), so the packed
-    variant adds no compile-cache shapes."""
-    return jit_variant("apply_pack", False)(
-        bases, quals, lengths, flags, read_group_idx, has_qual, valid,
-        phred_table, lmax, size,
-    )
-
-
 def apply_pack2_body(
     bases, quals, lengths, flags, read_group_idx, has_qual, valid,
     phred_table, lmax: int, size: int,
@@ -1060,9 +957,8 @@ def apply_pack2_kernel(
     bases, quals, lengths, flags, read_group_idx, has_qual, valid,
     phred_table, lmax: int, size: int,
 ):
-    """Jit entry point over :func:`apply_pack2_body` (the
-    resident-window pass-C dispatch when packed columns are on; the
-    donating twin lives in :func:`jit_variant`)."""
+    """Jit entry point over :func:`apply_pack2_body` (the streamed
+    pass-C dispatch; the donating twin lives in :func:`jit_variant`)."""
     return jit_variant("apply_pack2", False)(
         bases, quals, lengths, flags, read_group_idx, has_qual, valid,
         phred_table, lmax, size,
@@ -1237,40 +1133,34 @@ def recalibrate_base_qualities(
 
 def apply_recalibration_dispatch(
     ds: AlignmentDataset, phred_table: np.ndarray, gl: int,
-    backend: Optional[str] = None, device=None, mesh=None,
-    pack: bool = False, resident=None,
+    backend: Optional[str] = None, device=None, mesh=None, resident=None,
 ):
     """Start the per-residue table application for one window -> opaque
-    handle for :func:`apply_recalibration_finish`.
+    handle for :func:`apply_recalibration_finish_packed`, or ``None``
+    when a donating attempt consumed ``resident`` and failed (the caller
+    places a fresh handle and dispatches again).
 
-    On the ``device`` backend this ships the window's [N, L] bases/quals
-    and *dispatches* the gather kernel without blocking — the streamed
-    pipeline double-buffers: window i's result is fetched (and its part
-    encoded) while window i+1's gather runs on the chip.  ``device``
-    commits the inputs to an explicit chip (multi-chip round-robin);
-    ``phred_table`` may be a device-resident array (the pool replicates
-    the solved table once per device instead of re-shipping it per
-    window; under ``mesh`` it is the replicated placement from
-    ``MeshPartitioner.put_replicated`` — placed once, resident for the
-    whole pass).  The other backends compute eagerly and the handle is
-    just the result.
+    On the ``device`` backend with a ``resident`` handle (a
+    ``device_pool.ResidentWindow`` — the streamed pass C) this
+    *dispatches* the fused apply + bases+quals pack
+    (:func:`apply_pack2_body`) without blocking: the five row arrays come
+    from the handle, only the post-split ``has_qual``/``valid`` bools
+    ship, and the handle finishes as ``(ds, io.arrow_pack.PackedColumns)``
+    — both flat encode-ready columns, ``sum(lengths)`` bytes each, never
+    the [N, L] matrix.  The streamed pipeline double-buffers: window i's
+    result is fetched (and its part encoded) while window i+1's gather
+    runs on the chip.  ``device`` is the handle's pool chip; ``mesh`` (a
+    ``MeshPartitioner``, with a mesh-sharded handle) runs the same body
+    per shard.  ``phred_table`` may be a device-resident array (the pool
+    replicates the solved table once per device; under ``mesh`` it is
+    the replicated placement from ``MeshPartitioner.put_replicated``).
+    Where the runtime honors donation the handle's quals/bases buffers
+    become the packed outputs.
 
-    ``pack=True`` (device/mesh backends only) dispatches the fused
-    apply+pack kernel instead: the handle's payload is the window's
-    flat SANGER-encoded qual column (``ops/colpack``), fetched by
-    :func:`apply_recalibration_finish_packed` as ``sum(lengths)``
-    bytes — the pass-C d2h fetch ships the encode-ready column, never
-    the [N, L] matrix.
-
-    ``resident`` (a ``device_pool.ResidentWindow``) dispatches off the
-    window's ingest-resident arrays — only the post-split ``has_qual``/
-    ``valid`` bools ship — and with ``pack=True`` upgrades to the fused
-    bases+quals pack (``apply_pack2_kernel``): BOTH flat encode-ready
-    columns come home and the handle finishes as
-    ``(ds, io.arrow_pack.PackedColumns)``.  Where the runtime honors
-    donation the resident quals/bases buffers become the packed
-    outputs.  A dead handle falls back to the non-resident dispatch,
-    byte-identically."""
+    Without a handle, the ``device`` backend runs the in-memory API's
+    single-device leg (:func:`apply_table_kernel`, finishing as a
+    dataset); the other backends compute eagerly and the handle is just
+    the result."""
     backend = bqsr_backend(backend)
     from adam_tpu.parallel.device_pool import span_attrs
 
@@ -1279,7 +1169,7 @@ def apply_recalibration_dispatch(
         _tele.SPAN_BQSR_APPLY_DISPATCH, backend=backend, **attrs,
     ):
         return _apply_dispatch_impl(
-            ds, phred_table, gl, backend, device, mesh, pack, resident
+            ds, phred_table, gl, backend, device, mesh, resident
         )
 
 
@@ -1402,7 +1292,7 @@ def fused_bc_dispatch(
                 faults.point("device.dispatch")
                 if not rw.alive:
                     # donated shards died under a half-run attempt:
-                    # the caller re-runs the separate passes host-ship
+                    # the caller runs the separate passes instead
                     return None
                 try:
                     total, mism, pq, pb = mesh.fused_bc_window(
@@ -1481,308 +1371,136 @@ def fused_bc_dispatch(
 
 def _apply_dispatch_impl(
     ds: AlignmentDataset, phred_table, gl: int, backend: str, device=None,
-    mesh=None, pack: bool = False, resident=None,
+    mesh=None, resident=None,
 ):
     b = ds.batch.to_numpy()
     count_apply_residues(b)
-    if backend == "device" and mesh is not None:
-        from adam_tpu.formats.batch import grid_cols, grid_rows, pad_rows_np
-        from adam_tpu.utils import compile_ledger, faults
-        from adam_tpu.utils import retry as _retry
+    if backend != "device":
+        from adam_tpu import native
 
-        n = b.n_rows
-        L = b.lmax
-        gm = mesh.rows_for(grid_rows(n))
-        glc = grid_cols(L)
-        n_rg = phred_table.shape[0]
-        n_cyc = phred_table.shape[2]
-        rw = resident
-        if rw is not None and not (
-            rw.alive and rw.device == "mesh"
-            and rw.g == gm and rw.gl == glc
-        ):
-            rw = None
-        if rw is not None:
-            hq_pad = pad_rows_np(b.has_qual, gm, False)
-            vd_pad = pad_rows_np(b.valid, gm, False)
-            if pack:
-                # the bases half: both flat columns come home, each
-                # split into per-shard exact payload slices
-                pack_lens_q = _apply_pack_lens(b)
-                pack_lens_b = _apply_pack_lens_bases(b)
-                lens_q_pad = pad_rows_np(pack_lens_q, gm, 0)
-                lens_b_pad = pad_rows_np(pack_lens_b, gm, 0)
-
-                def dispatch_mesh_pack2():
-                    faults.point("device.dispatch")
-                    if not rw.alive:
-                        # donated buffers died under a half-run attempt:
-                        # re-ship the quals-only pack from the host copy
-                        return None
-                    try:
-                        pq, pb = mesh.apply_pack2_window(
-                            rw, hq_pad, vd_pad, phred_table, glc
-                        )
-                    except BaseException:
-                        if mesh.apply_supports_donation():
-                            # the donating collective may have consumed
-                            # the resident shards mid-failure: the
-                            # handle must never offer them again
-                            rw.mark_consumed()
-                        raise
-                    if mesh.apply_supports_donation():
-                        rw.mark_consumed()
-                    return (
-                        mesh.packed_payload_slices(pq, lens_q_pad, glc),
-                        mesh.packed_payload_slices(pb, lens_b_pad, glc),
-                    )
-
-                with compile_ledger.track(
-                    ("mesh.apply_pack2", gm, glc, n_rg, n_cyc),
-                    mesh.ledger_key(),
-                ):
-                    got = _retry.retry_call(
-                        dispatch_mesh_pack2, site="bqsr.apply.dispatch"
-                    )
-                if got is not None:
-                    q_slices, b_slices = got
-                    return ds, b, ("packed2", q_slices, pack_lens_q,
-                                   b_slices, pack_lens_b)
-                rw = None  # handle died: fall through to the re-ship
-            else:
-                def dispatch_mesh_resident():
-                    faults.point("device.dispatch")
-                    if not rw.alive:
-                        return None
-                    return mesh.apply_window_resident(
-                        rw, hq_pad, vd_pad, phred_table, glc
-                    )[:n, :L]
-
-                with compile_ledger.track(
-                    ("mesh.apply", gm, glc, n_rg, n_cyc),
-                    mesh.ledger_key(),
-                ):
-                    new_dev = _retry.retry_call(
-                        dispatch_mesh_resident, site="bqsr.apply.dispatch"
-                    )
-                if new_dev is not None:
-                    return ds, b, new_dev
-                rw = None
-        args = (
-            # adam-tpu: noqa[residency] reason=non-resident fallback: residency off, a dead handle, or a replay re-ships from the host ingest copy
-            pad_rows_np(b.bases, gm, schema.BASE_PAD, cols=glc),
-            # adam-tpu: noqa[residency] reason=non-resident fallback: residency off, a dead handle, or a replay re-ships from the host ingest copy
-            pad_rows_np(b.quals, gm, schema.QUAL_PAD, cols=glc),
-            pad_rows_np(b.lengths, gm, 0),
-            pad_rows_np(b.flags, gm, schema.FLAG_UNMAPPED),
-            pad_rows_np(b.read_group_idx, gm, -1),
-            pad_rows_np(b.has_qual, gm, False),
-            pad_rows_np(b.valid, gm, False),
-        )
-        if pack:
-            pack_lens = _apply_pack_lens(b)
-
-            def dispatch_mesh_pack():
-                faults.point("device.dispatch")
-                packed = mesh.apply_pack_window(args, phred_table, glc)
-                # per-shard exact payload slices: shard k's segment of
-                # the flat output holds exactly its rows' packed bytes
-                # at the segment start (host-known lengths -> host-known
-                # split; nothing but real column bytes ever fetches)
-                return mesh.packed_payload_slices(
-                    packed, pad_rows_np(pack_lens, gm, 0), glc
-                )
-
-            with compile_ledger.track(
-                ("mesh.apply_pack", gm, glc, n_rg, n_cyc),
-                mesh.ledger_key(),
-            ):
-                slices = _retry.retry_call(
-                    dispatch_mesh_pack, site="bqsr.apply.dispatch"
-                )
-            return ds, b, ("packed", slices, pack_lens)
-
-        def dispatch_mesh():
-            faults.point("device.dispatch")
-            return mesh.apply_window(args, phred_table, glc)[:n, :L]
-
-        with compile_ledger.track(
-            ("mesh.apply", gm, glc, n_rg, n_cyc), mesh.ledger_key()
-        ):
-            new_dev = _retry.retry_call(
-                dispatch_mesh, site="bqsr.apply.dispatch"
+        new_quals = None
+        if backend == "native":
+            new_quals = native.bqsr_apply(
+                b.bases, np.asarray(b.quals), b.lengths, b.flags,
+                b.read_group_idx, b.has_qual, b.valid, phred_table, gl,
             )
-        return ds, b, new_dev
-    if backend == "device":
-        from adam_tpu.formats.batch import grid_cols, grid_rows, pad_rows_np
+        if new_quals is None:
+            new_quals = _apply_table_np(b, phred_table, gl)
+        return ds, b, new_quals
+    from adam_tpu.formats.batch import grid_cols, grid_rows, pad_rows_np
+    from adam_tpu.ops.colpack import fetch_grid
+    from adam_tpu.parallel.device_pool import (
+        donation_ok, putter, resident_arrays,
+    )
+    from adam_tpu.utils import compile_ledger, faults
+    from adam_tpu.utils import retry as _retry
 
-        n = b.n_rows
-        L = b.lmax
+    n = b.n_rows
+    glc = grid_cols(b.lmax)
+    n_rg = phred_table.shape[0]
+    n_cyc = phred_table.shape[2]
+    _put = putter(device)
+
+    def _placed_table():
+        if isinstance(phred_table, np.ndarray):
+            return _put(np.ascontiguousarray(phred_table, np.uint8))
+        return phred_table  # device-resident (pool-replicated)
+
+    rw = resident
+    if rw is None:
+        if mesh is not None:
+            raise ValueError("the mesh apply dispatches on a resident "
+                             "window handle")
+        # the in-memory API's single-device leg: the plain gather over
+        # this call's own placement, fetched as the [N, L] matrix
         g = grid_rows(n)
-        glc = grid_cols(L)
-        from adam_tpu.parallel.device_pool import donation_ok, putter
-        from adam_tpu.utils import compile_ledger, faults
-        from adam_tpu.utils import retry as _retry
-
-        _put = putter(device)
-        n_rg = phred_table.shape[0]
-        n_cyc = phred_table.shape[2]
-
-        def _placed_table():
-            if isinstance(phred_table, np.ndarray):
-                return _put(np.ascontiguousarray(phred_table, np.uint8))
-            return phred_table  # device-resident (pool-replicated)
-
-        rw = resident
-        if rw is not None and not (
-            rw.alive and rw.device is device and rw.g == g and rw.gl == glc
-        ):
-            rw = None
-        if rw is not None:
-            from adam_tpu.ops.colpack import fetch_grid
-
-            hq_pad = pad_rows_np(b.has_qual, g, False)
-            vd_pad = pad_rows_np(b.valid, g, False)
-            if pack:
-                # the bases half of the packed tail: one fused dispatch
-                # emits BOTH flat encode-ready columns off the resident
-                # arrays; the fetch ships sum(lengths) bytes each
-                pack_lens_q = _apply_pack_lens(b)
-                pack_lens_b = _apply_pack_lens_bases(b)
-                total_q = int(pack_lens_q.sum())
-                total_b = int(pack_lens_b.sum())
-                cut_q = min(g * glc, fetch_grid(total_q))
-                cut_b = min(g * glc, fetch_grid(total_b))
-
-                def dispatch_pack2():
-                    faults.point("device.dispatch", device=device)
-                    if not rw.alive:
-                        # donated buffers died under a half-run
-                        # attempt: re-ship through the fallback below
-                        return None
-                    donate = donation_ok(device)
-                    try:
-                        pq, pb = jit_variant("apply_pack2", donate)(
-                            *rw.args(), _put(hq_pad), _put(vd_pad),
-                            _placed_table(), glc, g * glc,
-                        )
-                    except BaseException:
-                        if donate:
-                            rw.mark_consumed()
-                        raise
-                    if donate:
-                        rw.mark_consumed()
-                    return pq[:cut_q], pb[:cut_b]
-
-                # ledger key == _apply_entry's resident pack2 key
-                with compile_ledger.track(
-                    ("bqsr.apply_pack2", g, glc, n_rg, n_cyc), device
-                ):
-                    got = _retry.retry_call(
-                        dispatch_pack2, site="bqsr.apply.dispatch"
-                    )
-                if got is not None:
-                    return ds, b, (
-                        "packed2", [(got[0], total_q)], pack_lens_q,
-                        [(got[1], total_b)], pack_lens_b,
-                    )
-                rw = None  # handle died: fall through to the re-ship
-            else:
-                def dispatch_resident():
-                    faults.point("device.dispatch", device=device)
-                    if not rw.alive:
-                        return None
-                    donate = donation_ok(device)
-                    try:
-                        out = jit_variant("apply", donate)(
-                            *rw.args(), _put(hq_pad), _put(vd_pad),
-                            _placed_table(), glc,
-                        )
-                    except BaseException:
-                        if donate:
-                            rw.mark_consumed()
-                        raise
-                    if donate:
-                        rw.mark_consumed()
-                    return out[:n, :L]
-
-                with compile_ledger.track(
-                    ("bqsr.apply", g, glc, n_rg, n_cyc), device
-                ):
-                    new_dev = _retry.retry_call(
-                        dispatch_resident, site="bqsr.apply.dispatch"
-                    )
-                if new_dev is not None:
-                    return ds, b, new_dev
-                rw = None
-
-        def _placed_args():
-            return (
-                # adam-tpu: noqa[residency] reason=non-resident fallback: residency off, a dead handle, or a replay re-ships from the host ingest copy
-                _put(pad_rows_np(b.bases, g, schema.BASE_PAD, cols=glc)),
-                # adam-tpu: noqa[residency] reason=non-resident fallback: residency off, a dead handle, or a replay re-ships from the host ingest copy
-                _put(pad_rows_np(b.quals, g, schema.QUAL_PAD, cols=glc)),
-                _put(pad_rows_np(b.lengths, g, 0)),
-                _put(pad_rows_np(b.flags, g, schema.FLAG_UNMAPPED)),
-                _put(pad_rows_np(b.read_group_idx, g, -1)),
-                _put(pad_rows_np(b.has_qual, g, False)),
-                _put(pad_rows_np(b.valid, g, False)),
-                _placed_table(),
-            )
-
-        if pack:
-            from adam_tpu.ops.colpack import fetch_grid
-
-            pack_lens = _apply_pack_lens(b)
-            total = int(pack_lens.sum())
-            # bucketed device-side slice (over-fetch < 6.25%, host
-            # trims): an exact per-window size would compile one slice
-            # program per window
-            cut = min(g * glc, fetch_grid(total))
-
-            def dispatch_pack():
-                faults.point("device.dispatch", device=device)
-                packed = apply_pack_kernel(*_placed_args(), glc, g * glc)
-                return packed[:cut]
-
-            # ledger key == apply_pack_prewarm_entry's key (the pass-C
-            # re-warm compiles the fused kernel at the solved width)
-            with compile_ledger.track(
-                ("bqsr.apply_pack", g, glc, n_rg, n_cyc), device
-            ):
-                packed_dev = _retry.retry_call(
-                    dispatch_pack, site="bqsr.apply.dispatch"
-                )
-            return ds, b, ("packed", [(packed_dev, total)], pack_lens)
 
         def dispatch():
             faults.point("device.dispatch", device=device)
+            rows, _nbytes = resident_arrays(b, g, glc, _put)
             return apply_table_kernel(
-                *_placed_args(), glc,
-            )[:n, :L]  # device-side slice: fetch only real rows/lanes
+                *rows, _put(pad_rows_np(b.has_qual, g, False)),
+                _put(pad_rows_np(b.valid, g, False)), _placed_table(),
+                glc,
+            )[:n, :b.lmax]  # device-side slice: fetch only real cells
 
-        # ledger key == the prewarm/apply_prewarm_entry key: the pass-C
-        # re-warm compiles against the SOLVED table's width, and an
-        # in-window miss here is exactly the "wider merged table"
-        # coverage gap PERF.md describes
-        with compile_ledger.track(
-            ("bqsr.apply", g, glc, n_rg, n_cyc), device
-        ):
-            new_dev = _retry.retry_call(
-                dispatch, site="bqsr.apply.dispatch"
-            )
+        # adam-tpu: noqa[dispatch-ledger] reason=the in-memory API's single-device leg: no streamed pass dispatches it, so no prewarm entry builds its key
+        with compile_ledger.track(("bqsr.apply", g, glc, n_rg, n_cyc), device):
+            new_dev = _retry.retry_call(dispatch, site="bqsr.apply.dispatch")
         return ds, b, new_dev
-    from adam_tpu import native
 
-    new_quals = None
-    if backend == "native":
-        new_quals = native.bqsr_apply(
-            b.bases, np.asarray(b.quals), b.lengths, b.flags,
-            b.read_group_idx, b.has_qual, b.valid, phred_table, gl,
-        )
-    if new_quals is None:
-        new_quals = _apply_table_np(b, phred_table, gl)
-    return ds, b, new_quals
+    # the streamed leg: both flat columns come home off the resident
+    # arrays, each fetched as its exact payload
+    hq_pad = pad_rows_np(b.has_qual, rw.g, False)
+    vd_pad = pad_rows_np(b.valid, rw.g, False)
+    pack_lens_q = _apply_pack_lens(b)
+    pack_lens_b = _apply_pack_lens_bases(b)
+    if mesh is not None:
+        lens_q_pad = pad_rows_np(pack_lens_q, rw.g, 0)
+        lens_b_pad = pad_rows_np(pack_lens_b, rw.g, 0)
+        donate = mesh.apply_supports_donation()
+
+        def dispatch_mesh_pack2():
+            faults.point("device.dispatch")
+            if not rw.alive:
+                return None  # consumed by a half-run donating attempt
+            try:
+                pq, pb = mesh.apply_pack2_window(
+                    rw, hq_pad, vd_pad, phred_table, glc
+                )
+            finally:
+                # the donating collective consumes the resident shards,
+                # on success and (possibly) mid-failure alike: the handle
+                # must never offer them again
+                if donate:
+                    rw.mark_consumed()
+            return (
+                mesh.packed_payload_slices(pq, lens_q_pad, glc),
+                mesh.packed_payload_slices(pb, lens_b_pad, glc),
+            )
+
+        with compile_ledger.track(
+            ("mesh.apply_pack2", rw.g, glc, n_rg, n_cyc), mesh.ledger_key(),
+        ):
+            got = _retry.retry_call(
+                dispatch_mesh_pack2, site="bqsr.apply.dispatch"
+            )
+    else:
+        g = rw.g
+        total_q = int(pack_lens_q.sum())
+        total_b = int(pack_lens_b.sum())
+        # bucketed device-side slices (over-fetch < 6.25%, the fetch
+        # trims): an exact per-window size would compile one slice
+        # program per window
+        cut_q = min(g * glc, fetch_grid(total_q))
+        cut_b = min(g * glc, fetch_grid(total_b))
+        donate = donation_ok(device)
+
+        def dispatch_pack2():
+            faults.point("device.dispatch", device=device)
+            if not rw.alive:
+                return None  # consumed by a half-run donating attempt
+            try:
+                pq, pb = jit_variant("apply_pack2", donate)(
+                    *rw.args(), _put(hq_pad), _put(vd_pad),
+                    _placed_table(), glc, g * glc,
+                )
+            finally:
+                if donate:
+                    rw.mark_consumed()
+            return [(pq[:cut_q], total_q)], [(pb[:cut_b], total_b)]
+
+        # ledger key == apply_prewarm_entry's key
+        with compile_ledger.track(
+            ("bqsr.apply_pack2", g, glc, n_rg, n_cyc), device
+        ):
+            got = _retry.retry_call(
+                dispatch_pack2, site="bqsr.apply.dispatch"
+            )
+    if got is None:
+        return None
+    q_slices, b_slices = got
+    return ds, b, ("packed2", q_slices, pack_lens_q, b_slices, pack_lens_b)
 
 
 def apply_handle_dataset(handle) -> AlignmentDataset:
@@ -1815,15 +1533,14 @@ def apply_recalibration_finish(handle) -> AlignmentDataset:
 def apply_recalibration_finish_packed(handle):
     """Finish one dispatched window -> ``(dataset, PackedQuals | None)``.
 
-    A ``pack=True`` handle fetches the flat encode-ready qual payload —
-    ``sum(lengths)`` bytes, one slice per resident shard — and returns
-    it beside the dataset (whose batch keeps its PRE-recalibration
-    quals: the OQ stash is the only remaining consumer of the matrix,
-    and the writer encodes the qual column straight off the packed
-    buffer).  A resident-window ``packed2`` handle additionally fetches
-    the flat base column (the bases half of the packed tail) and
-    returns a :class:`~adam_tpu.io.arrow_pack.PackedColumns` carrying
-    both.  A plain handle behaves exactly like
+    A streamed ``packed2`` handle fetches both flat encode-ready
+    columns — ``sum(lengths)`` bytes each, one slice per resident shard
+    — and returns them as a :class:`~adam_tpu.io.arrow_pack.PackedColumns`
+    beside the dataset (whose batch keeps its PRE-recalibration quals:
+    the OQ stash is the only remaining consumer of the matrix, and the
+    writer encodes both columns straight off the packed buffers).  The
+    coalescer's ``packed`` handle carries the qual column only and
+    returns a ``PackedQuals``.  A plain handle behaves exactly like
     :func:`apply_recalibration_finish` and returns ``packed=None``."""
     from adam_tpu.io.arrow_pack import PackedColumns, PackedQuals
     from adam_tpu.utils.transfer import device_fetch

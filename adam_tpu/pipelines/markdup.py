@@ -69,12 +69,11 @@ def get_columns_jit(donate: bool = False):
     so both hit the same executable cache).  Locked: the prewarm calls
     this from one thread per device, and a lost race here would warm a
     discarded wrapper whose executable cache the real dispatches never
-    see.  ``donate=True`` is the resident-window variant: with quals/
-    lengths/flags read from the window's ingest-resident arrays, the
-    per-pass ``start`` temporary (i64[g], the only shipped input whose
-    buffer the i64[g] ``five`` output can alias) is donated —
-    dispatched only where ``device_pool.donation_ok`` says the runtime
-    honors it, and warmed by the same decision."""
+    see.  ``donate=True`` donates the per-call ``start`` temporary
+    (i64[g], the only input whose buffer the i64[g] ``five`` output can
+    alias; quals/lengths/flags come from the window's device handle and
+    stay) — dispatched only where ``device_pool.donation_ok`` says the
+    runtime honors it, and warmed by the same decision."""
     key = bool(donate)
     jit = _COLUMNS_JITS.get(key)
     if jit is None:
@@ -100,22 +99,23 @@ def markdup_columns_dispatch(batch, device=None, mesh=None, resident=None):
     window i's columns are being fetched/summarized (double buffer).
     ``device``: an explicit jax device to commit the inputs to (the
     multi-chip pool's round-robin target); ``None`` keeps the default
-    device, exactly the single-chip behavior.  ``mesh``: a
-    :class:`~adam_tpu.parallel.partitioner.MeshPartitioner` — the
-    [N, L] arrays shard over its ``batch`` axis and every device works
-    the same window (SPMD), bitwise the single-chip columns.
-    ``resident``: the window's ingest-resident device payload
-    (``device_pool.ResidentWindow``) — quals/lengths/flags dispatch
+    device, exactly the single-chip behavior.  ``resident``: the
+    window's device payload (``device_pool.ResidentWindow``, pinned at
+    ingest or placed for the pass) — quals/lengths/flags dispatch
     straight off the handle and only the markdup-specific start/end/
-    cigar columns ship; a dead or mismatched handle falls back to the
-    legacy re-ship below, bitwise the same columns."""
+    cigar columns ship.  ``mesh``: a
+    :class:`~adam_tpu.parallel.partitioner.MeshPartitioner` with a
+    mesh-sharded ``resident`` — the window shards over its ``batch``
+    axis and every device works the same window (SPMD), bitwise the
+    single-chip columns.  Without a handle (the in-memory API) the call
+    places the row arrays itself."""
     from adam_tpu.formats.batch import (
         grid_cigar_cols, grid_cols, grid_rows, pad_rows_np,
     )
     from adam_tpu.parallel.device_pool import (
-        donation_ok, putter, span_attrs,
+        donation_ok, putter, resident_arrays, span_attrs,
     )
-    from adam_tpu.utils import faults
+    from adam_tpu.utils import compile_ledger, faults
     from adam_tpu.utils import retry as _retry
     from adam_tpu.utils import telemetry as _tele
 
@@ -127,7 +127,6 @@ def markdup_columns_dispatch(batch, device=None, mesh=None, resident=None):
     ):
         b = batch.to_numpy()
         n = b.n_rows
-        g = grid_rows(n)
         # quantize BOTH axes, not just rows: windows differ in lmax and
         # max cigar-op count, and every distinct shape is a fresh
         # trace+compile serialized inside pass A's ingest loop (the
@@ -136,80 +135,50 @@ def markdup_columns_dispatch(batch, device=None, mesh=None, resident=None):
         gc = grid_cigar_cols(
             b.cigar_ops.shape[1] if b.cigar_ops.ndim == 2 else 1
         )
+        rw = resident
+        g = rw.g if rw is not None else grid_rows(n)
+
+        def fresh():
+            return (
+                pad_rows_np(b.start, g, -1),
+                pad_rows_np(b.end, g, -1),
+                pad_rows_np(b.cigar_ops, g, schema.CIGAR_PAD, cols=gc),
+                pad_rows_np(b.cigar_lens, g, 0, cols=gc),
+                pad_rows_np(b.cigar_n, g, 0),
+            )
 
         if mesh is not None:
-            from adam_tpu.utils import compile_ledger
-
-            gm = mesh.rows_for(g)
-            rw = resident
-            if rw is not None and not (
-                rw.alive and rw.device == "mesh"
-                and rw.g == gm and rw.gl == gl
-            ):
-                rw = None
+            if rw is None:
+                raise ValueError("the mesh markdup dispatches on a "
+                                 "resident window handle")
 
             def dispatch_mesh():
                 faults.point("device.dispatch")
-                fresh = (
-                    pad_rows_np(b.start, gm, -1),
-                    pad_rows_np(b.end, gm, -1),
-                    pad_rows_np(b.cigar_ops, gm, schema.CIGAR_PAD,
-                                cols=gc),
-                    pad_rows_np(b.cigar_lens, gm, 0, cols=gc),
-                    pad_rows_np(b.cigar_n, gm, 0),
-                )
-                if rw is not None and rw.alive:
-                    return mesh.markdup_window_resident(rw, fresh)
-                return mesh.markdup_window((
-                    fresh[0], fresh[1],
-                    pad_rows_np(b.flags, gm, schema.FLAG_UNMAPPED),
-                    fresh[2], fresh[3], fresh[4],
-                    # adam-tpu: noqa[residency] reason=non-resident fallback: residency off, a dead handle, or a replay re-ships from the host ingest copy
-                    pad_rows_np(b.quals, gm, schema.QUAL_PAD, cols=gl),
-                    pad_rows_np(b.lengths, gm, 0),
-                ))
+                return mesh.markdup_window_resident(rw, fresh())
 
             with compile_ledger.track(
-                ("mesh.markdup", gm, gc, gl), mesh.ledger_key()
+                ("mesh.markdup", g, gc, gl), mesh.ledger_key()
             ):
                 five, score = _retry.retry_call(
                     dispatch_mesh, site="markdup.dispatch"
                 )
             return five[:n], score[:n]
 
-        rw = resident
-        if rw is not None and not (
-            rw.alive and rw.device is device and rw.g == g and rw.gl == gl
-        ):
-            rw = None
-
         def dispatch():
             # the device_put + jit call re-run as one unit on a retry
-            # (device_put is idempotent — a fresh commit; the
-            # donated start temporary is re-placed every attempt, so a
-            # half-run donating call can never re-pass a dead buffer)
+            # (device_put is idempotent — a fresh commit; the donated
+            # start temporary is re-placed every attempt, so a half-run
+            # donating call can never re-pass a dead buffer)
             faults.point("device.dispatch", device=device)
-            start = _put(pad_rows_np(b.start, g, -1))
-            end = _put(pad_rows_np(b.end, g, -1))
-            ops = _put(pad_rows_np(b.cigar_ops, g, schema.CIGAR_PAD,
-                                   cols=gc))
-            lens = _put(pad_rows_np(b.cigar_lens, g, 0, cols=gc))
-            n_ops = _put(pad_rows_np(b.cigar_n, g, 0))
-            if rw is not None and rw.alive:
-                return get_columns_jit(donate=donation_ok(device))(
-                    start, end, rw.get("flags"), ops, lens, n_ops,
-                    rw.get("quals"), rw.get("lengths"),
-                )
-            return get_columns_jit()(
-                start, end,
-                _put(pad_rows_np(b.flags, g, schema.FLAG_UNMAPPED)),
-                ops, lens, n_ops,
-                # adam-tpu: noqa[residency] reason=non-resident fallback: residency off, a dead handle, or a replay re-ships from the host ingest copy
-                _put(pad_rows_np(b.quals, g, schema.QUAL_PAD, cols=gl)),
-                _put(pad_rows_np(b.lengths, g, 0)),
+            start, end, ops, lens, n_ops = (_put(a) for a in fresh())
+            rows = (
+                rw.args() if rw is not None
+                else resident_arrays(b, g, gl, _put)[0]
             )
-
-        from adam_tpu.utils import compile_ledger
+            _bases, quals, lengths, flags, _rg = rows
+            return get_columns_jit(donate=donation_ok(device))(
+                start, end, flags, ops, lens, n_ops, quals, lengths,
+            )
 
         # compile-ledger key == the prewarm entry key for this kernel:
         # a miss here is a shape the prewarm never covered, cold-

@@ -62,6 +62,7 @@ should be doing.
 
 from __future__ import annotations
 
+import contextlib
 import hashlib
 import logging
 import os
@@ -532,27 +533,14 @@ def _transform_streamed_impl(
         "mode": "mesh" if mesh_part is not None else "pool",
     }
     stats["partitioner"] = exec_state["mode"]
-    # pass-C packed-column fetch (ADAM_TPU_PACKED_COLS, default on for
-    # the device backend): the apply kernel emits the flat encode-ready
-    # SANGER qual payload on device and the d2h fetch ships
-    # sum(lengths) bytes instead of the [N, L] matrix; the writer pool
-    # assembles the arrow column zero-copy over the fetched buffer
-    # (io/arrow_pack).  Host/degraded windows fall back to the matrix
-    # path, byte-identically.
-    from adam_tpu.ops.colpack import packed_columns_enabled
-
-    use_packed = use_device and packed_columns_enabled()
-    stats["packed_columns"] = use_packed
-    # device-resident windows (ADAM_TPU_RESIDENT, default on for the
-    # device backend; docs/PERF.md "Device-resident windows"): each
-    # window's bases/quals/lengths/flags/rg land on device ONCE at
-    # ingest — pinned per pool device, or as one mesh-sharded placement
-    # — and every pass dispatches against the handle; the later passes
-    # ship only their per-pass inputs (bit-packed MD masks, post-split
-    # validity bools), so the ledger's per-pass h2d collapses to the
-    # one ingest entry.  With packed columns on, pass C upgrades to the
-    # fused bases+quals pack (the bases half of the packed tail).
-    use_resident = use_device and dp_mod.resident_windows_enabled()
+    # device-resident windows (docs/PERF.md "Device-resident windows"):
+    # on the device backend every markdup/observe/apply dispatch takes
+    # a ResidentWindow — pinned at ingest (per pool device, or one
+    # mesh-sharded placement) and kept until its part submits, or
+    # placed transient for one pass where the target holds no live
+    # handle.  The later passes ship only their per-pass inputs
+    # (bit-packed MD masks, post-split validity bools), and pass C
+    # fetches both columns packed (the fused bases+quals pack2).
     stats["resident_windows"] = 0
     # cross-job window batching (serve/batching.py): the scheduler's
     # coalescer client merges this job's per-window dispatches with its
@@ -622,51 +610,91 @@ def _transform_streamed_impl(
         return "native" if native.available() else "numpy"
 
     # ---- device-resident windows: handle registry + lifecycle ----------
-    # window index -> ResidentWindow; the live-bytes ledger backs the
+    # window index -> its handle pinned at ingest; ``transients`` holds
+    # the per-pass handles in flight.  The live-bytes ledger backs the
     # no-HBM-growth invariant (gauge returns to 0 as pass C releases)
     resident_map: dict = {}
+    transients: set = set()
     resident_live = {"bytes": 0, "made": 0}
 
-    def _make_resident(win, ds):
-        """Place window ``win``'s resident payload at ingest (pinned on
-        its round-robin pool device, or mesh-sharded).  Best-effort: a
-        failed placement just leaves the window on the legacy
-        re-ship-per-pass path."""
-        if not use_resident or res["device_lost"]:
-            return
-        b = ds.batch.to_numpy()
-        mp = exec_state["mesh"]
-        try:
-            # the one per-window h2d: attributed to the ledger's
-            # ``ingest`` bucket, which the analyzer's residency verdict
-            # compares against the (≈0) observe/apply buckets
-            with tele.pass_scope("ingest"):
-                if mp is not None:
-                    rw = part_mod.mesh_resident_window(b, win, mp)
-                else:
-                    dev = _pick_device(win)
-                    if dev is _HOST:
-                        return
-                    rw = dp_mod.make_resident_window(b, win, dev)
-        except Exception as e:
-            log.warning(
-                "resident placement of window %d failed (%s); the "
-                "window re-ships per pass", win, e,
-            )
-            return
-        resident_map[win] = rw
+    def _placed(rw):
         resident_live["bytes"] += rw.nbytes
         resident_live["made"] += 1
         tr.count(tele.C_RESIDENT_WINDOWS)
         tr.count(tele.C_RESIDENT_BYTES, rw.nbytes)
         tr.gauge(tele.G_RESIDENT_LIVE, resident_live["bytes"])
 
-    def _release_resident(win, drop=False):
-        """Release window ``win``'s handle (the refcounted base ref —
-        after its pass-C fetch, or at the skip/fault sites); ``drop``
-        marks a fault-path drop (eviction, degrade) for the counters."""
-        rw = resident_map.pop(win, None)
-        if rw is None:
+    def _place(win, batch, target):
+        b = batch.to_numpy()
+        if isinstance(target, part_mod.MeshPartitioner):
+            return part_mod.mesh_resident_window(b, win, target)
+        return dp_mod.make_resident_window(b, win, target)
+
+    def _make_resident(win, ds):
+        """Pin window ``win``'s resident payload at ingest (on its
+        round-robin pool device, or mesh-sharded).  Best-effort: after
+        a failed placement each pass places a transient handle."""
+        if not use_device or res["device_lost"]:
+            return
+        target = exec_state["mesh"]
+        if target is None:
+            target = _pick_device(win)
+            if target is _HOST:
+                return
+        try:
+            # the one per-window h2d: attributed to the ledger's
+            # ``ingest`` bucket, which the analyzer's residency verdict
+            # compares against the (≈0) observe/apply buckets
+            with tele.pass_scope("ingest"):
+                rw = _place(win, ds.batch, target)
+        except Exception as e:
+            log.warning(
+                "resident placement of window %d failed (%s); its "
+                "passes place transient handles", win, e,
+            )
+            return
+        resident_map[win] = rw
+        _placed(rw)
+
+    def _acquire(win, batch, target):
+        """THE handle helper every dispatch and recovery site goes
+        through: window ``win``'s pinned handle when it is alive on
+        ``target`` (a pool device, None for the default device, or the
+        live MeshPartitioner), else a transient one placed on
+        ``target`` from the host-retained ingest copy ``batch``."""
+        rw = resident_map.get(win)
+        if rw is not None and rw.alive and (
+            rw.device == "mesh"
+            if isinstance(target, part_mod.MeshPartitioner)
+            else rw.device is target
+        ):
+            return rw
+        rw = _place(win, batch, target)
+        transients.add(rw)
+        _placed(rw)
+        return rw
+
+    @contextlib.contextmanager
+    def _handle_for(win, batch, target):
+        """:func:`_acquire` for one dispatch: a transient handle is
+        released when the block exits (the runtime keeps the buffers of
+        the queued computation alive until it completes)."""
+        rw = _acquire(win, batch, target)
+        try:
+            yield rw
+        finally:
+            if rw in transients:
+                _retire(rw)
+
+    def _retire(rw, drop=False):
+        """Release a handle (pinned: after its pass-C fetch or at the
+        skip sites; transient: after its dispatch); ``drop`` marks a
+        fault-path drop (eviction, degrade) for the counters."""
+        if rw in transients:
+            transients.discard(rw)
+        elif resident_map.get(rw.window) is rw:
+            del resident_map[rw.window]
+        else:
             return
         rw.drop() if drop else rw.release()
         resident_live["bytes"] -= rw.nbytes
@@ -675,16 +703,21 @@ def _transform_streamed_impl(
         )
         tr.gauge(tele.G_RESIDENT_LIVE, resident_live["bytes"])
 
+    def _release_resident(win):
+        rw = resident_map.get(win)
+        if rw is not None:
+            _retire(rw)
+
     def _drop_resident_on(dev):
-        """An evicted device takes its pinned windows with it: their
-        later passes re-ship from the host-retained ingest copy."""
-        for win, rw in list(resident_map.items()):
+        """An evicted device takes its handles with it: the later
+        passes of its windows place transient handles on survivors."""
+        for rw in [*resident_map.values(), *transients]:
             if rw.device is dev:
-                _release_resident(win, drop=True)
+                _retire(rw, drop=True)
 
     def _drop_all_resident():
-        for win in list(resident_map):
-            _release_resident(win, drop=True)
+        for rw in [*resident_map.values(), *transients]:
+            _retire(rw, drop=True)
 
     def _evict_or_lose(dev, exc) -> bool:
         """Evict a failed device; True = survivors remain, False = the
@@ -746,8 +779,8 @@ def _transform_streamed_impl(
         exec_state["mode"] = "pool"
         stats["partitioner"] = "pool"
         # mesh-sharded resident handles die with the mesh: the pool
-        # path takes their windows over by re-shipping from the
-        # host-retained ingest copy (docs/ROBUSTNESS.md)
+        # path takes their windows over on transient handles placed
+        # from the host-retained ingest copy (docs/ROBUSTNESS.md)
         _drop_all_resident()
         # fused B→C outputs sharded over the dying mesh are no longer
         # trustworthy either: forget them, so pass C re-applies those
@@ -827,14 +860,13 @@ def _transform_streamed_impl(
     # With the applied table known BEFORE pass B — a known-sites run, or
     # a -dump_observations resume whose journal already holds the solved
     # table (re-observing only for the merge artifacts) — each eligible
-    # window's observe and apply+pack fuse into one donated dispatch.
-    # Eligibility mirrors the packed2 fast path (device backend + packed
-    # columns + resident windows); the cross-job coalescer owns its own
-    # fusion, so a coalesced run keeps the separate passes.
+    # window's observe and apply+pack fuse into one donated dispatch on
+    # its pinned handle (device backend); the cross-job coalescer owns
+    # its own fusion, so a coalesced run keeps the separate passes.
     fused_table = None
     if (
-        recalibrate and use_device and use_packed and use_resident
-        and coalescer is None and bqsr_mod.fused_bc_enabled()
+        recalibrate and use_device and coalescer is None
+        and bqsr_mod.fused_bc_enabled()
     ):
         if known_table is not None:
             fused_table = (
@@ -905,9 +937,10 @@ def _transform_streamed_impl(
                 )
         if mp is not None:
             try:
-                cols = md_mod.markdup_columns_dispatch(
-                    batch, mesh=mp, resident=resident_map.get(win)
-                )
+                with _handle_for(win, batch, mp) as rw:
+                    cols = md_mod.markdup_columns_dispatch(
+                        batch, mesh=mp, resident=rw
+                    )
                 tr.count(tele.C_DEVICE_DISPATCHED)
                 tr.count(tele.C_MESH_DISPATCHED)
                 return "mesh", cols
@@ -915,11 +948,11 @@ def _transform_streamed_impl(
                 _mesh_degrade(e, "pass-A markdup")
 
         def on_device(dev):
-            # the dispatch validates the handle itself (device match +
-            # aliveness), so a replay on a survivor just re-ships
-            cols = md_mod.markdup_columns_dispatch(
-                batch, device=dev, resident=resident_map.get(win)
-            )
+            # a replay on a survivor places a transient handle there
+            with _handle_for(win, batch, dev) as rw:
+                cols = md_mod.markdup_columns_dispatch(
+                    batch, device=dev, resident=rw
+                )
             tr.count(tele.C_DEVICE_DISPATCHED)
             return dev, cols
 
@@ -1018,14 +1051,10 @@ def _transform_streamed_impl(
                     )
                 if recalibrate:
                     entries.append(
-                        part_mod.mesh_observe_prewarm_entry(b, n_rg, mp)
-                    )
-                    if use_resident:
-                        entries.append(
-                            part_mod.mesh_observe_packed_prewarm_entry(
-                                b, n_rg, mp
-                            )
+                        part_mod.mesh_observe_packed_prewarm_entry(
+                            b, n_rg, mp
                         )
+                    )
                     if fused_table is not None:
                         # the megakernel the fused tier will dispatch,
                         # at the KNOWN table's cycle width
@@ -1044,8 +1073,6 @@ def _transform_streamed_impl(
                     streamed_prewarm_entries(
                         b, n_rg, mark_duplicates=mark_duplicates,
                         recalibrate=recalibrate,
-                        packed_apply=use_packed,
-                        resident=use_resident,
                         fused_n_cyc=(
                             fused_table[0].shape[2]
                             if fused_table is not None else None
@@ -1068,13 +1095,9 @@ def _transform_streamed_impl(
         n_rg = len(ds.read_groups) + 1
         with tr.span(tele.SPAN_POOL_PREWARM):
             if mp is not None:
-                entries = [part_mod.mesh_observe_prewarm_entry(b, n_rg, mp)]
-                if use_resident:
-                    entries.append(
-                        part_mod.mesh_observe_packed_prewarm_entry(
-                            b, n_rg, mp
-                        )
-                    )
+                entries = [
+                    part_mod.mesh_observe_packed_prewarm_entry(b, n_rg, mp)
+                ]
                 if fused_table is not None:
                     entries.append(
                         part_mod.mesh_fused_bc_prewarm_entry(
@@ -1083,11 +1106,7 @@ def _transform_streamed_impl(
                     )
                 mp.prewarm(entries, tracer=tr)
             else:
-                entries = [dp_mod.observe_prewarm_entry(b, n_rg)]
-                if use_resident:
-                    entries.append(
-                        dp_mod.observe_packed_prewarm_entry(b, n_rg)
-                    )
+                entries = [dp_mod.observe_packed_prewarm_entry(b, n_rg)]
                 if fused_table is not None:
                     entries.append(
                         dp_mod.fused_bc_prewarm_entry(
@@ -1311,10 +1330,11 @@ def _transform_streamed_impl(
         host backend when none remain), returning host arrays."""
 
         def on_device(nd):
-            total, mism, _rg, g = bqsr_mod._observe_device(
-                w, known_snps, backend, device=nd
-            )
-            return device_fetch(total), device_fetch(mism), g
+            with _handle_for(i, w.batch, nd) as rw:
+                total, mism, _rg, g = bqsr_mod._observe_device(
+                    w, known_snps, backend, device=nd, resident=rw
+                )
+                return device_fetch(total), device_fetch(mism), g
 
         def replay(exc):
             with tr.span(tele.SPAN_POOL_REPLAY, window=i,
@@ -1413,10 +1433,10 @@ def _transform_streamed_impl(
         mp = exec_state["mesh"]
         if mp is not None:
             try:
-                with tele.pass_scope("observe"):
+                with tele.pass_scope("observe"), \
+                        _handle_for(i, w.batch, mp) as rw:
                     total, mism, _rg, g = bqsr_mod._observe_device(
-                        w, known_snps, backend, mesh=mp,
-                        resident=resident_map.get(i),
+                        w, known_snps, backend, mesh=mp, resident=rw,
                     )
                     mp.accumulate(total, mism, g)
                 mesh_obs.append((i, w))
@@ -1460,10 +1480,10 @@ def _transform_streamed_impl(
                     return got, None
 
         def on_device(dev):
-            total, mism, _rg, g = bqsr_mod._observe_device(
-                w, known_snps, backend, device=dev,
-                resident=resident_map.get(i),
-            )
+            with _handle_for(i, w.batch, dev) as rw:
+                total, mism, _rg, g = bqsr_mod._observe_device(
+                    w, known_snps, backend, device=dev, resident=rw,
+                )
             tr.count(tele.C_DEVICE_DISPATCHED)
             return (total, mism, g), _obs_replay(i, w, dev)
 
@@ -1907,6 +1927,25 @@ def _transform_streamed_impl(
         )
         return host_ds, None
 
+    def _apply_on(idx, w, tbl, device=None, mesh=None):
+        """Dispatch window ``idx``'s pass-C pack2 on ``device`` or the
+        ``mesh`` -> handle.  A handle that a failed donating attempt
+        consumed is replaced by a transient one, once; a second
+        consumption is the device's failure."""
+        target = mesh if mesh is not None else device
+        for _ in range(2):
+            with _handle_for(idx, w.batch, target) as rw:
+                h = bqsr_mod.apply_recalibration_dispatch(
+                    w, tbl, gl, backend, device=device, mesh=mesh,
+                    resident=rw,
+                )
+            if h is not None:
+                return h
+        raise RuntimeError(
+            f"window {idx}: failed donating dispatches consumed its "
+            "resident handles"
+        )
+
     def _apply_parts_mesh(plist):
         """Mesh pass C: the solved table places ONCE, replicated, and
         stays device-resident while every window's [N, L] gather shards
@@ -1930,28 +1969,14 @@ def _transform_streamed_impl(
             for item in plist:
                 bw = item[1].batch
                 seen_dims.setdefault((bw.n_rows, bw.lmax), item[1])
-            mesh_apply_entry = part_mod.mesh_apply_prewarm_entry
             with tr.span(tele.SPAN_POOL_PREWARM_C):
-                pw_entries = []
-                for w in seen_dims.values():
-                    bw = w.batch.to_numpy()
-                    if use_packed and use_resident:
-                        # the resident bases+quals pack2, plus the
-                        # quals-only pack a dead handle falls back to
-                        pw_entries.append(mesh_apply_entry(
-                            bw, table.shape[0], table.shape[2], mp,
-                            pack2=True,
-                        ))
-                    if use_packed:
-                        pw_entries.append(mesh_apply_entry(
-                            bw, table.shape[0], table.shape[2], mp,
-                            pack=True,
-                        ))
-                    else:
-                        pw_entries.append(mesh_apply_entry(
-                            bw, table.shape[0], table.shape[2], mp,
-                        ))
-                mp.prewarm(pw_entries, tracer=tr)
+                mp.prewarm([
+                    part_mod.mesh_apply_prewarm_entry(
+                        w.batch.to_numpy(), table.shape[0],
+                        table.shape[2], mp,
+                    )
+                    for w in seen_dims.values()
+                ], tracer=tr)
         except Exception as e:
             _mesh_degrade(e, "pass-C table placement")
             rem = list(plist)
@@ -2001,11 +2026,7 @@ def _transform_streamed_impl(
                         tele.SPAN_APPLY_DISPATCH, window=idx,
                         device="mesh",
                     ):
-                        handle = bqsr_mod.apply_recalibration_dispatch(
-                            w, tbl_dev, gl, backend, mesh=mp,
-                            pack=use_packed,
-                            resident=resident_map.get(idx),
-                        )
+                        handle = _apply_on(idx, w, tbl_dev, mesh=mp)
                 except Exception as e:
                     return _remainder(e, "pass-C apply dispatch")
                 tr.count(tele.C_DEVICE_DISPATCHED)
@@ -2094,30 +2115,12 @@ def _transform_streamed_impl(
             # apply_split_s, so compile time never shows up as
             # host encode/submit time
             with tr.span(tele.SPAN_POOL_PREWARM_C):
-                pw_entries = []
-                for w in seen_dims.values():
-                    bw = w.batch.to_numpy()
-                    if use_packed and use_resident:
-                        # the resident bases+quals pack2 (what pass C will
-                        # actually dispatch), beside the quals-only pack a
-                        # dead handle falls back to
-                        pw_entries.append(apply_prewarm_entry(
-                            bw, table.shape[0], table.shape[2],
-                            pack=True, resident=True,
-                        ))
-                    if use_packed:
-                        pw_entries.append(apply_prewarm_entry(
-                            bw, table.shape[0], table.shape[2], pack=True,
-                        ))
-                    # the plain gather stays warm on every leg: eviction
-                    # replays re-apply with pack=False on a survivor, and
-                    # one entry covers both twins (resident warms the
-                    # donating variant alongside the plain one)
-                    pw_entries.append(apply_prewarm_entry(
-                        bw, table.shape[0], table.shape[2],
-                        resident=use_resident,
-                    ))
-                dpool.prewarm(pw_entries, tracer=tr)
+                dpool.prewarm([
+                    apply_prewarm_entry(
+                        w.batch.to_numpy(), table.shape[0], table.shape[2],
+                    )
+                    for w in seen_dims.values()
+                ], tracer=tr)
         # in-flight queue of (part idx, device, handle): depth
         # 2 single-device (the classic double buffer); with a
         # pool a double buffer per device — window j+1's gather
@@ -2152,42 +2155,29 @@ def _transform_streamed_impl(
                     )
             return dev_tables[i]
 
-        def _replay_apply(p_idx, dev, w, exc):
-            """Window p_idx's apply died on ``dev``: evict it
-            and re-run dispatch+fetch synchronously on a
-            survivor, host backend when none remain."""
-
-            def on_device(nd):
-                h = bqsr_mod.apply_recalibration_dispatch(
-                    w, _device_table(nd), gl, backend, device=nd
-                )
-                return bqsr_mod.apply_recalibration_finish(h)
-
-            with tr.span(tele.SPAN_POOL_REPLAY, window=p_idx,
-                         **dp_mod.span_attrs(dev)), \
-                    dp_mod.replay_scope():
-                _evict_or_lose(dev, exc)
-                return _on_survivors(
-                    p_idx, on_device, lambda: _host_apply(w)
-                )
-
-        def _solo_apply_sync(p_idx, w):
-            """Synchronous solo apply -> (dataset, packed | None) for a
-            window whose coalesced dispatch failed: the normal survivor
-            walk with the same packed/resident fast paths the solo
-            dispatch loop uses, host backend when the device path is
+        def _apply_sync(p_idx, w):
+            """Synchronous dispatch+fetch -> (dataset, packed | None)
+            on the survivor walk (the same pack2 on a handle the
+            dispatch loop runs), host backend when the device path is
             gone — byte-identical output either way."""
 
             def on_device(nd):
-                h = bqsr_mod.apply_recalibration_dispatch(
-                    w, _device_table(nd), gl, backend, device=nd,
-                    pack=use_packed, resident=resident_map.get(p_idx),
+                return bqsr_mod.apply_recalibration_finish_packed(
+                    _apply_on(p_idx, w, _device_table(nd), device=nd)
                 )
-                return bqsr_mod.apply_recalibration_finish_packed(h)
 
             return _on_survivors(
                 p_idx, on_device, lambda: (_host_apply(w), None)
             )
+
+        def _replay_apply(p_idx, dev, w, exc):
+            """Window p_idx's apply died on ``dev``: evict it and re-run
+            dispatch+fetch synchronously on a survivor."""
+            with tr.span(tele.SPAN_POOL_REPLAY, window=p_idx,
+                         **dp_mod.span_attrs(dev)), \
+                    dp_mod.replay_scope():
+                _evict_or_lose(dev, exc)
+                return _apply_sync(p_idx, w)
 
         def _hedge_redispatch(p_idx, p_dev, p_handle):
             """The speculative twin of a late window -> (closure, nd):
@@ -2209,10 +2199,7 @@ def _transform_streamed_impl(
                     tele.SPAN_APPLY_DISPATCH, window=p_idx, hedge=1,
                     **dp_mod.span_attrs(nd),
                 ):
-                    h = bqsr_mod.apply_recalibration_dispatch(
-                        w, _device_table(nd), gl, backend, device=nd,
-                        pack=use_packed,
-                    )
+                    h = _apply_on(p_idx, w, _device_table(nd), device=nd)
                 return bqsr_mod.apply_recalibration_finish_packed(h)
 
             return run, nd
@@ -2241,9 +2228,7 @@ def _transform_streamed_impl(
                         "coalesced apply of window %d fell back to "
                         "the solo dispatch (%s)", p_idx, e,
                     )
-                    done, p_packed = _solo_apply_sync(
-                        p_idx, p_handle.dataset
-                    )
+                    done, p_packed = _apply_sync(p_idx, p_handle.dataset)
                 else:
                     # SDC audit, fused-fetch success only: a fused
                     # dispatch has no single producing chip to
@@ -2339,14 +2324,12 @@ def _transform_streamed_impl(
                         time.monotonic() - t_sent, tracer=tr,
                     )
             except Exception as e:
-                # the replay re-applies synchronously (survivor chip or
-                # host backend) and returns a matrix-path dataset —
-                # its part encodes through the legacy column builders
-                done = _replay_apply(
+                # the replay re-applies synchronously: pack2 on a
+                # survivor's handle, or the host backend's matrix
+                done, p_packed = _replay_apply(
                     p_idx, p_dev,
                     bqsr_mod.apply_handle_dataset(p_handle), e,
                 )
-                p_packed = None
             else:
                 # SDC audit: a deterministic ADAM_TPU_AUDIT_RATE sample
                 # of windows dual-computes on the host parity twin and
@@ -2378,8 +2361,7 @@ def _transform_streamed_impl(
                 # writer-pool overlap are unchanged
                 try:
                     fut = coalescer.submit_apply(
-                        idx, w, table, pack=use_packed,
-                        resident=resident_map.get(idx),
+                        idx, w, table, resident=resident_map.get(idx),
                     )
                 except Exception as e:
                     log.warning(
@@ -2419,18 +2401,16 @@ def _transform_streamed_impl(
                     tele.SPAN_APPLY_DISPATCH, window=idx,
                     **dp_mod.span_attrs(dev),
                 ):
-                    handle = bqsr_mod.apply_recalibration_dispatch(
-                        w, _device_table(dev), gl, backend,
-                        device=dev, pack=use_packed,
-                        resident=resident_map.get(idx),
+                    handle = _apply_on(
+                        idx, w, _device_table(dev), device=dev
                     )
                 tr.count(tele.C_DEVICE_DISPATCHED)
                 return dev, handle
 
             # round-robin by WINDOW index (not parts position): the
             # resident handle was pinned at ingest by _pick_device(win),
-            # and an index mismatch here would silently re-ship every
-            # window (placement never affects output bytes either way)
+            # and an index mismatch here would place a transient handle
+            # for every window (placement never affects output bytes)
             got = _on_survivors(idx, _dispatch_one, lambda: None)
             if got is None:  # device path lost: apply host-side
                 _submit(idx, _host_apply(w))

@@ -24,10 +24,10 @@ dispatch per pass**:
   band of the fused table, so slicing its band back out is bitwise the
   histogram its solo dispatch would have produced (integer scatter-adds
   over disjoint bins commute with concatenation);
-* pass-C applies gather from one rg-concatenated table and, when packed
-  columns are on, emit one flat payload whose **per-job byte ranges are
-  exact** (the row-prefix pack is a prefix concatenation in row order),
-  so each job's Arrow parts stay byte-identical to its solo run.
+* pass-C applies gather from one rg-concatenated table and emit one
+  flat packed qual payload whose **per-job byte ranges are exact** (the
+  row-prefix pack is a prefix concatenation in row order), so each
+  job's Arrow parts stay byte-identical to its solo run.
 
 Fault contract: a fused dispatch that fails past its retry budget fails
 only the tickets it carried — every affected job falls back to its own
@@ -212,10 +212,10 @@ def _fused_apply_body(cols, extras, table, segs, lmax: int,
     """cols: per-ticket (bases, quals, lengths, flags, read_group_idx);
     extras: per-ticket (has_qual, valid); table: the rg-concatenated
     (cycle-centered) fused table; segs as in the observe body.  One
-    fused table gather; with ``pack_size`` (static, the fused grid
-    area) additionally the on-device SANGER encode + row-prefix pack —
-    the flat payload's per-job byte ranges are exact prefix sums, so
-    slicing them back out is bitwise each job's solo packed payload."""
+    fused table gather, then the on-device SANGER encode + row-prefix
+    pack over ``pack_size`` (static, the fused grid area) — the flat
+    payload's per-job byte ranges are exact prefix sums, so slicing
+    them back out is bitwise each job's solo packed qual column."""
     import jax.numpy as jnp
 
     from adam_tpu.ops.colpack import pack_rows_body, sanger_body
@@ -230,8 +230,6 @@ def _fused_apply_body(cols, extras, table, segs, lmax: int,
         parts.append((bases, quals, lengths, flags, rg_off, hq, vd))
     cat = [jnp.concatenate(xs, axis=0) for xs in zip(*parts)]
     new_q = apply_table_body(*cat, table, lmax)
-    if not pack_size:
-        return new_q
     lengths_cat, hq_cat, vd_cat = cat[2], cat[5], cat[6]
     pack_lens = jnp.where(vd_cat & hq_cat, lengths_cat, 0)
     return pack_rows_body(sanger_body(new_q), pack_lens, pack_size)
@@ -303,10 +301,9 @@ class CoalescerClient:
             self.job, self.tenant, window, ds, known_snps, resident
         )
 
-    def submit_apply(self, window, ds, table, pack=False,
-                     resident=None) -> _Future:
+    def submit_apply(self, window, ds, table, resident=None) -> _Future:
         return self._c.submit_apply(
-            self.job, self.tenant, window, ds, table, pack, resident,
+            self.job, self.tenant, window, ds, table, resident,
         )
 
 
@@ -461,7 +458,7 @@ class WindowCoalescer:
         )
 
     def submit_apply(self, job, tenant, window, ds, table,
-                     pack=False, resident=None) -> _Future:
+                     resident=None) -> _Future:
         # the table's cycle half-width is NOT threaded through: the
         # fused gather derives it from the (pad-centered, concatenated)
         # fused table's own shape, exactly like apply_table_body
@@ -482,13 +479,10 @@ class WindowCoalescer:
                 pad_rows_np(b.has_qual, g, False),
                 pad_rows_np(b.valid, g, False),
             ),
-            "pack_lens": (
-                pack_lengths(b.lengths, b.valid, b.has_qual)
-                if pack else None
-            ),
+            "pack_lens": pack_lengths(b.lengths, b.valid, b.has_qual),
         }
         fut = self._submit(
-            "apply", ("apply", gl, bool(pack)), job, tenant, window,
+            "apply", ("apply", gl), job, tenant, window,
             b.n_rows, g, gl, payload,
         )
         fut.dataset = ds
@@ -595,25 +589,15 @@ class WindowCoalescer:
         arrays in place when usable, else the grid-padded host copy
         placed fresh (the placement itself books the re-ship in the
         h2d transfer ledger, under the ``batch`` pass bucket)."""
-        from adam_tpu.formats import schema
-        from adam_tpu.formats.batch import pad_rows_np
+        from adam_tpu.parallel.device_pool import resident_arrays
 
         rw = self._ticket_resident(t, device)
         if rw is not None:
             return rw.args()
-        # non-resident fallback (the function name carries the
-        # residency-rule exemption): the ticket's handle is dead,
-        # mismatched or residency is off — the fused block re-ships
-        # from the host-retained ingest copy, bitwise the same rows
-        b = t.payload["b"]
-        host = (
-            pad_rows_np(b.bases, t.g, schema.BASE_PAD, cols=t.gl),
-            pad_rows_np(b.quals, t.g, schema.QUAL_PAD, cols=t.gl),
-            pad_rows_np(b.lengths, t.g, 0),
-            pad_rows_np(b.flags, t.g, schema.FLAG_UNMAPPED),
-            pad_rows_np(b.read_group_idx, t.g, -1),
-        )
-        return tuple(put(a) for a in host)
+        # the ticket's handle is dead or pinned on another chip than the
+        # group's target: the block places from the host-retained
+        # ingest copy, bitwise the same rows
+        return resident_arrays(t.payload["b"], t.g, t.gl, put)[0]
 
     def warm_fused_executable(self, kind, jitfn, args, statics, key,
                               device) -> None:
@@ -733,22 +717,9 @@ class WindowCoalescer:
         cols = []
         for t in grp:
             start, end, ops, lens, n_ops = t.payload["fresh"]
-            rw = self._ticket_resident(t, device)
-            if rw is not None:
-                flags = rw.get("flags")
-                quals = rw.get("quals")
-                lengths = rw.get("lengths")
-            else:
-                from adam_tpu.formats import schema
-                from adam_tpu.formats.batch import pad_rows_np
-
-                b = t.payload["b"]
-                flags = put(pad_rows_np(b.flags, t.g,
-                                        schema.FLAG_UNMAPPED))
-                # adam-tpu: noqa[residency] reason=non-resident fallback: the ticket's handle is dead/mismatched or residency is off — the fused block re-ships from the host ingest copy
-                quals = put(pad_rows_np(b.quals, t.g, schema.QUAL_PAD,
-                                        cols=t.gl))
-                lengths = put(pad_rows_np(b.lengths, t.g, 0))
+            _bases, quals, lengths, flags, _rg = self._resident_cols(
+                t, device, put
+            )
             per = (put(start), put(end), flags, put(ops), put(lens),
                    put(n_ops), quals, lengths)
             cols.append(per)
@@ -854,7 +825,6 @@ class WindowCoalescer:
         device = self._target_device(grp)
         put = putter(device)
         gl = grp[0].gl
-        pack = bool(grp[0].key[2])
         # fused table: every job's solved table centered into the
         # widest cycle axis (exactly merge_observations' centering, so
         # each job's gathers land on its own cells), concatenated on
@@ -908,12 +878,12 @@ class WindowCoalescer:
             cols.append(self._resident_cols(t, device, put))
             hq, vd = t.payload["extras"]
             extras.append((put(hq), put(vd)))
-        size = sum(t.g for t in grp) * gl if pack else 0
+        size = sum(t.g for t in grp) * gl
         jitfn = fused_jit("apply")
         key = (
             "batch.apply",
             tuple((t.g, t.payload["table"].shape[0]) for t in grp),
-            gl, n_cyc, pack,
+            gl, n_cyc,
         )
         statics = {"segs": tuple(segs), "lmax": gl, "pack_size": size}
         args = (tuple(cols), tuple(extras), table_dev)
@@ -931,33 +901,19 @@ class WindowCoalescer:
             out = _retry.retry_call(dispatch, site="sched.batch.dispatch")
         self.tracer.count(tele.C_DEVICE_DISPATCHED)
         results = []
-        if pack:
-            totals = [int(t.payload["pack_lens"].sum()) for t in grp]
-            cut = min(size, fetch_grid(sum(totals))) if size else 0
-            payload = device_fetch(out[:cut])
-            self.tracer.count(tele.C_DEVICE_FETCHED)
-            off = 0
-            for t, total_t in zip(grp, totals):
-                # the per-job packed-column payload split: the fused
-                # pack's byte ranges are exact prefix sums, so this
-                # slice IS the job's solo packed payload
-                sl = np.ascontiguousarray(payload[off:off + total_t])
-                off += total_t
-                results.append((
-                    t.payload["ds"], t.payload["b"],
-                    ("packed", [(sl, total_t)], t.payload["pack_lens"]),
-                ))
-        else:
-            new_q = device_fetch(out)
-            self.tracer.count(tele.C_DEVICE_FETCHED)
-            r0 = 0
-            for t in grp:
-                b = t.payload["b"]
-                results.append((
-                    t.payload["ds"], b,
-                    np.ascontiguousarray(
-                        new_q[r0:r0 + t.n, :b.lmax]
-                    ),
-                ))
-                r0 += t.g
+        totals = [int(t.payload["pack_lens"].sum()) for t in grp]
+        cut = min(size, fetch_grid(sum(totals)))
+        payload = device_fetch(out[:cut])
+        self.tracer.count(tele.C_DEVICE_FETCHED)
+        off = 0
+        for t, total_t in zip(grp, totals):
+            # the per-job packed-column payload split: the fused pack's
+            # byte ranges are exact prefix sums, so this slice IS the
+            # job's solo packed payload
+            sl = np.ascontiguousarray(payload[off:off + total_t])
+            off += total_t
+            results.append((
+                t.payload["ds"], t.payload["b"],
+                ("packed", [(sl, total_t)], t.payload["pack_lens"]),
+            ))
         return results, time.monotonic() - t_d

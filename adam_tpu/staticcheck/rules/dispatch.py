@@ -14,8 +14,7 @@ Three checks:
 
 * **coverage** — a call to a jit-compiled callable (``@jax.jit``
   functions, ``jax.jit(...)`` bindings, ``*_kernel`` names, the mesh
-  ``observe_window``/``apply_window``/``markdup_window``/
-  ``fused_bc_window`` collectives)
+  ``markdup_window``/``fused_bc_window`` collectives)
   in a streamed-path module must sit inside ``with
   compile_ledger.track(...)``.  The dominant idiom nests the dispatch
   in a local ``def dispatch(): ...`` retried via ``retry_call`` inside
@@ -65,10 +64,7 @@ SCOPE_FILES = frozenset({
 PREWARM_FILES = ("adam_tpu/parallel/device_pool.py",
                  "adam_tpu/parallel/partitioner.py")
 
-MESH_WINDOW_METHODS = frozenset(
-    {"observe_window", "apply_window", "markdup_window",
-     "fused_bc_window"}
-)
+MESH_WINDOW_METHODS = frozenset({"markdup_window", "fused_bc_window"})
 
 #: Function-name suffixes a ``pl.pallas_call`` site may live under:
 #: the jit-able math (``*_body``), a dispatchable binding

@@ -56,8 +56,6 @@ DEVICE_CALL_PATTERNS = (
     "*_kernel",
     "*_jit",
     "_observe_device",
-    "observe_window",
-    "apply_window",
     "markdup_window",
     "device_lexsort",
     "*_columns_dispatch",

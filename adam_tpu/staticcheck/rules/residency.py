@@ -3,11 +3,11 @@ only.
 
 The device-resident-windows contract (docs/PERF.md "Device-resident
 windows"): a streamed window's ``bases``/``quals`` matrices cross the
-host->device link ONCE, when the window is tokenized
-(``device_pool.make_resident_window`` / ``partitioner.
-mesh_resident_window`` under ``pass_scope("ingest")``), and the
-markdup/observe/apply passes dispatch against the
-:class:`~adam_tpu.parallel.device_pool.ResidentWindow` handle.  A new
+host->device link only when a handle is placed — ONCE, when the window
+is tokenized (``device_pool.make_resident_window`` / ``partitioner.
+mesh_resident_window`` under ``pass_scope("ingest")``), unless that pin
+fails or dies — and the markdup/observe/apply passes dispatch against
+the :class:`~adam_tpu.parallel.device_pool.ResidentWindow` handle.  A new
 ``putter``/``DevicePool.put``/``put_rows`` placement of those matrices
 inside a dispatch path silently re-ships the fattest arrays in the
 pipeline every pass — exactly the regression this rule exists to stop
@@ -23,13 +23,15 @@ the call target is a placer (a name bound from ``putter(...)``,
 ``pad_rows_np`` grid pad** — padding the fat window matrices is what a
 device ship looks like on this surface, whether the placement happens
 in the same expression or via a tuple handed to a mesh collective.
-Functions whose name (or any
-enclosing function's name) matches ``*resident*``/``*ingest*`` — the
-sanctioned placement sites — or the warm/prewarm/probe/bench patterns
-are exempt.  The legacy non-resident fallbacks (residency off, a dead
-handle, an eviction replay re-shipping from the host ingest copy) stay
-in the code on purpose and carry ``noqa[residency]`` suppressions with
-reasons, per the usual suppression contract."""
+Functions whose name (or any enclosing function's name) matches
+``*resident*``/``*ingest*`` — the sanctioned placement sites:
+``device_pool.resident_arrays`` and the handle builders on it, and the
+coalescer's per-ticket column source — or the warm/prewarm/probe/bench
+patterns are exempt.  Every other window payload reaches the device
+through a ResidentWindow handle, pinned at ingest or placed transient
+for one pass (a failed pin, an eviction, a consumed or degraded
+handle, a hedge twin), so the streamed dispatch paths carry no
+re-ship fallback and no ``noqa[residency]`` suppression."""
 
 from __future__ import annotations
 
@@ -53,8 +55,7 @@ SCOPE_FILES = (
     "adam_tpu/parallel/device_pool.py",
     "adam_tpu/parallel/partitioner.py",
     # the cross-job coalescer dispatches fused grids built from
-    # ResidentWindow slices; its non-resident re-ship fallbacks must
-    # stay visibly fallbacks (serve/batching.py)
+    # ResidentWindow slices (serve/batching.py)
     "adam_tpu/serve/batching.py",
 )
 
@@ -96,9 +97,9 @@ class ResidencyRule(Rule):
     contract = (
         "A streamed window's bases/quals matrices place on device once, "
         "at ingest (ResidentWindow under pass_scope('ingest')); markdup/"
-        "observe/apply dispatch against the handle.  Re-placements in "
-        "the dispatch paths are fallbacks and must carry a justified "
-        "noqa[residency] (docs/PERF.md 'Device-resident windows')."
+        "observe/apply dispatch against the handle, pinned at ingest "
+        "or placed transient for one pass through the sanctioned "
+        "builders (docs/PERF.md 'Device-resident windows')."
     )
 
     def visit(self, ctx):
@@ -145,9 +146,9 @@ class ResidencyRule(Rule):
                     self.name, sub,
                     f"host->device placement of window .{attr} outside "
                     "the ingest-resident path — dispatch against the "
-                    "ResidentWindow handle, or justify the fallback "
-                    "with noqa[residency] (docs/PERF.md "
-                    "'Device-resident windows')",
+                    "ResidentWindow handle (pinned, or transient via "
+                    "device_pool.resident_arrays) — docs/PERF.md "
+                    "'Device-resident windows'",
                 )
 
     def _walk_children(self, ctx, stmt, placers):

@@ -922,9 +922,9 @@ def render_report(report: dict) -> str:
             "  verdict: h2d is ingest-only"
             if res.get("ingest_only") else
             "  verdict: h2d is NOT ingest-only — observe/apply "
-            "re-shipped window payloads (residency off, handles "
-            "dropped, or a regression the residency staticcheck rule "
-            "should have caught)"
+            "placed window payloads (transient handles after failed "
+            "pins, evictions or a mesh degrade, or a regression the "
+            "residency staticcheck rule should have caught)"
         )
         dc = res.get("donated_compiles") or {}
         if dc.get("count"):

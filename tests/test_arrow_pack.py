@@ -150,18 +150,6 @@ def test_fetch_grid_properties():
     assert colpack.fetch_grid(1_000_001) == colpack.fetch_grid(1_000_002)
 
 
-def test_packed_columns_enabled(monkeypatch):
-    monkeypatch.delenv("ADAM_TPU_PACKED_COLS", raising=False)
-    assert colpack.packed_columns_enabled(True) is True
-    assert colpack.packed_columns_enabled(False) is False
-    for v, want in (("1", True), ("on", True), ("0", False),
-                    ("off", False), ("auto", False)):
-        monkeypatch.setenv("ADAM_TPU_PACKED_COLS", v)
-        assert colpack.packed_columns_enabled(False) is want
-    monkeypatch.setenv("ADAM_TPU_PACKED_COLS", "sideways")
-    assert colpack.packed_columns_enabled(True) is True  # warn + default
-
-
 # ---------------------------------------------------------------------------
 # Arrow builders
 # ---------------------------------------------------------------------------
@@ -247,6 +235,27 @@ def _write_part(tmp_path, tag, ds, compression, packed=None):
     return path
 
 
+def _pack2_on_handle(ds, table, gl, device=None, mesh=None):
+    """Pass C's one program: the fused bases+quals pack2 dispatched on a
+    resident handle (pool chip or mesh-sharded) -> (dataset, packed)."""
+    from adam_tpu.parallel import device_pool as dp
+    from adam_tpu.parallel import partitioner as part_mod
+
+    b = ds.batch.to_numpy()
+    if mesh is not None:
+        rw = part_mod.mesh_resident_window(b, 0, mesh)
+        table = mesh.put_replicated(table)
+    else:
+        rw = dp.make_resident_window(b, 0, device)
+    handle = bq.apply_recalibration_dispatch(
+        ds, table, gl, "device", device=device, mesh=mesh, resident=rw
+    )
+    rw.release()
+    got, packed = bq.apply_recalibration_finish_packed(handle)
+    assert packed.quals is not None and packed.bases is not None
+    return got, packed
+
+
 @pytest.mark.parametrize("compression", ["none", "snappy", "zstd"])
 def test_packed_part_byte_identical_pool_device(
     wgs_apply_case, tmp_path, compression
@@ -255,12 +264,9 @@ def test_packed_part_byte_identical_pool_device(
 
     ds, table, gl = wgs_apply_case
     ref = bq.apply_recalibration(ds, table, gl, "numpy")
-    handle = bq.apply_recalibration_dispatch(
-        ds, table, gl, "device", device=jax.local_devices()[0], pack=True
-    )
-    got, packed = bq.apply_recalibration_finish_packed(handle)
-    assert packed is not None
-    assert int(packed.lens.sum()) == len(packed.buf)
+    got, packed = _pack2_on_handle(ds, table, gl, jax.local_devices()[0])
+    assert int(packed.quals.lens.sum()) == len(packed.quals.buf)
+    assert int(packed.bases.lens.sum()) == len(packed.bases.buf)
     a = _write_part(tmp_path, f"ref-{compression}", ref, compression)
     b = _write_part(
         tmp_path, f"packed-{compression}", got, compression, packed=packed
@@ -276,11 +282,7 @@ def test_packed_part_byte_identical_mesh(wgs_apply_case, tmp_path):
     ds, table, gl = wgs_apply_case
     ref = bq.apply_recalibration(ds, table, gl, "numpy")
     mp = MeshPartitioner(jax.local_devices()[:2])
-    handle = bq.apply_recalibration_dispatch(
-        ds, mp.put_replicated(table), gl, "device", mesh=mp, pack=True
-    )
-    got, packed = bq.apply_recalibration_finish_packed(handle)
-    assert packed is not None
+    got, packed = _pack2_on_handle(ds, table, gl, mesh=mp)
     a = _write_part(tmp_path, "mesh-ref", ref, "zstd")
     b = _write_part(tmp_path, "mesh-packed", got, "zstd", packed=packed)
     assert _sha(a) == _sha(b)
@@ -293,10 +295,7 @@ def test_packed_part_byte_identical_host_fallback(wgs_apply_case, tmp_path):
 
     ds, table, gl = wgs_apply_case
     ref = bq.apply_recalibration(ds, table, gl, "numpy")
-    handle = bq.apply_recalibration_dispatch(
-        ds, table, gl, "device", device=jax.local_devices()[0], pack=True
-    )
-    got, packed = bq.apply_recalibration_finish_packed(handle)
+    got, packed = _pack2_on_handle(ds, table, gl, jax.local_devices()[0])
     a = _write_part(tmp_path, "host", ref, "zstd")
     b = _write_part(tmp_path, "dev", got, "zstd", packed=packed)
     assert _sha(a) == _sha(b)

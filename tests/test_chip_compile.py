@@ -81,14 +81,12 @@ def _compile(fn, *args, **kw):
 _ROW5 = ("bases", "quals", "lengths", "flags", "read_group_idx")
 _BQSR_ARGS = {
     "observe_packed": _ROW5 + ("res_bits", "mm_bits", "read_ok"),
-    "apply_pack": _ROW5 + ("has_qual", "valid", "table"),
     "apply_pack2": _ROW5 + ("has_qual", "valid", "table"),
     "fused_bc": _ROW5 + ("res_bits", "mm_bits", "read_ok", "has_qual",
                          "valid", "table"),
 }
 _BQSR_STATICS = {
     "observe_packed": (N_RG, GL),
-    "apply_pack": (GL, G * GL),
     "apply_pack2": (GL, G * GL),
     "fused_bc": (N_RG, GL, G * GL),
 }

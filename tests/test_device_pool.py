@@ -148,13 +148,21 @@ def test_streamed_prewarm_entries_cover_enabled_kernels():
     batch, _side = pack_reads(recs)
     b = batch.to_numpy()
     keys = [k[0] for k, _fn in dp.streamed_prewarm_entries(b, 2)]
-    assert keys == ["markdup.columns", "bqsr.observe", "bqsr.apply"]
+    assert keys == [
+        "markdup.columns", "bqsr.observe_packed", "bqsr.apply_pack2",
+    ]
     keys = [
         k[0] for k, _fn in dp.streamed_prewarm_entries(
             b, 2, mark_duplicates=False
         )
     ]
-    assert keys == ["bqsr.observe", "bqsr.apply"]
+    assert keys == ["bqsr.observe_packed", "bqsr.apply_pack2"]
+    keys = [
+        k[0] for k, _fn in dp.streamed_prewarm_entries(
+            b, 2, fused_n_cyc=41
+        )
+    ]
+    assert keys[-1] == "bqsr.fused_bc"
     assert dp.streamed_prewarm_entries(
         b, 2, mark_duplicates=False, recalibrate=False
     ) == []

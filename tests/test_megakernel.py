@@ -166,8 +166,7 @@ def megakernel_runs(tmp_path_factory):
 
     def leg(label, mode, n, fused, extra=None, known=None):
         out = str(d / f"out.{label}.adam")
-        env_keys = {"ADAM_TPU_RESIDENT": "1",
-                    "ADAM_TPU_FUSED_BC": fused, **(extra or {})}
+        env_keys = {"ADAM_TPU_FUSED_BC": fused, **(extra or {})}
         old = {k: os.environ.get(k) for k in env_keys}
         os.environ.update(env_keys)
         if mode is not None:
@@ -341,7 +340,6 @@ def test_megakernel_sigkill_mid_fused_then_resume(tmp_path):
                       + " --xla_force_host_platform_device_count=2"),
         "ADAM_TPU_NO_COMPILE_CACHE": "1",
         "ADAM_TPU_BQSR_BACKEND": "device",
-        "ADAM_TPU_RESIDENT": "1",
         "ADAM_TPU_FUSED_BC": "1",
         "ADAM_TPU_FAULTS":
             "proc.kill=kill,device=fused_bc,after=1,times=1",
